@@ -2,7 +2,9 @@
 # libmxnet.so; here the native surface is the IO/runtime layer — the compute
 # path is JAX/XLA).
 #
-#   make            build all native libs into mxnet_tpu/_lib/
+#   make            build all native libs into mxnet_tpu/_lib/ (each linked
+#                   under a temporary name and renamed: tests in several
+#                   workers run `make` at once in a fresh checkout)
 #   make clean
 
 CXX      ?= g++
@@ -21,7 +23,8 @@ all: $(LIBDIR)/libmxtpu_io.so $(LIBDIR)/libmxtpu_predict.so \
 
 $(LIBDIR)/libmxtpu_io.so: $(IO_SRCS) src/io/mxtpu_io.h
 	@mkdir -p $(LIBDIR)
-	$(CXX) $(CXXFLAGS) $(IO_SRCS) $(LDFLAGS) -o $@
+	$(CXX) $(CXXFLAGS) $(IO_SRCS) $(LDFLAGS) -o $@.$$$$.tmp \
+	    && mv -f $@.$$$$.tmp $@
 
 # C predict ABI: embeds CPython and drives mxnet_tpu/c_predict.py
 # (reference analogue: src/c_api/c_predict_api.cc in libmxnet.so)
@@ -30,7 +33,7 @@ $(LIBDIR)/libmxtpu_predict.so: src/capi/c_predict_api.cc \
                                src/capi/embed_common.h
 	@mkdir -p $(LIBDIR)
 	$(CXX) $(CXXFLAGS) $(PY_INCLUDES) src/capi/c_predict_api.cc \
-	    $(LDFLAGS) $(PY_LDFLAGS) -o $@
+	    $(LDFLAGS) $(PY_LDFLAGS) -o $@.$$$$.tmp && mv -f $@.$$$$.tmp $@
 
 # Training C ABI: NDArray/Symbol/Executor/KVStore core (c_api.h);
 # embeds CPython and drives mxnet_tpu/c_api.py (reference analogue:
@@ -39,7 +42,7 @@ $(LIBDIR)/libmxtpu.so: src/capi/c_api.cc src/capi/c_api.h \
                        src/capi/embed_common.h
 	@mkdir -p $(LIBDIR)
 	$(CXX) $(CXXFLAGS) $(PY_INCLUDES) src/capi/c_api.cc \
-	    $(LDFLAGS) $(PY_LDFLAGS) -o $@
+	    $(LDFLAGS) $(PY_LDFLAGS) -o $@.$$$$.tmp && mv -f $@.$$$$.tmp $@
 
 clean:
 	rm -rf $(LIBDIR)
@@ -88,14 +91,14 @@ ci-amalgamation: ci-native
 # stage 3: unit suite (excludes the tiers owned by their own stages)
 ci-unit: ci-native
 	python -m pytest tests/ -x -q \
-	    --ignore=tests/test_examples.py \
+	    --ignore-glob='tests/test_examples_*.py' \
 	    --ignore=tests/test_distributed.py \
 	    --ignore=tests/test_perl_frontend.py \
 	    --ignore=tests/test_amalgamation.py
 
 # stage 4: every example executes with its asserts
 ci-examples: ci-native
-	python -m pytest tests/test_examples.py -x -q
+	python -m pytest tests/test_examples_*.py -x -q
 
 # stage 5: real 2-process jax.distributed run
 ci-distributed: ci-native
@@ -112,7 +115,8 @@ ci-frontends: ci-native
 # CI smoke config (marked smoke, not a comparable round) — the full
 # measurement belongs to the driver's MULTICHIP round / bench stage
 ci-dryrun: ci-native
-	MXTPU_MULTICHIP_FAST=1 \
+	MXTPU_MULTICHIP_FAST=1 JAX_PLATFORMS=cpu \
+	    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	    python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
 # stage 8: fault-injection smoke — crash-safe checkpoints, auto-resume,

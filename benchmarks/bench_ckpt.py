@@ -11,7 +11,7 @@ Async leg: the snapshot-then-persist hiccup — host snapshot
 the background writer, drained between samples so every sample
 measures a steady-state submit (no back-pressure wait).
 
-The guarded value is the ratio ``sync_write_ms / async_hiccup_ms``
+The value is the ratio ``sync_write_ms / async_hiccup_ms``
 (bigger = the async path hides more of the write). The ACCEPTANCE
 contract (enforced absolutely in bench.py) is
 ``async_hiccup < 0.1 * sync_write``: the step loop's checkpoint stall

@@ -4,27 +4,29 @@
 The metric pair the compiler layer exists for: ``compile_cold_start_s``
 (fresh process, empty cache — bind + first fused step pays full
 trace+XLA-compile) vs ``cache_warm_start_s`` (fresh process, warm cache
-— the same programs deserialize from ``MXTPU_COMPILE_CACHE_DIR``).
-Each measurement is a REAL subprocess: in-process jit caches cannot
-contaminate it, exactly like a serving cold start or a ``resume='auto'``
-relaunch.
+— the same programs deserialize from the executable store under
+``JAX_COMPILATION_CACHE_DIR``). Each measurement is a REAL subprocess:
+in-process jit caches cannot contaminate it, exactly like a serving cold
+start or a ``resume='auto'`` relaunch.
 
-The child is pinned to ``JAX_PLATFORMS=cpu``: compile/serialize latency
-is a host-side property, and a CPU child never contends with a parent
-that holds the TPU (bench.py runs this inside the TPU bench job).
+This is a HOST measurement and says so (``"backend": "cpu"``): the
+children are pinned to ``JAX_PLATFORMS=cpu``, because a chip belongs to
+one process and two fresh processes cannot take turns on it inside a
+parent that holds it. That is also why bench.py, which holds the chip,
+does not run this: it is its own command. The chip's cold/warm compile
+times are what ``chip_smoke.py`` prints per phase.
 
-``run()`` returns one nested bench.py record; the guarded value is
-``warm_speedup = cold/warm`` (higher is better, so the shared
-``vs_best_recorded`` machinery applies unchanged), with an absolute
-``regression`` flag when the warm start fails to beat the cold start at
-all. ``python benchmarks/bench_compile_cache.py`` prints the record;
-``--child`` is the measured payload (used by ci/compiler_smoke.py too).
+``python benchmarks/bench_compile_cache.py`` prints the record
+(``value`` is ``cold/warm``); ``--child`` is the measured payload (used
+by ci/compiler_smoke.py too). Both caches (JAX's and the executable
+store) live under one fixed directory inside the checkout,
+``.cache/bench-compile-cache``, emptied before the cold run.
 """
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -80,11 +82,20 @@ def child():
                       "stats": compiler.stats()}))
 
 
+def empty_cache_dir():
+    """The fixed, emptied cache root of one cold->warm experiment."""
+    root = os.path.join(ROOT, ".cache", "bench-compile-cache")
+    shutil.rmtree(root, ignore_errors=True)
+    os.makedirs(root)
+    return root
+
+
 def run_child(cache_dir, extra_env=None):
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               MXTPU_COMPILE_CACHE_DIR=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=cache_dir,
                MXTPU_RETRACE_STRICT="1")
+    env.pop("MXTPU_COMPILE_CACHE_DIR", None)    # the store follows the root
     env.pop("XLA_FLAGS", None)      # one CPU device is plenty and fast
     env.update(extra_env or {})
     out = subprocess.run(
@@ -95,24 +106,18 @@ def run_child(cache_dir, extra_env=None):
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def run(quiet=False, cache_dir=None):
-    """Two cold->warm child runs; returns the nested bench record."""
-    tmp = None
-    if cache_dir is None:
-        tmp = tempfile.TemporaryDirectory(prefix="mxtpu-cc-bench-")
-        cache_dir = tmp.name
-    try:
-        cold = run_child(cache_dir)
-        warm = run_child(cache_dir)
-    finally:
-        if tmp is not None:
-            tmp.cleanup()
+def run(quiet=False):
+    """Two cold->warm child runs; returns the record."""
+    cache_dir = empty_cache_dir()
+    cold = run_child(cache_dir)
+    warm = run_child(cache_dir)
     cold_s = float(cold["ready_s"])
     warm_s = float(warm["ready_s"])
     rec = {
         "metric": "cache_warm_speedup",
         "value": round(cold_s / warm_s, 3) if warm_s else 0.0,
         "unit": "x",
+        "backend": "cpu",
         "compile_cold_start_s": round(cold_s, 4),
         "cache_warm_start_s": round(warm_s, 4),
         "cold_compiles": cold["stats"]["programs"]["compiled"],

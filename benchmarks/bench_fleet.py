@@ -18,9 +18,9 @@ standby-promotion readiness seconds, and the chaos p99 vs the no-fault
 p99 — the acceptance contract (enforced absolutely in bench.py) is
 ZERO lost requests and a bounded p99 ratio.
 
-``run()`` returns one nested bench.py record; the guarded value is the
-3-replica no-fault requests/sec (vs_best_recorded self-seeds on the
-first recorded round). ``python benchmarks/bench_fleet.py`` prints it.
+``run()`` returns one nested bench.py record; the value is the
+3-replica no-fault requests/sec. ``python benchmarks/bench_fleet.py``
+prints it.
 """
 import json
 import os

@@ -8,7 +8,7 @@ Two legs, matching ROADMAP item 1's acceptance:
   same deadline): once against the fp32 backend, once against the
   int8-PTQ backend (`quantize_backend`: calibrated scales, accuracy
   gate). ResNet-18 reports img/s, a scoring LSTM reports tok/s
-  (rows x seq tokens per wall second). The guarded value is the
+  (rows x seq tokens per wall second). The value is the
   quantized ResNet img/s; the ABSOLUTE contract bench.py enforces is
   ``accuracy_delta <= threshold`` for both models (the gate actually
   shipped int8 — a quantized record from a fallback fp32 backend would
@@ -17,8 +17,8 @@ Two legs, matching ROADMAP item 1's acceptance:
 * ``bf16_train`` — the same micro training config stepped under
   ``MXTPU_PRECISION=fp32`` and ``=bf16`` (fused Module step, dynamic
   loss-scale guard armed in bf16): per-step wall time each, their
-  ratio (the effective-TFLOPS delta — on a real chip round this is the
-  MFU delta, on this CPU host it is the honesty-labeled proxy), and
+  ratio (the effective-TFLOPS delta at fixed FLOPs; the record names
+  the device it was taken on), and
   the mean relative loss delta, which must stay inside
   ``LOSS_RTOL`` (bf16 rounding moves the loss, it must not move the
   optimization: documented tolerance 5e-2).
@@ -36,6 +36,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
+sys.path.insert(0, HERE)
 
 N_REQUESTS = 48
 MAX_BATCH = 16
@@ -57,7 +58,7 @@ def _resnet_module():
     sym = models.get_symbol("resnet", num_layers=18,
                             num_classes=NUM_CLASSES,
                             image_shape=",".join(map(str, IMAGE_SHAPE)))
-    mod = mx.mod.Module(sym, label_names=[], context=mx.cpu())
+    mod = mx.mod.Module(sym, label_names=[])
     mod.bind(data_shapes=[("data", (MAX_BATCH,) + IMAGE_SHAPE)],
              label_shapes=None, for_training=False)
     mx.random.seed(5)
@@ -82,7 +83,7 @@ def _lstm_module():
     pred = mx.sym.FullyConnected(last, num_hidden=NUM_CLASSES,
                                  name="pred")
     net = mx.sym.SoftmaxOutput(pred, name="softmax")
-    mod = mx.mod.Module(net, label_names=[], context=mx.cpu())
+    mod = mx.mod.Module(net, label_names=[])
     mod.bind(data_shapes=[("data", (MAX_BATCH, LSTM_SEQ))],
              label_shapes=None, for_training=False)
     mx.random.seed(11)
@@ -225,15 +226,15 @@ def _train_losses(precision):
 
 
 def bench_bf16_train():
+    from _device import device_stamp
     fp32_losses, fp32_s = _train_losses("fp32")
     bf16_losses, bf16_s = _train_losses("bf16")
     rel = [abs(a - b) / (abs(a) + 1e-12)
            for a, b in zip(fp32_losses, bf16_losses)]
     return {
         "metric": "bf16_train_step_speedup",
-        # >1 means the bf16 step is faster; the chip round reads this
-        # as the MFU delta (effective TFLOPS scale with 1/step-time at
-        # fixed FLOPs). Host-CPU honesty: no native bf16 units here.
+        # >1 means the bf16 step is faster (effective TFLOPS scale with
+        # 1/step-time at fixed FLOPs)
         "value": round(fp32_s / bf16_s, 3),
         "unit": "x (fp32 step time / bf16 step time)",
         "fp32_step_s": round(fp32_s, 5),
@@ -242,12 +243,14 @@ def bench_bf16_train():
         "loss_rtol": LOSS_RTOL,
         "loss_allclose": bool(np.mean(rel) <= LOSS_RTOL),
         "steps": TRAIN_STEPS,
-        "host_bench": True,
+        "device": device_stamp(),
     }
 
 
 def run(quiet=False):
+    from _device import device_stamp
     serving = bench_quant_serving()
+    serving["device"] = device_stamp()
     serving["bf16_train"] = bench_bf16_train()
     if not quiet:
         print(json.dumps(serving))
@@ -255,4 +258,6 @@ def run(quiet=False):
 
 
 if __name__ == "__main__":
+    from _device import require_chip
+    require_chip()
     run()

@@ -21,7 +21,7 @@ the compile count flat or lower) and a ``symbolic`` sub-record showing
 the warm-up matrix collapse (ONE warmed signature where the dense
 matrix warms ``len(coalescer_sizes)``).
 
-``run()`` returns one nested bench.py record; the guarded value is the
+``run()`` returns one nested bench.py record; the value is the
 packed-leg requests/sec. The absolute contracts bench.py enforces
 regardless of history: improvement >= 3, packed p99 <= dense p99 x
 1.5, packed warmed signatures <= dense, zero unwarmed signatures, zero
@@ -139,13 +139,10 @@ def bench_packed(rng):
 def bench_symbolic():
     """The warm-up matrix collapse: ONE symbolic probe where the dense
     matrix warms every coalescer size."""
-    from mxnet_tpu.compiler.symbolic import symbolic_dims_supported
     from mxnet_tpu.serving import InferenceServer, SymbolicJitBackend
     from mxnet_tpu.serving.warmup import coalescer_sizes
 
     dense_sizes = len(coalescer_sizes(MAX_BATCH))
-    if not symbolic_dims_supported():
-        return {"supported": False, "dense_warmup_sizes": dense_sizes}
     server = InferenceServer(
         SymbolicJitBackend(lambda arrays: [arrays["data"] * 2.0],
                            max_rows=MAX_BATCH,
@@ -161,7 +158,6 @@ def bench_symbolic():
     stats = server.stats()
     server.close()
     return {
-        "supported": True,
         "dense_warmup_sizes": dense_sizes,
         "warmed_signatures": stats["batching"]["warmed_signatures"],
         "warmup_skipped_covered": stats["warmup_skipped_covered"],

@@ -13,7 +13,7 @@ run numpy math that releases the GIL, so aggregate numbers are bounded
 by the host core count (``host_cores`` is the honesty field, as in the
 fleet bench).
 
-``run()`` returns one nested bench.py record; the guarded value is the
+``run()`` returns one nested bench.py record; the value is the
 hedged-leg aggregate requests/sec. The acceptance contract (enforced
 absolutely in bench.py) is ``hedged_p99 < unhedged_p99``, hedges
 actually fired, and ZERO lost requests on both legs.

@@ -4,7 +4,7 @@
 Reference analogue: example/image-classification/benchmark_score.py —
 img/s for alexnet/vgg/inception/resnet at several batch sizes (the
 reference's published K80 numbers live in its README; BASELINE.md). Runs
-each zoo model's forward under jit with honest host-read syncing.
+each zoo model's forward under jit; timings close with block_until_ready.
 
 Usage: python benchmarks/benchmark_score.py [--models resnet18_v1,...]
        [--batch-sizes 1,32] [--image-shape 3,224,224]
@@ -31,12 +31,12 @@ def score(model_name, batch, image_shape, iters=10):
     net.hybridize()
     x = mx.nd.array(np.random.rand(batch, c, h, w).astype(np.float32))
     # warm (compile)
-    float(net(x).asnumpy().ravel()[0])
+    jax.block_until_ready(net(x)._data)
     t0 = time.perf_counter()
     out = None
     for _ in range(iters):
         out = net(x)
-    float(out.asnumpy().ravel()[0])   # host read: drain the device queue
+    jax.block_until_ready(out._data)
     dt = time.perf_counter() - t0
     return batch * iters / dt
 
@@ -49,6 +49,9 @@ def main():
     ap.add_argument("--image-shape", default="3,224,224")
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args()
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from _device import require_chip
+    print("device:", require_chip(), flush=True)
 
     shape = tuple(int(d) for d in args.image_shape.split(","))
     for name in args.models.split(","):

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from _device import require_chip
 from profile_resnet import resnet50_convs, _sync, timed  # noqa: F401
 
 
@@ -69,7 +70,7 @@ def shift_conv_loop(B, h, w, cin, cout, Kiters):
 
 def main():
     B = int(os.environ.get("BENCH_BATCH", "256"))
-    print("device:", jax.devices()[0], flush=True)
+    print("device:", require_chip(), flush=True)
 
     uniq = {}
     for shape in resnet50_convs():

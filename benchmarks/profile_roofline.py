@@ -16,13 +16,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from _device import require_chip
 from profile_resnet import _sync, timed  # noqa: F401
 
 
 
 
 def main():
-    print("device:", jax.devices()[0], flush=True)
+    print("device:", require_chip(), flush=True)
 
     # HBM bandwidth: elementwise x*1.0000001 over a big array, K iters.
     # Each iter reads + writes the array once: 2*bytes traffic.

@@ -18,7 +18,6 @@ Exit 0 = green. Any assertion failure or child crash fails the stage.
 import os
 import shutil
 import sys
-import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -29,7 +28,7 @@ import bench_compile_cache  # noqa: E402
 
 
 def main():
-    tmp = tempfile.mkdtemp(prefix="mxtpu-ci-compiler-")
+    tmp = bench_compile_cache.empty_cache_dir()
     try:
         print("== cold run (empty cache) ==", flush=True)
         cold = bench_compile_cache.run_child(tmp)

@@ -120,8 +120,6 @@ def check_model(mod, make_row, seed, label, tmpdir):
 def main():
     import tempfile
     tmpdir = tempfile.mkdtemp(prefix="quant-smoke-")
-    os.environ.setdefault("MXTPU_COMPILE_CACHE_DIR",
-                          os.path.join(tmpdir, "cc"))
 
     def resnet_row(rng, n):
         return {"data": rng.rand(n, *IMAGE_SHAPE).astype(np.float32)}

@@ -32,7 +32,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-from mxnet_tpu.compiler.symbolic import symbolic_dims_supported  # noqa: E402
 from mxnet_tpu.serving import (CallableBackend, CallableStepBackend,  # noqa: E402
                                InferenceServer, InflightBatcher,
                                SymbolicJitBackend)
@@ -75,11 +74,6 @@ def smoke_packing():
 
 
 def smoke_symbolic():
-    if not symbolic_dims_supported():
-        print("[ragged-smoke] symbolic: jax.export symbolic shapes "
-              "unavailable on this build; skipping (fallback regime "
-              "is covered by tests/test_ragged.py)")
-        return
     server = InferenceServer(
         SymbolicJitBackend(lambda arrays: [arrays["data"] * 2.0],
                            max_rows=MAX_BATCH,

@@ -4,14 +4,16 @@ Reference analogue: python/mxnet/base.py ``_load_lib`` loading libmxnet.so.
 Here the native surface is only the runtime around the compute path (the
 compute path is XLA); ``libmxtpu_io.so`` provides GIL-free bulk RecordIO.
 
-The library is built by ``make`` (repo root). If it is missing, we attempt
-one on-demand compile with g++; failing that, callers fall back to the
+The library is built by ``make`` (repo root) into the git-ignored
+``mxnet_tpu/_lib/``. If it is missing, or older than ``src/io/recordio.cc``,
+we compile it on demand with g++; failing that, callers fall back to the
 pure-python path — the framework stays fully functional without a
-toolchain.
+toolchain — and a WARNING says which reader is in use.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -36,13 +38,21 @@ def _try_build():
     if not os.path.exists(_SRC):
         return False
     os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+    # several processes build at once in a fresh checkout (every xdist
+    # worker imports the tests that need the lib): each links its own
+    # file and renames it, so the path holds a whole library or none
+    tmp = "%s.%d.tmp" % (_LIB_PATH, os.getpid())
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", _SRC,
-           "-shared", "-pthread", "-o", _LIB_PATH]
+           "-shared", "-pthread", "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _LIB_PATH)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _bind(lib):
@@ -84,12 +94,23 @@ def get_lib():
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB or None
-        if not os.path.exists(_LIB_PATH) and not _try_build():
-            _LIB = False
-            return None
+        have = os.path.exists(_LIB_PATH)
+        stale = (have and os.path.exists(_SRC)
+                 and os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH))
+        if (not have or stale) and not _try_build():
+            if not have:
+                logging.warning("native IO library unavailable (no %s and "
+                                "g++ could not build it from %s): the "
+                                "pure-python reader serves", _LIB_PATH, _SRC)
+                _LIB = False
+                return None
+            logging.warning("%s is older than %s and could not be rebuilt: "
+                            "loading the stale library", _LIB_PATH, _SRC)
         try:
             _LIB = _bind(ctypes.CDLL(_LIB_PATH))
-        except OSError:
+        except OSError as err:
+            logging.warning("native IO library %s failed to load (%s): the "
+                            "pure-python reader serves", _LIB_PATH, err)
             _LIB = False
             return None
         return _LIB or None

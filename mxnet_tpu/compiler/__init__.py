@@ -14,7 +14,7 @@ artifacts cached and reused. Two halves (docs/how_to/compiler.md):
   sharding specs and quantization rewrites plug in.
 - :mod:`.fingerprint` + :mod:`.cache` + :mod:`.aot` — a stable graph
   fingerprint keying serialized compiled executables under
-  ``~/.cache/mxnet_tpu`` (atomic writes, SHA-256 manifests, corrupt
+  ``<jax cache dir>/mxtpu-executables`` (atomic writes, SHA-256 manifests, corrupt
   fallback to recompile, LRU size bound), so serving cold start, CI,
   ``fit(resume='auto')`` and bench rounds skip retrace+recompile of
   unchanged programs. ``MXTPU_COMPILE_CACHE=0`` kills the disk layer;
@@ -41,10 +41,10 @@ from .passes import (Annotate, CommonSubexpressionElimination,  # noqa: F401
                      PassManager, RematPolicy, default_pass_manager,
                      optimize, register_annotator)
 from .symbolic import (SymbolicBatchProgram,  # noqa: F401
-                       symbolic_dims_supported, symbolic_transform_sig)
+                       symbolic_transform_sig)
 
 __all__ = ["ir", "passes", "fingerprint", "cache", "aot", "memory",
-           "symbolic", "SymbolicBatchProgram", "symbolic_dims_supported",
+           "symbolic", "SymbolicBatchProgram",
            "symbolic_transform_sig",
            "MemoryBudgetError", "MemoryEstimate", "estimate_peak_bytes",
            "GraphIR",

@@ -13,11 +13,13 @@ call signature:
 3. a real ``lower().compile()`` — traced once, compiled once, then
    serialized into the cache for every later process.
 
-Every step of the persistent path is best-effort: an unserializable
-program (exotic callbacks), an unpicklable pytree, a backend without
-executable serialization — each falls back to the plain ``jax.jit``
-call path and counts a *bypass*. Numerics are identical on every path;
-the cache can only ever change latency.
+The store can only ever change latency, never numerics, but it does
+not fail quietly: a cached executable that will not load is logged at
+WARNING, invalidated, counted (``invalid_load``) and recompiled; a
+``lower().compile()`` that raises is logged at WARNING, counted
+(``bypassed``) and that function goes through plain ``jax.jit`` from
+then on; an executable that cannot be serialized (host callbacks) is
+counted (``unserializable``) and stays in-process.
 
 ``on_materialize(kind)`` (kind in ``{"compiled", "loaded"}``) fires once
 per new executable so retrace guards can count a cache load as the one
@@ -30,6 +32,9 @@ import pickle
 import threading
 from collections import OrderedDict
 from typing import Callable, Dict, Optional, Sequence
+
+import jax
+from jax.experimental import serialize_executable as _se
 
 from ..base import getenv
 from . import cache as _cache
@@ -51,7 +56,8 @@ def program_stats() -> Dict[str, int]:
     """compiled/loaded/bypassed/shared program counters."""
     with _lock:
         base = {"compiled": 0, "loaded": 0, "bypassed": 0, "shared": 0,
-                "invalid_load": 0}
+                "invalid_load": 0, "unserializable": 0,
+                "jax_cache_served": 0}
         base.update(_prog_counters)
         return base
 
@@ -61,39 +67,22 @@ def reset_program_stats():
         _prog_counters.clear()
 
 
-def _serializer():
-    try:
-        from jax.experimental import serialize_executable as se
-        return se
-    except ImportError:
-        return None
+# JAX's own persistent cache can answer the store's compile (same HLO
+# under another store key, or a store entry lost). The executable it
+# hands back was deserialized, and must not be serialized again:
+# XLA:CPU writes such an executable out without its kernels, and the
+# entry then dies at run time with "Function <fusion> not found". JAX
+# records the hit on the compiling thread, so a thread-local count taken
+# around one compile tells whose executable came back.
+_jax_cache_hits = threading.local()
 
 
-def _jax_version_tuple():
-    import jax
-    parts = []
-    for piece in jax.__version__.split(".")[:3]:
-        digits = "".join(ch for ch in piece if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)
+def _on_jax_event(event, **_):
+    if event == "/jax/compilation_cache/cache_hits":
+        _jax_cache_hits.n = getattr(_jax_cache_hits, "n", 0) + 1
 
 
-_DONATED_BROKEN: Optional[bool] = None
-
-
-def _donated_deserialize_broken() -> bool:
-    """True on the jax line whose ``deserialize_and_load`` loses the
-    donation aliasing bookkeeping (see :meth:`PersistentJit._persist_ok`
-    for the bisect); drives the version-gated default of
-    ``MXTPU_COMPILE_CACHE_DONATED``. Process-cached: _persist_ok runs
-    on every donated-program call (the training hot path), and the jax
-    version cannot change mid-process."""
-    global _DONATED_BROKEN
-    if _DONATED_BROKEN is None:
-        _DONATED_BROKEN = _jax_version_tuple() < (0, 5, 0)
-    return _DONATED_BROKEN
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 class PersistentJit:
@@ -110,7 +99,6 @@ class PersistentJit:
                  static_argnums: Sequence[int] = (),
                  donate_argnums: Sequence[int] = (),
                  on_materialize: Optional[Callable[[str], None]] = None):
-        import jax
         self._fn = fn
         self.kind = kind
         self._key_parts = tuple(str(p) for p in key_parts)
@@ -125,11 +113,10 @@ class PersistentJit:
         # serialized so one signature never deserializes/compiles twice
         self._mat_lock = threading.Lock()
         self._programs: Dict[object, Callable] = {}
-        # once persistence is known to be unusable for this function
-        # (backend without executable serialization, lower()/compile()
-        # rejection), every later call goes straight to the plain jit —
-        # the per-call signature walk must not outlive its purpose
-        self._disabled = _serializer() is None
+        # once lower()/compile() has rejected this function, every later
+        # call goes straight to the plain jit — the per-call signature
+        # walk must not outlive its purpose
+        self._disabled = False
         # steady-state fast path, keyed by the static-arg values: each
         # statics combination keeps a short candidate list of
         # materialized programs, tried in order — the compiled
@@ -147,35 +134,8 @@ class PersistentJit:
     def jit(self):
         return self._jit
 
-    def _persist_ok(self) -> bool:
-        """Donated programs are excluded from the persistent store on
-        the jax 0.4.x line: CALLING a deserialized executable with
-        buffer donation corrupts the process heap for some program
-        shapes (re-bisected on this container's jax 0.4.37 CPU backend:
-        a donated whole-step program carrying an LSTM scan aborts the
-        warm process with ``malloc_consolidate(): invalid chunk size``;
-        donated MLP steps and every undonated program are clean). The
-        culprit is jax/experimental/serialize_executable.py:57 —
-        ``deserialize_and_load`` rebuilds the Compiled via
-        ``unloaded_executable.load()``, which reloads the raw
-        executable through ``backend.deserialize_executable`` WITHOUT
-        the input-output aliasing bookkeeping the live
-        ``lower().compile()`` path establishes, so the CPU PJRT client
-        both donates (frees) and reads the aliased scan-carry buffer.
-        The 0.5 line rewrote that load path, so the gate is by jax
-        version rather than a blanket off; ``MXTPU_COMPILE_CACHE_DONATED``
-        overrides the default in either direction (1 opts a 0.4.x tree
-        in, 0 opts a newer tree out). Undonated executor/serving
-        programs — the serving-cold-start and resume paths — are cached
-        everywhere."""
-        if not self._donate:
-            return True
-        return bool(getenv("MXTPU_COMPILE_CACHE_DONATED",
-                           int(not _donated_deserialize_broken()), int))
-
     def __call__(self, *args):
-        if self._disabled or not _cache.cache_enabled() \
-                or not self._persist_ok():
+        if self._disabled or not _cache.cache_enabled():
             return self._jit(*args)
         try:
             statics_key = tuple(args[i] for i in self._static)
@@ -229,48 +189,66 @@ class PersistentJit:
             self._on_materialize(kind)
 
     def _materialize(self, canon: str, args) -> Callable:
-        se = _serializer()
-        if se is None:
-            _count("bypassed")
-            return self._jit
         key = program_key(self.kind, "+".join(self._key_parts), canon,
                           donation=self._donate)
         store = _cache.default_cache()
         data = store.get(key)
         if data is not None:
             try:
-                payload, in_tree, out_tree = pickle.loads(data)
-                compiled = se.deserialize_and_load(payload, in_tree,
-                                                   out_tree)
+                payload, in_tree, out_tree, device_ids = pickle.loads(data)
+                compiled = _se.deserialize_and_load(
+                    payload, in_tree, out_tree,
+                    execution_devices=_devices_by_id(device_ids))
                 self._notify("loaded")
                 return self._wrap_compiled(compiled)
             except Exception as err:    # noqa: BLE001 — entry unusable here
                 logging.warning("PersistentJit[%s]: cached executable "
-                                "%s failed to load (%s); recompiling",
-                                self.kind, key[:12], err)
+                                "%s failed to load (%s: %s); recompiling",
+                                self.kind, key[:12], type(err).__name__, err)
                 # a digest-valid entry that cannot deserialize is as
                 # invalid as a corrupt one — one shared invalidation
                 # definition lives on the cache
                 store.invalidate(key)
                 _count("invalid_load")
+        jax_hits = getattr(_jax_cache_hits, "n", 0)
         try:
             compiled = self._jit.lower(*args).compile()
         except Exception as err:        # noqa: BLE001 — AOT-unfriendly call
-            logging.debug("PersistentJit[%s]: lower/compile failed (%s); "
-                          "plain jit path", self.kind, err)
+            # loud, counted, and the same call then goes through the
+            # plain jit, which raises the real error if there is one
+            logging.warning("PersistentJit[%s]: lower/compile failed "
+                            "(%s: %s); this program runs through plain "
+                            "jax.jit, outside the executable store",
+                            self.kind, type(err).__name__, err)
             _count("bypassed")
             self._disabled = True       # don't re-pay the sig walk per call
             return self._jit
         self._notify("compiled")
+        if getattr(_jax_cache_hits, "n", 0) != jax_hits:
+            # JAX's cache served it and keeps serving it; see above
+            _count("jax_cache_served")
+            return self._wrap_compiled(compiled)
         try:
-            payload, in_tree, out_tree = se.serialize(compiled)
-            store.put(key, pickle.dumps((payload, in_tree, out_tree)),
+            payload, in_tree, out_tree = _se.serialize(compiled)
+            # the program's own device assignment, in order: a warm
+            # load must hand deserialize_and_load exactly these, or it
+            # spreads a one-device program over every local device
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            store.put(key, pickle.dumps((payload, in_tree, out_tree,
+                                         device_ids)),
                       meta={"kind": self.kind, "sig": canon[:512]})
         except Exception as err:        # noqa: BLE001 — unserializable
-            logging.debug("PersistentJit[%s]: executable not "
-                          "serializable (%s); in-process only", self.kind,
-                          err)
+            logging.info("PersistentJit[%s]: executable not "
+                         "serializable (%s: %s); in-process only",
+                         self.kind, type(err).__name__, err)
+            _count("unserializable")
         return self._wrap_compiled(compiled)
+
+
+def _devices_by_id(device_ids):
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in device_ids]
 
 
 class ProgramRegistry:

@@ -6,8 +6,8 @@ training job — here generalized so EVERY process (CI, serving cold
 start, ``fit(resume='auto')``, bench rounds) skips XLA recompilation of
 programs that haven't changed.
 
-Layout (default root ``~/.cache/mxnet_tpu/executables``, override
-``MXTPU_COMPILE_CACHE_DIR``)::
+Layout (default root ``<jax cache dir>/mxtpu-executables`` — see
+:func:`jax_cache_dir` — override ``MXTPU_COMPILE_CACHE_DIR``)::
 
     <root>/<key[:2]>/<key>.bin            # pickled (payload, trees) from
                                           # jax serialize_executable
@@ -41,9 +41,47 @@ from typing import Dict, Optional
 from ..base import getenv
 
 __all__ = ["CompilationCache", "default_cache", "cache_enabled",
-           "cache_stats", "reset_cache_stats"]
+           "cache_stats", "reset_cache_stats", "jax_cache_dir",
+           "configure_jax_cache"]
 
 MANIFEST_VERSION = 1
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def jax_cache_dir() -> Optional[str]:
+    """Where JAX's persistent compilation cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else the
+    fixed ``<checkout>/.cache/jax``. The path is part of JAX's cache
+    key, so it is never derived from a pid, a clock or a temp name.
+    None where neither exists: a package imported from an archive (the
+    amalgamated predict library) has no checkout to keep a cache in."""
+    named = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if named:
+        return named
+    if os.path.isdir(_CHECKOUT):
+        return os.path.join(_CHECKOUT, ".cache", "jax")
+    return None
+
+
+def configure_jax_cache():
+    """Called once at ``import mxnet_tpu``. With
+    ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already read it and
+    nothing is touched; otherwise JAX's cache is pointed at
+    :func:`jax_cache_dir`."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR") and jax_cache_dir():
+        import jax
+        jax.config.update("jax_compilation_cache_dir", jax_cache_dir())
+
+
+def _store_root() -> Optional[str]:
+    base = jax_cache_dir()
+    root = getenv("MXTPU_COMPILE_CACHE_DIR",
+                  base and os.path.join(base, "mxtpu-executables"))
+    # expanduser like every other user-supplied root in the repo — env
+    # files and CI yaml pass '~' without shell expansion
+    return root and os.path.expanduser(root)
 
 _lock = threading.Lock()
 _counters: Dict[str, int] = {}
@@ -70,8 +108,10 @@ def reset_cache_stats():
 
 def cache_enabled() -> bool:
     """The ``MXTPU_COMPILE_CACHE=0`` kill switch (read per call — tests
-    and operators flip it at runtime)."""
-    return bool(getenv("MXTPU_COMPILE_CACHE", 1, int))
+    and operators flip it at runtime); off too where the store has no
+    place to be (:func:`jax_cache_dir`)."""
+    return (bool(getenv("MXTPU_COMPILE_CACHE", 1, int))
+            and _store_root() is not None)
 
 
 class CompilationCache:
@@ -81,13 +121,8 @@ class CompilationCache:
 
     def __init__(self, root: Optional[str] = None,
                  max_bytes: Optional[int] = None):
-        if root is None:
-            root = getenv("MXTPU_COMPILE_CACHE_DIR",
-                          os.path.join(os.path.expanduser("~"), ".cache",
-                                       "mxnet_tpu", "executables"))
-        # expanduser like every other user-supplied root in the repo —
-        # env files and CI yaml pass '~' without shell expansion
-        self.root = os.path.expanduser(str(root))
+        self.root = (_store_root() if root is None
+                     else os.path.expanduser(str(root)))
         if max_bytes is None:
             max_bytes = int(getenv("MXTPU_COMPILE_CACHE_MB", 512, float)
                             * (1 << 20))
@@ -283,13 +318,10 @@ def default_cache() -> CompilationCache:
     (tests point the dir at tmp roots and shrink the bound)."""
     global _DEFAULT
     with _default_lock:
-        want = os.path.expanduser(getenv(
-            "MXTPU_COMPILE_CACHE_DIR",
-            os.path.join(os.path.expanduser("~"), ".cache",
-                         "mxnet_tpu", "executables")))
+        want = _store_root()
         want_bytes = int(getenv("MXTPU_COMPILE_CACHE_MB", 512, float)
                          * (1 << 20))
-        if _DEFAULT is None or _DEFAULT.root != str(want) \
+        if _DEFAULT is None or _DEFAULT.root != want \
                 or _DEFAULT.max_bytes != want_bytes:
             _DEFAULT = CompilationCache(root=want, max_bytes=want_bytes)
         return _DEFAULT

@@ -16,56 +16,22 @@ Identity discipline: the symbolic signature rides ``transform_sig`` in
 program over the same graph can never collide on one persisted key —
 a stale-layout serve is structurally impossible, not just unlikely.
 
-Support is probed, not assumed (:func:`symbolic_dims_supported`): on a
-jax build without working ``jax.export`` symbolic shapes — or when the
-export itself fails for a particular function — the program falls back
-to ordinary per-shape jit dispatch and reports ``supported=False`` so
-the serving tier keeps its dense bucket warm-up.
+A function that cannot be exported with a symbolic leading dim (it
+reshapes to a concrete batch, say) fails at construction with jax's own
+error: there is no per-shape fallback to hide it.
 """
 from __future__ import annotations
 
-import threading
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import export
 
 from .fingerprint import batch_signature, graph_fingerprint, program_key
 
-__all__ = ["symbolic_dims_supported", "symbolic_transform_sig",
-           "SymbolicBatchProgram"]
-
-_SUPPORTED: Optional[bool] = None
-_SUPPORTED_LOCK = threading.Lock()
-
-
-def symbolic_dims_supported() -> bool:
-    """Probe (once per process) whether this jax build can export a
-    program with a symbolic leading dim and call it at two different
-    concrete extents."""
-    global _SUPPORTED
-    if _SUPPORTED is None:
-        with _SUPPORTED_LOCK:
-            if _SUPPORTED is None:
-                _SUPPORTED = _probe()
-    return _SUPPORTED
-
-
-def _probe() -> bool:
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax import export
-
-        shape = export.symbolic_shape("_b, 2")
-        exported = export.export(jax.jit(lambda x: x * 2))(
-            jax.ShapeDtypeStruct(shape, jnp.float32))
-        for rows in (1, 3):
-            out = exported.call(np.ones((rows, 2), np.float32))
-            if np.asarray(out).shape != (rows, 2):
-                return False
-        return True
-    except Exception:
-        return False
+__all__ = ["symbolic_transform_sig", "SymbolicBatchProgram"]
 
 
 def symbolic_transform_sig(names: Sequence[str], max_rows: int,
@@ -86,20 +52,14 @@ class SymbolicBatchProgram:
     input name to its PER-ROW shape (without the batch axis);
     ``input_dtypes`` defaults every input to float32.
 
-    After construction, ``supported`` says which regime the program is
-    in: True — one export with the leading dim symbolic, ``compiles ==
-    1`` forever; False — per-shape ``jax.jit`` dispatch, ``compiles``
-    counts distinct row counts seen (exactly the warm-up matrix the
-    symbolic path deletes). Either way :attr:`key` is the persisted
-    program identity, with the symbolic signature riding
-    ``transform_sig`` only in the symbolic regime.
+    ``compiles`` is 1 for the life of the program; :attr:`key` is the
+    persisted program identity, with the symbolic signature riding
+    ``transform_sig``.
     """
 
     def __init__(self, fn: Callable[[Dict], List], input_specs: Dict,
                  max_rows: int, input_dtypes: Optional[Dict] = None,
                  name: str = "symbolic_batch"):
-        import jax
-
         self.fn = fn
         self.name = name
         self.max_rows = max(1, int(max_rows))
@@ -107,13 +67,10 @@ class SymbolicBatchProgram:
         self.input_dtypes = {
             k: np.dtype((input_dtypes or {}).get(k, np.float32))
             for k in self.input_specs}
-        self.transform_sig = ""
-        self._exported = None
-        self._jitted = jax.jit(self._call_fn)
-        self._lock = threading.Lock()
-        self._shapes_seen: set = set()     # tpu-lint: guarded-by=_lock
-        self.compiles = 0                  # tpu-lint: guarded-by=_lock
-        self.supported = symbolic_dims_supported() and self._export()
+        self._exported = self._export()
+        self.compiles = 1
+        self.transform_sig = symbolic_transform_sig(
+            sorted(self.input_specs), self.max_rows)
         self.key = self._program_key()
 
     # ``fn`` sees dict-in/list-out; jax traces it positionally by name so
@@ -122,33 +79,21 @@ class SymbolicBatchProgram:
         outs = self.fn(dict(arrays))
         return list(outs) if isinstance(outs, (list, tuple)) else [outs]
 
-    def _export(self) -> bool:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax import export
-
-            scope = export.SymbolicScope([f"_b <= {self.max_rows}"])
-            structs = {}
-            for iname, row in sorted(self.input_specs.items()):
-                rest = ", ".join(str(d) for d in row)
-                spec = f"_b, {rest}" if rest else "_b"
-                shape = export.symbolic_shape(spec, scope=scope)
-                structs[iname] = jax.ShapeDtypeStruct(
-                    shape, jnp.dtype(self.input_dtypes[iname]))
-            self._exported = export.export(jax.jit(self._call_fn))(structs)
-            # prove the range before promising it: the two extents that
-            # break most often (degenerate 1 and the bound itself)
-            for rows in {1, self.max_rows}:
-                self._exported.call(self._zeros(rows))
-            with self._lock:
-                self.compiles = 1
-        except Exception:
-            self._exported = None
-            return False
-        self.transform_sig = symbolic_transform_sig(
-            sorted(self.input_specs), self.max_rows)
-        return True
+    def _export(self):
+        scope = export.SymbolicScope([f"_b <= {self.max_rows}"])
+        structs = {}
+        for iname, row in sorted(self.input_specs.items()):
+            rest = ", ".join(str(d) for d in row)
+            spec = f"_b, {rest}" if rest else "_b"
+            shape = export.symbolic_shape(spec, scope=scope)
+            structs[iname] = jax.ShapeDtypeStruct(
+                shape, jnp.dtype(self.input_dtypes[iname]))
+        exported = export.export(jax.jit(self._call_fn))(structs)
+        # prove the range before promising it: the two extents that
+        # break most often (degenerate 1 and the bound itself)
+        for rows in {1, self.max_rows}:
+            exported.call(self._zeros(rows))
+        return exported
 
     def _zeros(self, rows: int) -> Dict[str, np.ndarray]:
         return {iname: np.zeros((rows,) + row, self.input_dtypes[iname])
@@ -161,20 +106,10 @@ class SymbolicBatchProgram:
             fp = f"callable:{self.name}"
         avals = batch_signature(
             self._zeros(self.max_rows), route=self.name,
-            symbolic_rows=self.max_rows if self.supported else None)
-        return program_key("symbolic_batch" if self.supported
-                           else "batched", fp, avals,
+            symbolic_rows=self.max_rows)
+        return program_key("symbolic_batch", fp, avals,
                            transform_sig=self.transform_sig)
 
     def __call__(self, arrays: Dict) -> List[np.ndarray]:
-        if self._exported is not None:
-            outs = self._exported.call(dict(arrays))
-        else:
-            with self._lock:
-                shapes = tuple(sorted(
-                    (k, tuple(np.shape(v))) for k, v in arrays.items()))
-                if shapes not in self._shapes_seen:
-                    self._shapes_seen.add(shapes)
-                    self.compiles += 1
-            outs = self._jitted(dict(arrays))
+        outs = self._exported.call(dict(arrays))
         return [np.asarray(o) for o in outs]

@@ -83,17 +83,13 @@ register_knob("MXTPU_COMPILE_CACHE", int, 1,
               "persist compiled executables under "
               "MXTPU_COMPILE_CACHE_DIR so later processes skip "
               "recompilation — 0 disables the disk layer")
-register_knob("MXTPU_COMPILE_CACHE_DIR", str,
-              "~/.cache/mxnet_tpu/executables",
-              "root of the persistent compilation cache")
+register_knob("MXTPU_COMPILE_CACHE_DIR", str, None,
+              "root of the executable store; default "
+              "<jax cache dir>/mxtpu-executables, where the jax cache dir "
+              "is JAX_COMPILATION_CACHE_DIR if set, else "
+              "<checkout>/.cache/jax")
 register_knob("MXTPU_COMPILE_CACHE_MB", float, 512,
               "LRU size bound of the compilation cache, megabytes")
-register_knob("MXTPU_COMPILE_CACHE_DONATED", int, None,
-              "also persist buffer-donating programs (fused/SPMD steps); "
-              "default is gated by jax version — off on the 0.4.x line, "
-              "whose deserialize_and_load (serialize_executable.py:57) "
-              "drops donation aliasing and corrupts the heap on CPU for "
-              "scan-carrying programs; on from 0.5. 1/0 force either way")
 register_knob("MXTPU_REMAT_MB", float, None,
               "activation-memory budget: a training bind whose estimated "
               "forward activations exceed it gets jax.checkpoint remat "

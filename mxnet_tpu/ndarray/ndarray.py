@@ -673,7 +673,7 @@ def onehot_encode(indices, out):
 
 def waitall():
     """Reference: MXNDArrayWaitAll — drain the async engine."""
-    (jax.effects_barrier if hasattr(jax, "effects_barrier") else (lambda: None))()
+    jax.effects_barrier()
 
 
 def imdecode(str_img, clip_rect=(0, 0, 0, 0), out=None, index=0, channels=3,

@@ -321,7 +321,7 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
             # sum and sum-of-squares reduce in a single multi-output
             # fusion (ONE HBM read of the activation where mean-then-var
             # reads it twice; worth ~11% on the ResNet-50 train step, see
-            # BENCH_NOTES.md). fp32 accumulators lose nothing relative to
+            # docs/how_to/performance.md). fp32 accumulators lose nothing relative to
             # 8-bit-mantissa data, so E[x^2]-E[x]^2 is safe here.
             n = 1
             for i in reduce_axes:

@@ -12,6 +12,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
@@ -85,14 +87,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
 
 
-try:  # pallas import is deferred-safe: CPU-only installs still work
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
 def _pick_block(s, target):
     b = min(target, s)
     while s % b:
@@ -100,28 +94,11 @@ def _pick_block(s, target):
     return max(b, 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
-                                             "block_k", "force_pallas"))
-def _flash_attention_dense(q, k, v, causal=False, scale=None, block_q=256,
-                           block_k=512, force_pallas=False):
-    """The dense core: every token is real. Kept custom_vjp'd and
-    bitwise-identical to the pre-ragged ``flash_attention`` — the public
-    dispatcher routes here whenever no lengths/segment_ids are given."""
+def _flash_dense_pallas(q, k, v, causal, scale, block_q, block_k,
+                        interpret):
+    """Build the dense flash ``pallas_call`` for (B, H, S, D) inputs."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    if causal and sq > sk:
-        # rows past the KV length would have an empty causal window —
-        # an ill-defined softmax the paths disagree on; reject loudly
-        raise ValueError(
-            f"flash_attention(causal=True) requires seq_q <= seq_k, got "
-            f"{sq} > {sk}")
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    on_tpu = jax.default_backend() == "tpu"
-    if not _HAVE_PALLAS or (not on_tpu and not force_pallas):
-        return _attn_reference(q, k, v, causal, scale)
-
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
     qr = q.reshape(b * h, sq, d)
@@ -144,9 +121,35 @@ def _flash_attention_dense(q, k, v, causal=False, scale=None, block_q=256,
             pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
             pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
         ],
-        interpret=not on_tpu,
+        interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
+                                             "block_k", "force_pallas"))
+def _flash_attention_dense(q, k, v, causal=False, scale=None, block_q=256,
+                           block_k=512, force_pallas=False):
+    """The dense core: every token is real. Kept custom_vjp'd and
+    bitwise-identical to the pre-ragged ``flash_attention`` — the public
+    dispatcher routes here whenever no lengths/segment_ids are given."""
+    sq, d = q.shape[2:]
+    sk = k.shape[2]
+    if causal and sq > sk:
+        # rows past the KV length would have an empty causal window —
+        # an ill-defined softmax the paths disagree on; reject loudly
+        raise ValueError(
+            f"flash_attention(causal=True) requires seq_q <= seq_k, got "
+            f"{sq} > {sk}")
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    on_tpu = jax.default_backend() == "tpu"
+    if not on_tpu and not force_pallas:
+        return _attn_reference(q, k, v, causal, scale)
+    return _flash_dense_pallas(q, k, v, causal, scale, block_q, block_k,
+                               interpret=not on_tpu)
 
 
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k, force_pallas):
@@ -279,13 +282,17 @@ def _flash_kernel_masked(*refs, causal, scale, seq_k, seq_q,
     """The masked variant of :func:`_flash_kernel`: same grid, same
     online-softmax recurrence, with the in-block mask extended by the
     per-batch key length and/or the packed segment ids (pallas guide:
-    ``broadcasted_iota`` + ``jnp.where``; TPU needs the >=2D iota)."""
+    ``broadcasted_iota`` + ``jnp.where``; TPU needs the >=2D iota).
+    The lengths arrive by scalar prefetch — (B*H,) int32 in SMEM — and
+    the segment ids as a (bq, 1) column and a (1, bk) row, the layouts
+    the TPU lowering takes."""
     it = iter(refs)
-    q_ref, k_ref, v_ref = next(it), next(it), next(it)
     len_ref = next(it) if has_len else None
+    q_ref, k_ref, v_ref = next(it), next(it), next(it)
     segq_ref = next(it) if has_seg else None
     segk_ref = next(it) if has_seg else None
     o_ref, m_ref, l_ref, acc_ref = next(it), next(it), next(it), next(it)
+    kv_len = len_ref[pl.program_id(0)] if has_len else None
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
     bq = q_ref.shape[1]
@@ -315,12 +322,11 @@ def _flash_kernel_masked(*refs, causal, scale, seq_k, seq_q,
         if causal:
             mask &= (ki * bk + cols) <= (q_off + rows)
         if has_len:
-            mask &= (ki * bk + cols) < len_ref[0, 0]
+            mask &= (ki * bk + cols) < kv_len
         if has_seg:
-            seg_q = segq_ref[0]
-            seg_k = segk_ref[0]
-            mask &= ((seg_q[:, None] == seg_k[None, :])
-                     & (seg_q[:, None] > 0))
+            seg_q = segq_ref[0]         # (bq, 1)
+            seg_k = segk_ref[0]         # (1, bk)
+            mask &= (seg_q == seg_k) & (seg_q > 0)
         s = jnp.where(mask, s, _NEG)
         m_prev = m_ref[:, 0]
         l_prev = l_ref[:, 0]
@@ -344,6 +350,57 @@ def _flash_kernel_masked(*refs, causal, scale, seq_k, seq_q,
         o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
 
 
+def _masked_pallas(q, k, v, lengths, segment_ids, causal, scale, block_q,
+                   block_k, interpret):
+    """Build the masked flash ``pallas_call`` for (B, H, S, D) inputs."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    bq = _pick_block(sq, block_q)
+    bk = _pick_block(sk, block_k)
+    # index maps see the grid indices, then any scalar-prefetch refs
+    operands = [q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
+                v.reshape(b * h, sk, d)]
+    in_specs = [
+        pl.BlockSpec((1, bq, d), lambda bh, i, j, *_: (bh, i, 0)),
+        pl.BlockSpec((1, bk, d), lambda bh, i, j, *_: (bh, j, 0)),
+        pl.BlockSpec((1, bk, d), lambda bh, i, j, *_: (bh, j, 0)),
+    ]
+    prefetch = []
+    if lengths is not None:
+        # one key length per batch element, broadcast over heads
+        prefetch.append(jnp.broadcast_to(
+            lengths.astype(jnp.int32)[:, None], (b, h)).reshape(b * h))
+    if segment_ids is not None:
+        seg = segment_ids.astype(jnp.int32)
+        operands.extend([seg[:, :, None], seg[:, None, :]])
+        in_specs.extend([
+            pl.BlockSpec((1, bq, 1), lambda bh, i, j, *_: (bh // h, i, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bh, i, j, *_: (bh // h, 0, j)),
+        ])
+    kernel = functools.partial(
+        _flash_kernel_masked, causal=causal, scale=scale, seq_k=sk,
+        seq_q=sq, has_len=lengths is not None,
+        has_seg=segment_ids is not None)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b * h, sq // bq, sk // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bq, d),
+                                   lambda bh, i, j, *_: (bh, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),  # running max m
+                pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
+                pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        interpret=interpret,
+        name="flash_attention_masked",
+    )(*prefetch, *operands)
+    return out.reshape(b, h, sq, d)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "force_pallas"))
 def _masked_attention(q, k, v, lengths, segment_ids, causal=False,
@@ -351,7 +408,7 @@ def _masked_attention(q, k, v, lengths, segment_ids, causal=False,
                       force_pallas=False):
     """The masked core: plain jit (differentiable through the jnp
     reference path), Pallas masked kernel on TPU/force_pallas."""
-    b, h, sq, d = q.shape
+    sq, d = q.shape[2:]
     sk = k.shape[2]
     if causal and sq > sk:
         raise ValueError(
@@ -364,51 +421,11 @@ def _masked_attention(q, k, v, lengths, segment_ids, causal=False,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     on_tpu = jax.default_backend() == "tpu"
-    if not _HAVE_PALLAS or (not on_tpu and not force_pallas):
+    if not on_tpu and not force_pallas:
         return _masked_reference(q, k, v, lengths, segment_ids,
                                  causal, scale)
-
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
-    operands = [q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-                v.reshape(b * h, sk, d)]
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-    ]
-    if lengths is not None:
-        # one key length per batch element, broadcast over heads
-        lens = jnp.broadcast_to(lengths.astype(jnp.int32)[:, None],
-                                (b, h)).reshape(b * h, 1)
-        operands.append(lens)
-        in_specs.append(pl.BlockSpec((1, 1), lambda bh, i, j: (bh, 0)))
-    if segment_ids is not None:
-        seg = jnp.broadcast_to(segment_ids.astype(jnp.int32)[:, None, :],
-                               (b, h, sk)).reshape(b * h, sk)
-        operands.extend([seg, seg])
-        in_specs.extend([
-            pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i)),
-            pl.BlockSpec((1, bk), lambda bh, i, j: (bh, j)),
-        ])
-    kernel = functools.partial(
-        _flash_kernel_masked, causal=causal, scale=scale, seq_k=sk,
-        seq_q=sq, has_len=lengths is not None,
-        has_seg=segment_ids is not None)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b * h, sq // bq, sk // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),  # running max m
-            pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
-            pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
-        ],
-        interpret=not on_tpu,
-    )(*operands)
-    return out.reshape(b, h, sq, d)
+    return _masked_pallas(q, k, v, lengths, segment_ids, causal, scale,
+                          block_q, block_k, interpret=not on_tpu)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
@@ -416,8 +433,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
                     segment_ids=None):
     """Attention over (B, H, S, D) inputs; exact, memory-efficient.
 
-    Uses the Pallas TPU kernel on TPU backends (or when force_pallas,
-    via the interpreter — tests), and the jnp reference elsewhere.
+    On a TPU backend this is always the compiled Pallas kernel. On any
+    other backend it is the jnp reference, or with ``force_pallas`` the
+    kernel through the Pallas interpreter (tests).
 
     ``lengths`` (B,) int — per-batch real KEY length; positions at or
     past it are masked out. ``segment_ids`` (B, S) int — packed-row

@@ -15,11 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _gates(xproj, h, w_h2h):
@@ -40,26 +36,92 @@ def _cell_jnp(xproj, h, c, w_h2h):
     return h_new.astype(h.dtype), c_new.astype(c.dtype)
 
 
-def _lstm_kernel(xproj_ref, h_ref, c_ref, w_ref, hn_ref, cn_ref):
-    i, f, g, o = _gates(xproj_ref[:], h_ref[:], w_ref[:])
+def _lstm_kernel(xi_ref, xf_ref, xg_ref, xo_ref, h_ref, c_ref,
+                 wi_ref, wf_ref, wg_ref, wo_ref, hn_ref, cn_ref):
+    """One block of ``bh`` hidden units: each gate's x-projection block
+    (N, bh) and h2h weight block (bh, H), the whole h (N, H)."""
+    h = h_ref[:]
+
+    def gate(x_ref, w_ref):
+        w = w_ref[:]
+        lhs = h
+        if lhs.dtype != w.dtype:
+            lhs, w = lhs.astype(jnp.float32), w.astype(jnp.float32)
+        # bf16 operands are exact on the MXU with f32 accumulation; a
+        # global "highest" default precision must not reach them (Mosaic
+        # refuses an fp32-precision matmul of bf16 operands)
+        precision = (jax.lax.Precision.DEFAULT
+                     if w.dtype == jnp.bfloat16 else None)
+        return x_ref[:].astype(jnp.float32) + jax.lax.dot_general(
+            lhs, w, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    i = jax.nn.sigmoid(gate(xi_ref, wi_ref))
+    f = jax.nn.sigmoid(gate(xf_ref, wf_ref))
+    g = jnp.tanh(gate(xg_ref, wg_ref))
+    o = jax.nn.sigmoid(gate(xo_ref, wo_ref))
     c_new = f * c_ref[:].astype(jnp.float32) + i * g
     h_new = o * jnp.tanh(c_new)
     hn_ref[:] = h_new.astype(hn_ref.dtype)
     cn_ref[:] = c_new.astype(cn_ref.dtype)
 
 
+def _lstm_kernel_whole(xproj_ref, h_ref, c_ref, w_ref, hn_ref, cn_ref):
+    """Every operand whole in VMEM, the gates sliced out of one (N, 4H)
+    projection: for an H whose gate blocks are off the 128-lane tiling."""
+    hn_ref[:], cn_ref[:] = _cell_jnp(xproj_ref[:], h_ref[:], c_ref[:],
+                                     w_ref[:])
+
+
+# double-buffered h2h weight blocks (4 gates x 2 buffers, sized as f32 so
+# a mixed-dtype upcast still fits) stay under this share of the chip's
+# 16 MiB scoped VMEM
+_W_BLOCK_BUDGET = 8 << 20
+
+
+def _hidden_block(hdim):
+    """Hidden units per grid step: the largest multiple of 128 that
+    divides H within the VMEM budget; None when H is not a multiple of
+    128 (a per-gate block of xproj would be off the TPU's lane tiling)."""
+    if hdim % 128:
+        return None
+    bh = 128
+    while hdim % (2 * bh) == 0 and 8 * (2 * bh) * hdim * 4 <= _W_BLOCK_BUDGET:
+        bh *= 2
+    return bh
+
+
 def _cell_pallas(xproj, h, c, w_h2h, interpret):
-    if not _HAVE_PALLAS:
-        from ...base import MXNetError
-        raise MXNetError("pallas is unavailable in this jax install; use "
-                         "lstm_cell_fused(..., impl='jnp')")
     n, hdim = h.shape
+    out_shape = (jax.ShapeDtypeStruct((n, hdim), h.dtype),
+                 jax.ShapeDtypeStruct((n, hdim), c.dtype))
+    bh = _hidden_block(hdim)
+    if bh is None:
+        # whole-array blocks are always on the tiling; the weight is not
+        # pipelined, so a large unaligned H runs out of VMEM and the
+        # compiler says so
+        return pl.pallas_call(
+            _lstm_kernel_whole, out_shape=out_shape, interpret=interpret,
+            name="lstm_cell",
+        )(xproj, h, c, w_h2h)
+    nb = hdim // bh
+
+    # gate k's block j: columns of xproj / rows of w_h2h at k * nb + j
+    x_specs = [pl.BlockSpec((n, bh), lambda j, k=k: (0, k * nb + j))
+               for k in range(4)]
+    w_specs = [pl.BlockSpec((bh, hdim), lambda j, k=k: (k * nb + j, 0))
+               for k in range(4)]
+    state_spec = pl.BlockSpec((n, bh), lambda j: (0, j))
     return pl.pallas_call(
         _lstm_kernel,
-        out_shape=(jax.ShapeDtypeStruct((n, hdim), h.dtype),
-                   jax.ShapeDtypeStruct((n, hdim), c.dtype)),
+        grid=(nb,),
+        in_specs=x_specs + [pl.BlockSpec((n, hdim), lambda j: (0, 0)),
+                            state_spec] + w_specs,
+        out_specs=(state_spec, state_spec),
+        out_shape=out_shape,
         interpret=interpret,
-    )(xproj, h, c, w_h2h)
+        name="lstm_cell",
+    )(xproj, xproj, xproj, xproj, h, c, w_h2h, w_h2h, w_h2h, w_h2h)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -101,9 +163,10 @@ _cell.defvjp(_cell_fwd, _cell_bwd)
 
 def lstm_cell_fused(xproj, h, c, w_h2h, impl=None):
     """One LSTM step: (xproj (N,4H), h (N,H), c (N,H), w_h2h (4H,H)) ->
-    (h', c'). impl: None = auto (pallas on TPU, jnp elsewhere),
-    'pallas' | 'interpret' | 'jnp' to force."""
+    (h', c'). impl: None = the compiled Pallas kernel on a TPU backend,
+    the jnp cell elsewhere; 'pallas' | 'interpret' | 'jnp' to force. On
+    TPU the kernel either compiles or raises — it never gives way to the
+    jnp cell on its own."""
     if impl is None:
-        impl = "pallas" if (_HAVE_PALLAS
-                            and jax.default_backend() == "tpu") else "jnp"
+        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
     return _cell(xproj, h, c, w_h2h, impl)

@@ -15,10 +15,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import MXNetError
-from .compat import axis_size, shard_map
 
 __all__ = ["moe_apply", "moe_dense_apply", "top1_router", "topk_router",
            "load_balance_loss"]

@@ -22,10 +22,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import MXNetError
-from .compat import axis_size, shard_map
 
 __all__ = ["pipeline_apply", "pipeline_value_and_grad",
            "stack_stage_params", "pipeline_from_symbol",

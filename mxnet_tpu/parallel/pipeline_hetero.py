@@ -42,10 +42,11 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import MXNetError
-from .compat import axis_size, shard_map
 from .. import random as _random
 
 __all__ = ["hetero_pipeline_from_symbol"]

@@ -28,10 +28,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import MXNetError
-from .compat import axis_size, shard_map
 
 __all__ = ["ring_attention", "ulysses_attention", "sequence_sharded_attention"]
 

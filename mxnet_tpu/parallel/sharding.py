@@ -282,7 +282,7 @@ def zero_shard_spec(base: P, shape, mesh: Mesh,
 def zero_sharded_update(mesh: Mesh, data_axis: str, update, w, g, s,
                         lr, wd, t, param_spec: P, state_spec: P):
     """Run one parameter's optimizer update sharded over ``data_axis``
-    inside a :func:`~jax.experimental.shard_map.shard_map`.
+    inside a :func:`jax.shard_map`.
 
     The shard_map is the bitwise contract's load-bearing wall: its
     boundary specs are pinned, so the sliced update's layout demands
@@ -314,7 +314,7 @@ def zero_sharded_update(mesh: Mesh, data_axis: str, update, w, g, s,
                 and data_axis not in _spec_axes(pentries[i])), None)
     if dim is None:
         return update(w, g, s, lr, wd, t)
-    from .compat import shard_map
+    from jax import shard_map
     nshard = mesh.shape[data_axis]
 
     def body(w, g, s, lr, t):

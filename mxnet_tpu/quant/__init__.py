@@ -12,8 +12,7 @@ serving tier queues, pads, and dispatches.
   every persistent program key), and gate on *measured* accuracy — a
   model beyond the threshold ships fp32 with a typed
   :class:`QuantAccuracyWarning`, never silently wrong. fp8-ready: the
-  format registry (:data:`~.core.FORMATS`) adds ``fp8_e4m3`` wherever
-  the jax build carries the dtype.
+  format registry (:data:`~.core.FORMATS`) carries ``fp8_e4m3`` too.
 * **Measured low-precision training** (:mod:`.loss_scale` + the
   ``MXTPU_PRECISION=bf16`` mode in :mod:`mxnet_tpu.perf` /
   ``SPMDTrainer``): the bf16-master-weight compute cast as a

@@ -69,14 +69,9 @@ class QuantFormat:
 
 FORMATS: Dict[str, QuantFormat] = {
     "int8": QuantFormat("int8", np.int8, 127.0, 8),
+    # fp8: same scale/clip machinery, different storage dtype
+    "fp8_e4m3": QuantFormat("fp8_e4m3", jnp.float8_e4m3fn, 448.0, 8),
 }
-
-# fp8: same scale/clip machinery, different storage dtype — registered
-# only when this jax build carries the type, so requesting it on an
-# older build is a typed configuration error instead of an AttributeError
-if hasattr(jnp, "float8_e4m3fn"):
-    FORMATS["fp8_e4m3"] = QuantFormat("fp8_e4m3", jnp.float8_e4m3fn,
-                                      448.0, 8)
 
 
 def get_format(name: str) -> QuantFormat:
@@ -84,8 +79,7 @@ def get_format(name: str) -> QuantFormat:
     if fmt is None:
         raise MXNetError(
             f"unknown quantization format {name!r}; available: "
-            f"{sorted(FORMATS)} (fp8 formats register only on jax "
-            f"builds that carry the dtype)")
+            f"{sorted(FORMATS)}")
     return fmt
 
 

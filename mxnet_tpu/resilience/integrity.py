@@ -442,7 +442,7 @@ class IntegrityGuard:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.compat import shard_map
+        from jax import shard_map
         axes = tuple(mesh.axis_names)
         naxes = len(axes)
         in_specs = []

@@ -92,11 +92,8 @@ class SymbolicJitBackend:
     (:class:`~mxnet_tpu.compiler.symbolic.SymbolicBatchProgram`).
 
     ``load()`` exports the program with the leading dim symbolic up to
-    ``max_rows``; ``supports_symbolic_batch`` then reports whether the
-    export actually took (on a jax build without symbolic shapes the
-    backend silently degrades to per-shape jit dispatch and the server
-    keeps its dense bucket warm-up — capability is *probed*, never
-    assumed)."""
+    ``max_rows`` and sets ``supports_symbolic_batch``; a function that
+    cannot be exported that way raises there."""
 
     def __init__(self, fn: Callable, max_rows: int,
                  input_specs: Dict[str, Sequence[int]],
@@ -117,7 +114,7 @@ class SymbolicJitBackend:
         self.program = SymbolicBatchProgram(
             self.fn, self.input_specs, self.max_rows,
             input_dtypes=getattr(self, "input_dtypes", None))
-        self.supports_symbolic_batch = self.program.supported
+        self.supports_symbolic_batch = True
 
     def infer(self, arrays: Dict[str, np.ndarray]) -> List[np.ndarray]:
         if self.program is None:

@@ -1,4 +1,4 @@
-"""Serving-runtime error taxonomy (docs/how_to/serving.md).
+"""Serving-runtime error types (docs/how_to/serving.md).
 
 Every rejection the runtime can produce is a distinct, catchable type so
 callers (and the C predict ABI shim above them) can map them onto

@@ -562,49 +562,108 @@ def test_cache_kill_switch(tmp_cache, monkeypatch):
     assert not os.path.exists(tmp_cache) or not any(os.scandir(tmp_cache))
 
 
-def test_donated_programs_skip_persistence_by_default(tmp_cache,
-                                                      monkeypatch):
-    """Calling a deserialized DONATED executable corrupts the heap on
-    this jax build for some program shapes (scan-carrying whole-step
-    programs) — donated call sites must not touch the persistent store
-    unless MXTPU_COMPILE_CACHE_DONATED=1 opts in explicitly."""
+def test_donated_programs_persist(tmp_cache):
+    """Donated call sites (every training step) use the persistent
+    store like any other program, and a warm load runs them."""
     import jax.numpy as jnp
 
     def f(xs):
         return [x + 1 for x in xs]
 
-    pj = compiler.PersistentJit(f, kind="donated", key_parts=("d",),
-                                donate_argnums=(0,))
-    pj([jnp.ones(3)])
-    assert compiler.stats()["cache"]["writes"] == 0
-    # the opt-in enables the store for backends where it is proven safe
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DONATED", "1")
-    pj2 = compiler.PersistentJit(f, kind="donated", key_parts=("d2",),
-                                 donate_argnums=(0,))
-    pj2([jnp.ones(3)])
+    def fresh():
+        return compiler.PersistentJit(f, kind="donated", key_parts=("d",),
+                                      donate_argnums=(0,))
+
+    fresh()([jnp.ones(3)])
     assert compiler.stats()["cache"]["writes"] == 1
+    (warm,) = fresh()([jnp.ones(3)])
+    assert np.array_equal(np.asarray(warm), np.full(3, 2.0))
+    assert compiler.stats()["programs"]["loaded"] == 1
 
 
-def test_donated_persistence_default_gated_by_jax_version(monkeypatch):
-    """The donated-program default is a jax-VERSION gate, not a blanket
-    off: the 0.4.x line's deserialize_and_load drops donation aliasing
-    (serialize_executable.py:57 — heap corruption on CPU, re-bisected),
-    the 0.5 line rewrote that path. The env knob forces either way."""
-    from mxnet_tpu.compiler import aot
-    monkeypatch.delenv("MXTPU_COMPILE_CACHE_DONATED", raising=False)
+def test_warm_load_keeps_the_programs_own_devices(tmp_cache):
+    """A warm load hands deserialize_and_load the program's own device
+    assignment: a one-device program compiled for device 3 of the
+    8-device host loads back onto device 3 alone (without
+    execution_devices it is spread over all 8 and dies on 'expected 8
+    shards'), and a 4-device mesh program keeps its device order."""
     import jax
-    broken = aot._donated_deserialize_broken()
-    assert broken == (aot._jax_version_tuple() < (0, 5, 0))
-    pj = compiler.PersistentJit(lambda xs: [x + 1 for x in xs],
-                                kind="gate", key_parts=("g",),
-                                donate_argnums=(0,))
-    assert pj._persist_ok() == (not broken)
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DONATED", "1")
-    assert pj._persist_ok() is True
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DONATED", "0")
-    assert pj._persist_ok() is False
-    assert aot._jax_version_tuple()[:2] == tuple(
-        int(p) for p in jax.__version__.split(".")[:2])
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    def f(x):
+        return x * 2 + 1
+
+    devs = jax.devices()
+    x1 = jax.device_put(jnp.arange(4.0), devs[3])
+    mesh = Mesh(np.array(devs[4:8][::-1]), ("data",))
+    x4 = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("data")))
+    for x in (x1, x4):
+        compiler.PersistentJit(f, kind="devs", key_parts=("p",))(x)
+    assert compiler.stats()["programs"]["compiled"] == 2
+    for x in (x1, x4):
+        warm = compiler.PersistentJit(f, kind="devs", key_parts=("p",))(x)
+        assert np.array_equal(np.asarray(warm), np.asarray(x) * 2 + 1)
+        assert warm.sharding.device_set == x.sharding.device_set
+    st = compiler.stats()["programs"]
+    assert st["loaded"] == 2 and st["invalid_load"] == 0, st
+
+
+@pytest.fixture
+def jax_cache_takes_everything(tmp_path):
+    """JAX's own persistent cache in a tmp root, every compile written."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names}
+    for n, v in zip(names, (str(tmp_path / "jax"), 0.0, -1)):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_store_keeps_out_what_jax_cache_served(tmp_cache,
+                                               jax_cache_takes_everything):
+    """A compile that JAX's persistent cache answers hands back a
+    deserialized executable; XLA:CPU serializes that again without its
+    kernels ('Function <fusion> not found' when the reloaded entry
+    runs). The store leaves such a program to JAX's cache."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(w, idx, x):
+        e = jnp.transpose(jnp.take(w, idx, axis=0), (1, 0, 2)) * 2.0
+
+        def step(c, t):
+            c = jnp.tanh(c @ x + t)
+            return c, c
+        c, ys = jax.lax.scan(step, jnp.zeros((4, 16), jnp.float32), e)
+        return ys.sum(0) + c
+
+    rng = np.random.RandomState(0)
+    args = (jnp.asarray(rng.rand(50, 16), jnp.float32),
+            jnp.asarray(rng.randint(0, 50, (4, 7))),
+            jnp.asarray(rng.rand(16, 16), jnp.float32))
+
+    def run(key):
+        jax.clear_caches()           # as a new process: nothing in memory
+        return np.asarray(compiler.PersistentJit(
+            f, kind="two-caches", key_parts=(key,))(*args))
+
+    want = run("a")                  # fresh compile: both caches write
+    assert compiler.stats()["cache"]["writes"] == 1
+    for _ in range(2):               # same HLO, new store key: JAX answers
+        assert np.array_equal(run("b"), want)
+    st = compiler.stats()
+    assert st["programs"]["jax_cache_served"] == 2, st
+    assert st["cache"]["writes"] == 1 and st["programs"]["loaded"] == 0, st
+    assert np.array_equal(run("a"), want)        # the store's own entry
+    assert compiler.stats()["programs"]["loaded"] == 1
 
 
 def test_persistent_jit_warm_load_skips_tracing(tmp_cache):

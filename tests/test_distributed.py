@@ -49,7 +49,7 @@ _WORKER = textwrap.dedent("""
 
     # global mesh spans both processes; a sharded psum sees every device
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from mxnet_tpu.parallel.compat import shard_map
+    from jax import shard_map
     mesh = dist.global_mesh({"world": 4})
     fn = jax.jit(shard_map(
         lambda x: jax.lax.psum(x, "world"), mesh=mesh,

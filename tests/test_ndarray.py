@@ -146,6 +146,28 @@ def test_context():
     a.wait_to_read()
 
 
+def test_requested_accelerator_is_that_chip_or_an_error(monkeypatch):
+    """tpu(i)/gpu(i) on a host WITH accelerators is device i or an
+    MXNetError, never i % n on another chip; with none (this CPU mesh)
+    the MXNet-compat mapping onto the virtual devices stays."""
+    from mxnet_tpu import context
+
+    class Chip:
+        platform = "tpu"
+
+    chips = [Chip(), Chip()]
+    cpus = context._jax_devices("cpu")
+    assert mx.gpu(9).jax_device is cpus[9 % len(cpus)]      # compat mapping
+    monkeypatch.setattr(
+        context, "_jax_devices",
+        lambda kind: cpus if kind == "cpu" else chips)
+    assert mx.tpu(1).jax_device is chips[1]
+    assert mx.gpu(0).jax_device is chips[0]
+    with pytest.raises(mx.MXNetError, match="2 accelerator"):
+        mx.tpu(2).jax_device
+    assert mx.cpu(0).jax_device is cpus[0]
+
+
 def test_concat_split():
     a = nd.ones((2, 3))
     b = nd.zeros((2, 3))

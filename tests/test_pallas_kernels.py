@@ -73,17 +73,31 @@ def test_flash_attention_causal_rejects_longer_queries():
         flash_attention(q, k, v, causal=True, force_pallas=True)
 
 
-def test_lstm_cell_interpret_matches_jnp():
+@pytest.mark.parametrize("n,hd,dtype,tol,block", [
+    (8, 16, np.float32, 1e-5, None),      # whole-array kernel (H % 128 != 0)
+    (8, 200, jnp.bfloat16, 2e-2, None),   # whole-array kernel, bf16 operands
+    (8, 1024, np.float32, 1e-4, 256),     # 4 grid steps of 256 hidden units
+    (16, 256, jnp.bfloat16, 2e-2, 256),   # native bf16 projection, f32 gates
+], ids=["h16-f32-whole", "h200-bf16-whole", "h1024-f32-tiled", "h256-bf16"])
+def test_lstm_cell_interpret_matches_jnp(n, hd, dtype, tol, block):
+    from mxnet_tpu.ops.pallas.lstm import _hidden_block
     rng = np.random.RandomState(4)
-    n, hd = 8, 16
-    xproj = jnp.asarray(rng.normal(0, 1, (n, 4 * hd)).astype(np.float32))
-    h = jnp.asarray(rng.normal(0, 1, (n, hd)).astype(np.float32))
-    c = jnp.asarray(rng.normal(0, 1, (n, hd)).astype(np.float32))
-    w = jnp.asarray(rng.normal(0, 0.5, (4 * hd, hd)).astype(np.float32))
+    scale = 0.5 / np.sqrt(hd / 16)
+
+    def arr(shape, s=1.0):
+        return jnp.asarray(rng.normal(0, s, shape).astype(np.float32)
+                           ).astype(dtype)
+
+    xproj, h, c = arr((n, 4 * hd)), arr((n, hd)), arr((n, hd))
+    w = arr((4 * hd, hd), scale)
+    assert _hidden_block(hd) == block
     h_j, c_j = lstm_cell_fused(xproj, h, c, w, impl="jnp")
     h_p, c_p = lstm_cell_fused(xproj, h, c, w, impl="interpret")
-    np.testing.assert_allclose(np.asarray(h_p), np.asarray(h_j), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(c_p), np.asarray(c_j), rtol=1e-5)
+    for got, want in ((h_p, h_j), (c_p, c_j)):
+        assert got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
 
 
 def test_lstm_cell_custom_vjp_matches_autodiff():

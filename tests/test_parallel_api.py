@@ -17,17 +17,6 @@ from mxnet_tpu.parallel import (SPMDTrainer, make_mesh, mesh_scope,
                                 pipeline_from_symbol)
 
 
-# the manual-SPMD paths run through parallel/compat.shard_map, which
-# adapts to either jax.shard_map (new API) or
-# jax.experimental.shard_map (the 0.4.x line) — skip only when a build
-# carries neither
-from mxnet_tpu.parallel.compat import has_shard_map
-
-requires_shard_map = pytest.mark.skipif(
-    not has_shard_map(),
-    reason="no shard_map implementation in this jax build")
-
-
 def _manual_attention(q, k, v, num_heads, causal):
     B, S, E = q.shape
     H, D = num_heads, E // num_heads
@@ -57,7 +46,6 @@ def test_mha_op_matches_manual():
             rtol=1e-4, atol=1e-5)
 
 
-@requires_shard_map
 @pytest.mark.parametrize("mode", ["ring", "ulysses"])
 def test_mha_op_sequence_parallel_matches_full(mode):
     rng = np.random.RandomState(1)
@@ -72,7 +60,6 @@ def test_mha_op_sequence_parallel_matches_full(mode):
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-5)
 
 
-@requires_shard_map
 def test_gluon_mha_layer_mesh_transparent():
     rng = np.random.RandomState(2)
     x = mx.nd.array(rng.randn(2, 16, 32).astype(np.float32))
@@ -89,7 +76,6 @@ def test_gluon_mha_layer_mesh_transparent():
     np.testing.assert_allclose(out_h, ref, rtol=1e-4, atol=1e-5)
 
 
-@requires_shard_map
 def test_transformer_lm_4d_training_converges():
     """dp=2 x tp=2 x sp=2 + ZeRO optimizer sharding, all via public API."""
     B, S, V = 8, 16, 64
@@ -136,7 +122,6 @@ def _staged_mlp(n_stages, d):
     return h
 
 
-@requires_shard_map
 def test_pipeline_from_symbol_matches_executor():
     d, n = 16, 4
     sym = _staged_mlp(n, d)
@@ -174,7 +159,6 @@ def test_pipeline_from_symbol_matches_executor():
     assert float(l1) < float(l0) * 0.5
 
 
-@requires_shard_map
 def test_pipeline_from_symbol_ragged_delegates_to_hetero():
     """Non-isomorphic stages used to be rejected; they now route to the
     heterogeneous flat-buffer pipeline and produce executor-exact
@@ -217,7 +201,6 @@ def test_pipeline_from_symbol_rejects_bad_graphs():
         pipeline_from_symbol(plain, mesh)
 
 
-@requires_shard_map
 def test_executor_retraces_on_mesh_change():
     """ADVICE r2: the executor's compiled program is keyed on the ambient
     mesh. A graph first run OUTSIDE mesh_scope must not keep running the
@@ -278,7 +261,6 @@ def _pipelined_lm_symbol(V, D, n_stages):
     return out
 
 
-@requires_shard_map
 def test_pipeline_heterogeneous_model_1f1b_trains():
     """Embedding->blocks->head pipelines (prologue/epilogue outside the
     isomorphic body) and the 1F1B train_step converges; gradients match

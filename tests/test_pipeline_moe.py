@@ -2,21 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from mxnet_tpu.parallel import make_mesh
 from mxnet_tpu.parallel.moe import moe_apply, top1_router
 from mxnet_tpu.parallel.pipeline import pipeline_apply, stack_stage_params
-
-# every test in this file drives pipeline/moe paths that run through
-# parallel/compat.shard_map, which adapts to either jax.shard_map (new
-# API) or jax.experimental.shard_map (the 0.4.x line) — skip only when
-# a build carries neither
-from mxnet_tpu.parallel.compat import has_shard_map
-
-pytestmark = pytest.mark.skipif(
-    not has_shard_map(),
-    reason="no shard_map implementation in this jax build")
 
 
 def _stage(params, h):

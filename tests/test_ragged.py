@@ -27,7 +27,6 @@ import mxnet_tpu as mx
 from mxnet_tpu import resilience, serving
 from mxnet_tpu.compiler import GraphIR, batch_signature
 from mxnet_tpu.compiler.symbolic import (SymbolicBatchProgram,
-                                         symbolic_dims_supported,
                                          symbolic_transform_sig)
 from mxnet_tpu.ops.pallas.attention import flash_attention
 from mxnet_tpu.resilience import faults
@@ -222,13 +221,10 @@ def test_graphir_symbolic_dims_declaration_and_signature():
     assert symbolic_transform_sig(["data"], 16) == "symdims=data@0<=16"
 
 
-@pytest.mark.skipif(not symbolic_dims_supported(),
-                    reason="jax.export symbolic shapes unavailable")
 def test_symbolic_batch_program_one_compile_any_rows():
     prog = SymbolicBatchProgram(
         lambda arrays: [arrays["data"] * 2.0 + arrays["bias"]],
         {"data": (3,), "bias": (3,)}, max_rows=8)
-    assert prog.supported
     for rows in (1, 3, 8):
         feed = {"data": np.full((rows, 3), 2.0, np.float32),
                 "bias": np.ones((rows, 3), np.float32)}
@@ -238,17 +234,13 @@ def test_symbolic_batch_program_one_compile_any_rows():
     assert prog.transform_sig == "symdims=bias@0<=8,data@0<=8"
 
 
-def test_symbolic_batch_program_fallback_counts_shapes(monkeypatch):
-    import mxnet_tpu.compiler.symbolic as sym_mod
-    monkeypatch.setattr(sym_mod, "_SUPPORTED", False)
-    prog = SymbolicBatchProgram(lambda arrays: [arrays["data"] * 2.0],
-                                {"data": (3,)}, max_rows=8)
-    assert not prog.supported
-    for rows in (1, 3, 3, 8):
-        (out,) = prog({"data": np.ones((rows, 3), np.float32)})
-        np.testing.assert_array_equal(out, np.full((rows, 3), 2.0))
-    assert prog.compiles == 3                    # distinct row counts
-    assert prog.transform_sig == ""              # concrete identity
+def test_symbolic_batch_program_refuses_a_concrete_batch_fn():
+    """A function that pins the batch dim cannot serve a range; it
+    fails at construction instead of degrading to per-shape jit."""
+    with pytest.raises(ValueError):
+        SymbolicBatchProgram(
+            lambda arrays: [arrays["data"].reshape(8, 3) * 2.0],
+            {"data": (3,)}, max_rows=8)
 
 
 # ---------------------------------------------------------------------------
@@ -391,8 +383,6 @@ def test_packed_oversize_and_multirow_rejected_at_admission():
     assert sum(st["queue"]["shape_histogram"].values()) >= 2
 
 
-@pytest.mark.skipif(not symbolic_dims_supported(),
-                    reason="jax.export symbolic shapes unavailable")
 def test_symbolic_backend_collapses_warmup_zero_retrace(monkeypatch):
     monkeypatch.setenv("MXTPU_RETRACE_STRICT", "1")
     clock = FakeClock()
@@ -562,19 +552,25 @@ def test_flash_attention_segment_mask_matches_per_segment_dense():
     np.testing.assert_array_equal(out[0, :, 7], 0.0)
 
 
-def test_flash_attention_masked_pallas_interpret_matches_reference():
+@pytest.mark.parametrize("h,s,d,bq,bk", [(1, 8, 4, 8, 8), (3, 32, 8, 8, 16)],
+                         ids=["one-block", "heads-and-blocks"])
+def test_flash_attention_masked_pallas_interpret_matches_reference(
+        h, s, d, bq, bk):
     from mxnet_tpu.ops.pallas.attention import _masked_reference
     rng = np.random.default_rng(3)
-    q, k, v = _rand_qkv(rng, 2, 1, 8, 4)
-    lengths = np.array([5, 8], np.int32)
-    seg = np.array([[1, 1, 2, 2, 2, 0, 0, 0],
-                    [1, 1, 1, 1, 2, 2, 2, 2]], np.int32)
+    q, k, v = _rand_qkv(rng, 2, h, s, d)
+    lengths = np.array([5, s], np.int32)
+    seg = np.zeros((2, s), np.int32)
+    seg[0, :2], seg[0, 2:5] = 1, 2                  # tail stays pad (0)
+    seg[1, :s // 2], seg[1, s // 2:] = 1, 2
     for kw in ({"lengths": lengths},
                {"segment_ids": seg},
                {"lengths": lengths, "segment_ids": seg, "causal": True}):
         got = np.asarray(flash_attention(q, k, v, force_pallas=True,
-                                         block_q=8, block_k=8, **kw))
+                                         block_q=bq, block_k=bk, **kw))
         ref = np.asarray(_masked_reference(
             q, k, v, kw.get("lengths"), kw.get("segment_ids"),
-            kw.get("causal", False), 1.0 / 2.0))
+            kw.get("causal", False), 1.0 / d ** 0.5))
         np.testing.assert_allclose(got, ref, atol=1e-5)
+        if "segment_ids" in kw:         # fully-masked rows: exact 0
+            np.testing.assert_array_equal(got[0, :, 5:], 0.0)

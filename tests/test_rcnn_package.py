@@ -3,7 +3,7 @@
 Reference analogue: the reference ships rcnn/ as an importable package
 (dataset/imdb.py, core/loader.py, dataset/pascal_voc_eval.py); these
 tests pin the same contracts on our examples/rcnn modules without
-running full training (the training gates live in test_examples.py).
+running full training (the training gates live in _example_cases.py).
 """
 import os
 import sys

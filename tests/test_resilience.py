@@ -495,9 +495,11 @@ def test_fit_kill_mid_write_then_auto_resume_completes(tmp_path):
     np.random.seed(0)
     mx.random.seed(0)
     victim = mx.mod.Module(_mlp(), context=mx.cpu())
-    # epoch-1 checkpoint writes 3 files + manifest = 4 passes of the
-    # checkpoint.write site; the kill fires during epoch 2's checkpoint
-    faults.arm(FaultPlan().arm("checkpoint.write", nth=5, exc="kill",
+    # epoch-1 checkpoint writes 4 files (symbol, params, states, and
+    # since the iterator became checkpointable its iter.json) + manifest
+    # = 5 passes of the checkpoint.write site; the kill fires during
+    # epoch 2's checkpoint
+    faults.arm(FaultPlan().arm("checkpoint.write", nth=6, exc="kill",
                                count=99))
     with pytest.raises(InjectedKill):
         _fit(victim, make_iter(), 3, checkpoint_prefix=prefix)

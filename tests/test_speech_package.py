@@ -3,7 +3,7 @@
 Reference analogue: the reference decomposes speech_recognition into
 config_util + arch_deepspeech + stt_layer_* + stt_io_bucketingiter;
 these tests pin those contracts on our examples/speech modules without
-full training (the WER convergence gate lives in test_examples.py).
+full training (the WER convergence gate lives in _example_cases.py).
 """
 import os
 import sys

@@ -1,0 +1,130 @@
+"""The main path's Pallas kernels, compiled at their real widths for a
+described TPU v5e (2x2, nothing attached): what the chip's compiler
+refuses — a block off the (8, 128) tiling, more scoped VMEM than a
+kernel may take — fails here, at no chip time.
+
+Nothing runs, so these say nothing about results; interpret-mode parity
+lives in test_pallas_kernels.py / test_ragged.py and the on-chip
+comparison in chip_smoke.py. ``jax.default_backend()`` is still ``cpu``
+in this process, so each case calls the function that builds the
+``pallas_call`` (explicit ``interpret=False``), not its dispatcher.
+
+The topology is described inside module-scoped fixtures only (guide
+on-chip-measurement §2): one process at a time may load the TPU
+library, every xdist worker imports this file, and only the worker that
+runs it may make the call. Keep every such test in THIS file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops.pallas import attention, lstm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without the chip (the next run warns
+    and recompiles): keep the cache off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _lstm_cell(n, hdim, dtype):
+    def build(struct):
+        args = (struct((n, 4 * hdim), dtype), struct((n, hdim), dtype),
+                struct((n, hdim), dtype), struct((4 * hdim, hdim), dtype))
+        return (lambda x, h, c, w: lstm._cell_pallas(
+            x, h, c, w, interpret=False)), args
+    return build
+
+
+def _dense(b, h, s, d, backward):
+    scale = 1.0 / d ** 0.5
+
+    def fwd(q, k, v):
+        return attention._flash_dense_pallas(q, k, v, True, scale, 256, 512,
+                                             interpret=False)
+
+    def fwd_bwd(q, k, v, do):
+        # what jax.grad of flash_attention runs on the chip: the kernel
+        # forward, then the blockwise jnp backward of its custom_vjp
+        out = fwd(q, k, v)
+        return out, attention._blockwise_bwd(q, k, v, out, do, True, scale,
+                                             512)
+
+    def build(struct):
+        qkv = struct((b, h, s, d), jnp.bfloat16)
+        if backward:
+            return fwd_bwd, (qkv, qkv, qkv, qkv)
+        return fwd, (qkv, qkv, qkv)
+    return build
+
+
+def _masked(b, h, s, d, with_lengths):
+    scale = 1.0 / d ** 0.5
+
+    def build(struct):
+        qkv = struct((b, h, s, d), jnp.bfloat16)
+        if with_lengths:
+            return (lambda q, k, v, lens: attention._masked_pallas(
+                q, k, v, lens, None, True, scale, 256, 512,
+                interpret=False)), (qkv, qkv, qkv, struct((b,), jnp.int32))
+        return (lambda q, k, v, seg: attention._masked_pallas(
+            q, k, v, None, seg, False, scale, 256, 512,
+            interpret=False)), (qkv, qkv, qkv, struct((b, s), jnp.int32))
+    return build
+
+
+_CASES = {
+    "lstm-n64-h1024-f32": _lstm_cell(64, 1024, jnp.float32),
+    "lstm-n64-h1024-bf16": _lstm_cell(64, 1024, jnp.bfloat16),
+    "lstm-n64-h2048-bf16": _lstm_cell(64, 2048, jnp.bfloat16),
+    # H off the 128-lane tiling takes the whole-array kernel:
+    # examples/rnn/lstm_bucketing.py's default, an odd width, PTB-medium
+    "lstm-n32-h64-f32": _lstm_cell(32, 64, jnp.float32),
+    "lstm-n32-h200-bf16": _lstm_cell(32, 200, jnp.bfloat16),
+    "lstm-n20-h650-f32": _lstm_cell(20, 650, jnp.float32),
+    "flash-b4h16s2048d64-fwd": _dense(4, 16, 2048, 64, backward=False),
+    "flash-b4h16s2048d64-fwd-bwd": _dense(4, 16, 2048, 64, backward=True),
+    "flash-b1h8s32768d128-fwd": _dense(1, 8, 32768, 128, backward=False),
+    "masked-b4h16s2048d64-lengths": _masked(4, 16, 2048, 64, True),
+    "masked-b4h16s2048d64-segment-ids": _masked(4, 16, 2048, 64, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = _CASES[case](struct)
+    # conftest turns x64 on for the numeric checks; the chip runs with it
+    # off, and Mosaic takes no int64 block index
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -13,6 +13,11 @@ rely on jax's native cloud auto-detection.
     python tools/launch.py -n 4 python my_training_script.py
 
 Each process must call mxnet_tpu.parallel.dist.init_process_group().
+
+One process drives all the local chips of a host, and a chip belongs to
+one process at a time: ``-n N`` with N > 1 on ONE host is the CPU mode
+(``JAX_PLATFORMS=cpu``). On a host with accelerators every local worker
+would try to open the same chips, and all but the first fail or hang.
 """
 import argparse
 import os
