@@ -24,11 +24,22 @@ counted (``unserializable``) and stays in-process.
 ``on_materialize(kind)`` (kind in ``{"compiled", "loaded"}``) fires once
 per new executable so retrace guards can count a cache load as the one
 expected program materialization instead of reporting a missed compile.
+
+Beside each executable it compiles, the store keeps that program's **op
+map**: ``{HLO instruction name: op_name path}`` parsed once from
+``compiled.as_text()`` (:func:`parse_op_map`). The path is what
+``jax.named_scope`` left in the instruction's metadata, so the names a
+device trace gives its operations (``fusion.2089``) read back as the graph
+op that emitted them (``jvp(Convolution/stage1_unit1_conv1)``). A warm load
+pays nothing for it; :func:`op_map` reads it back on demand.
 """
 from __future__ import annotations
 
+import contextlib
+import json
 import logging
 import pickle
+import re
 import threading
 from collections import OrderedDict
 from typing import Callable, Dict, Optional, Sequence
@@ -41,7 +52,7 @@ from . import cache as _cache
 from .fingerprint import aval_signature, program_key
 
 __all__ = ["PersistentJit", "ProgramRegistry", "program_stats",
-           "reset_program_stats"]
+           "reset_program_stats", "op_map", "parse_op_map"]
 
 _lock = threading.Lock()
 _prog_counters: Dict[str, int] = {}
@@ -83,6 +94,94 @@ def _on_jax_event(event, **_):
 
 
 jax.monitoring.register_event_listener(_on_jax_event)
+
+
+# -- the op map --------------------------------------------------------------
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'\bmetadata=\{[^}\n]*?\bop_name="([^"\n]*)"')
+
+# kind -> (store key, parsed map or None) of the program of that kind this
+# process materialized last; None until :func:`op_map` has read it back
+_materialized: Dict[str, tuple] = {}
+
+
+def parse_op_map(hlo_text: str):
+    """``({instruction name: op_name}, instructions)`` from the text of a
+    compiled HLO module: every instruction of every computation that
+    carries an ``op_name`` (parameters left out), under its name without
+    the ``%``; ``instructions`` counts the non-parameter instructions,
+    named or not."""
+    ops, total = {}, 0
+    for line in hlo_text.splitlines():
+        hit = _INSTRUCTION.match(line)
+        if hit is None or " parameter(" in line:
+            continue
+        total += 1
+        name = _OP_NAME.search(line, hit.end())
+        if name is not None:
+            ops[hit.group(1)] = name.group(1)
+    return ops, total
+
+
+def _op_map_key(key: str) -> str:
+    return key + "-ops"
+
+
+def _keep_op_map(store, kind: str, key: str, compiled):
+    """Parse and store the op map of a program just compiled."""
+    try:
+        ops, total = parse_op_map(compiled.as_text())
+    except Exception as err:    # noqa: BLE001 — names only; never the run
+        logging.info("PersistentJit[%s]: no op map (%s: %s)", kind,
+                     type(err).__name__, err)
+        return
+    logging.info("PersistentJit[%s]: op map names %d of %d instructions",
+                 kind, len(ops), total)
+    store.put(_op_map_key(key), json.dumps(ops).encode(),
+              meta={"kind": kind, "op_map_of": key}, counter="op_maps")
+    _materialized[kind] = (key, ops)
+
+
+_METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
+_metadata_in_key = [0, False]   # compiles under way; the setting they found
+
+
+@contextlib.contextmanager
+def _metadata_in_jax_key():
+    """While a PersistentJit compiles, JAX's own persistent cache keys on
+    the module *with* its locations. By default it leaves them out, and a
+    named scope is a location: a program would be served the executable of
+    its twin compiled before a scope was added (by the checkout before,
+    on the same cache directory), whose op_names name nothing, and the op
+    map read from it would be empty. Only these compiles pay: the
+    executable store answers for them first, under a key that holds no
+    line of anybody's script, so their warm start does not depend on
+    JAX's cache; every other ``jax.jit`` keeps JAX's default key."""
+    with _lock:
+        if _metadata_in_key[0] == 0:
+            _metadata_in_key[1] = getattr(jax.config, _METADATA_IN_KEY)
+            jax.config.update(_METADATA_IN_KEY, True)
+        _metadata_in_key[0] += 1
+    try:
+        yield
+    finally:
+        with _lock:
+            _metadata_in_key[0] -= 1
+            if _metadata_in_key[0] == 0:
+                jax.config.update(_METADATA_IN_KEY, _metadata_in_key[1])
+
+
+def op_map(kind: str) -> Dict[str, str]:
+    """The op map of the program of ``kind`` this process compiled or
+    loaded last; empty where there is none (store off, program compiled
+    before op maps were kept, entry evicted)."""
+    key, ops = _materialized.get(kind, (None, None))
+    if key is not None and ops is None:
+        data = _cache.default_cache().get(_op_map_key(key))
+        ops = json.loads(data) if data is not None else {}
+        _materialized[kind] = (key, ops)
+    return ops or {}
 
 
 class PersistentJit:
@@ -200,6 +299,7 @@ class PersistentJit:
                     payload, in_tree, out_tree,
                     execution_devices=_devices_by_id(device_ids))
                 self._notify("loaded")
+                _materialized[self.kind] = (key, None)
                 return self._wrap_compiled(compiled)
             except Exception as err:    # noqa: BLE001 — entry unusable here
                 logging.warning("PersistentJit[%s]: cached executable "
@@ -212,7 +312,8 @@ class PersistentJit:
                 _count("invalid_load")
         jax_hits = getattr(_jax_cache_hits, "n", 0)
         try:
-            compiled = self._jit.lower(*args).compile()
+            with _metadata_in_jax_key():
+                compiled = self._jit.lower(*args).compile()
         except Exception as err:        # noqa: BLE001 — AOT-unfriendly call
             # loud, counted, and the same call then goes through the
             # plain jit, which raises the real error if there is one
@@ -224,6 +325,7 @@ class PersistentJit:
             self._disabled = True       # don't re-pay the sig walk per call
             return self._jit
         self._notify("compiled")
+        _keep_op_map(store, self.kind, key, compiled)
         if getattr(_jax_cache_hits, "n", 0) != jax_hits:
             # JAX's cache served it and keeps serving it; see above
             _count("jax_cache_served")

@@ -230,10 +230,13 @@ class CompilationCache:
 
     # -- write ---------------------------------------------------------------
 
-    def put(self, key: str, data: bytes, meta: Optional[dict] = None):
+    def put(self, key: str, data: bytes, meta: Optional[dict] = None,
+            counter: str = "writes"):
         """Atomically store ``data`` under ``key`` + its manifest, then
         enforce the size bound. Failures are logged, never raised — a
-        full or read-only disk costs the warm start, not the run."""
+        full or read-only disk costs the warm start, not the run.
+        ``counter`` names the statistic the write is counted under
+        (``writes`` are executables)."""
         from ..resilience.checkpoint import atomic_write_bytes, file_digest
         bin_path, man_path = self._paths(key)
         try:
@@ -248,7 +251,7 @@ class CompilationCache:
                        "meta": meta or {}}
                 atomic_write_bytes(man_path, json.dumps(
                     doc, indent=1, sort_keys=True).encode("utf-8"))
-            _count("writes")
+            _count(counter)
             if self._approx_bytes is None:
                 self._approx_bytes = self.total_bytes()
             else:

@@ -53,6 +53,30 @@ def _is_placed(group2ctx):
     return len(set(_resolve_group_devs(group2ctx).values())) >= 2
 
 
+def _call_node(node, ins, rng, rng_index, is_train):
+    """The one place a graph op is called while a program traces: under
+    ``jax.named_scope("<op>/<node>")`` (``Convolution/stage1_unit1_conv1``),
+    so every HLO instruction it emits, forward and transposed, carries the
+    graph's own name in its ``op_name`` (read back through
+    ``profiler.op_scopes``). A scope exists at trace time only. Returns the
+    node's outputs as a tuple."""
+    call_attrs = dict(node.attrs)
+    op = node.op
+    if op.needs_is_train:
+        call_attrs["_is_train"] = is_train
+    if op.key_var_num_args and not call_attrs.get(op.key_var_num_args):
+        call_attrs[op.key_var_num_args] = len(ins)
+    with jax.named_scope(f"{op.name}/{node.name}"):
+        if id(node) in rng_index:
+            key = jax.random.fold_in(rng, rng_index[id(node)])
+            out = op.fn(key, *ins, **call_attrs)
+        elif op.needs_rng:
+            out = op.fn(rng, *ins, **call_attrs)
+        else:
+            out = op.fn(*ins, **call_attrs)
+    return out if isinstance(out, tuple) else (out,)
+
+
 def build_graph_eval(symbol, collect_all=False, proxies=None):
     """Build eval_fn(arg_vals: dict, aux_vals: dict, rng, is_train)
     -> (outputs: list, aux_updates: dict). Pure and jax-traceable.
@@ -91,20 +115,7 @@ def build_graph_eval(symbol, collect_all=False, proxies=None):
                     values[(id(node), 0)] = arg_vals[node.name]
                 continue
             ins = [values[(id(p), i)] for p, i in node.inputs]
-            call_attrs = dict(node.attrs)
-            if node.op.needs_is_train:
-                call_attrs["_is_train"] = is_train
-            if node.op.key_var_num_args and not call_attrs.get(node.op.key_var_num_args):
-                call_attrs[node.op.key_var_num_args] = len(ins)
-            if id(node) in rng_index:
-                key = jax.random.fold_in(rng, rng_index[id(node)])
-                out = node.op.fn(key, *ins, **call_attrs)
-            elif node.op.needs_rng:
-                out = node.op.fn(rng, *ins, **call_attrs)
-            else:
-                out = node.op.fn(*ins, **call_attrs)
-            if not isinstance(out, tuple):
-                out = (out,)
+            out = _call_node(node, ins, rng, rng_index, is_train)
             pname = proxies.get(id(node))
             if pname is not None and pname in arg_vals:
                 out = (out[0] + arg_vals[pname],) + out[1:]
@@ -222,21 +233,7 @@ def build_placed_graph_eval(symbol, group2dev):
             aux_updates = {}
             for node in _seg_nodes:
                 ins = [values[(id(p), i)] for p, i in node.inputs]
-                call_attrs = dict(node.attrs)
-                if node.op.needs_is_train:
-                    call_attrs["_is_train"] = is_train
-                if node.op.key_var_num_args and not call_attrs.get(
-                        node.op.key_var_num_args):
-                    call_attrs[node.op.key_var_num_args] = len(ins)
-                if id(node) in rng_index:
-                    key = jax.random.fold_in(rng, rng_index[id(node)])
-                    out = node.op.fn(key, *ins, **call_attrs)
-                elif node.op.needs_rng:
-                    out = node.op.fn(rng, *ins, **call_attrs)
-                else:
-                    out = node.op.fn(*ins, **call_attrs)
-                if not isinstance(out, tuple):
-                    out = (out,)
+                out = _call_node(node, ins, rng, rng_index, is_train)
                 for i, o in enumerate(out):
                     values[(id(node), i)] = o
                 if is_train and node.op.aux_update:
@@ -601,8 +598,7 @@ class Executor:
         # global key chain untouched — they draw nothing from it)
         rng = _random.next_key() if self._needs_rng else _null_key()
         from . import profiler as _profiler
-        with _profiler.profile_scope("Forward", "executor", "symbolic",
-                                     sync=lambda: outs):
+        with _profiler.profile_scope("Forward", "executor", "symbolic"):
             outs, aux_up = self._fwd(arg_vals, aux_vals, rng, bool(is_train),
                                      _ambient_mesh_key())
         if is_train:
@@ -641,7 +637,7 @@ class Executor:
         dense_diff = tuple(n for n in self._diff_args if n not in sparse_w)
         from . import profiler as _profiler
         with _profiler.profile_scope("ForwardBackward", "executor",
-                                     "symbolic", sync=lambda: grads):
+                                     "symbolic"):
             outs, aux_up, grads, proxy_grads = self._fwd_bwd(
                 arg_vals, aux_vals, rng, head_grads, dense_diff,
                 _ambient_mesh_key())

@@ -14,6 +14,7 @@ from typing import List, Optional
 
 import numpy as _np
 
+from . import profiler as _profiler
 from .base import MXNetError
 from .ndarray import NDArray, array as nd_array
 from .resilience import guarded_point
@@ -270,7 +271,11 @@ class NDArrayIter(DataIter):
         else:
             pad = self.batch_size - self.num_data + self.cursor
             sel = _np.concatenate([self.idx[self.cursor:], self.idx[:pad]])
-        return [nd_array(self._np_cache[id(x)][sel]) for _, x in data_source]
+        with _profiler.span("input.slice"):
+            rows = [self._np_cache[id(x)][sel] for _, x in data_source]
+        _profiler.count("input.bytes", sum(r.nbytes for r in rows))
+        with _profiler.span("input.h2d"):
+            return [nd_array(r) for r in rows]
 
     def getdata(self):
         return self._getdata(self.data)
@@ -467,14 +472,20 @@ class PrefetchingIter(DataIter):
         # A plain dict (not `self`) is shared with the producer threads
         # so they hold no reference that would keep this object alive.
         self._snap_flag = {"on": False}
+        # batch ordinals (profiler spans): the producers count the batches
+        # they fetch and the consumer those it takes, each since the last
+        # reset(), which zeroes both while the producers are parked
+        self._fetched = [{"n": 0} for _ in self.iters]
+        self._taken = 0
         self._slots = [_ExchangeSlot() for _ in self.iters]
-        for src, slot in zip(self.iters, self._slots):
+        for src, slot, fetched in zip(self.iters, self._slots,
+                                      self._fetched):
             threading.Thread(target=self._produce,
-                             args=(src, slot, self._snap_flag),
+                             args=(src, slot, self._snap_flag, fetched),
                              daemon=True).start()
 
     @staticmethod
-    def _produce(source, slot, snap_flag):
+    def _produce(source, slot, snap_flag, fetched):
         # per-prefetch snapshots only when armed AND the source can
         # snapshot all the way down (a wrapper over a snapshot-less
         # source *raises* from state_dict rather than losing the
@@ -486,7 +497,10 @@ class PrefetchingIter(DataIter):
             try:
                 if can_snapshot and snap_flag["on"]:
                     pre_state = source.state_dict()
-                staged = source.next()
+                with _profiler.span("input.fetch", batch=fetched["n"]):
+                    staged = source.next()
+                fetched["n"] += 1
+                _profiler.count("input.batches")
             except StopIteration:
                 staged = None
             except BaseException as err:  # noqa: BLE001
@@ -527,8 +541,14 @@ class PrefetchingIter(DataIter):
             slot.peek_filled()
         for src in self.iters:
             src.reset()
+        self._restart_ordinals()
         for slot in self._slots:
             slot.drain_and_let_refill()
+
+    def _restart_ordinals(self):
+        for fetched in self._fetched:
+            fetched["n"] = 0
+        self._taken = 0
 
     # -- checkpointable state (resilience/data.py, mid-epoch resume) ---------
 
@@ -580,11 +600,14 @@ class PrefetchingIter(DataIter):
             slot.peek_filled()
         for src, inner in zip(self.iters, state["inner"]):
             src.load_state_dict(inner)
+        self._restart_ordinals()
         for slot in self._slots:    # discard stale batch, refetch from
             slot.drain_and_let_refill()   # the restored position
 
     def iter_next(self):
-        staged = [slot.take().item for slot in self._slots]
+        with _profiler.span("input.wait", batch=self._taken):
+            staged = [slot.take().item for slot in self._slots]
+        self._taken += 1
         for item in staged:
             if isinstance(item, _ProducerFailure):
                 raise item.error

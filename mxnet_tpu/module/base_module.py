@@ -15,6 +15,7 @@ from typing import List, Optional
 
 from .. import metric as metric_mod
 from .. import ndarray as nd
+from .. import profiler as _profiler
 from ..base import MXNetError
 from ..callback import BatchEndParam
 from ..initializer import Uniform
@@ -57,16 +58,22 @@ def _lookahead(iterable, snapshot=None, want=None):
     (arbitrary iterators may pay O(dataset)), so it must not run every
     batch."""
     it = iter(iterable)
-    here = next(it, _END)
+    with _profiler.span("fit.fetch", batch=0):
+        here = next(it, _END)
     k = 0
     while here is not _END:
         state = None
         if snapshot is not None and (want is None or want(k)):
             state = snapshot()
-        nxt = next(it, _END)
+        # batch k+1 is fetched before step k runs: each fetch carries the
+        # ordinal of the batch it fetches, the loop's body that of batch k
+        with _profiler.span("fit.fetch", batch=k + 1):
+            nxt = next(it, _END)
+        _profiler.set_batch(k)
         yield here, (None if nxt is _END else nxt), state
         here = nxt
         k += 1
+    _profiler.set_batch(None)
 
 
 def _resolve_metric(m):
@@ -99,7 +106,6 @@ class BaseModule:
         self.params_initialized = False
         self.optimizer_initialized = False
         self._symbol = None
-        self._total_exec_bytes = 0
 
     # -- properties subclasses provide ---------------------------------------
     @property
@@ -852,12 +858,16 @@ class BaseModule:
                 progressed = True
             if upcoming is not None:
                 self.prepare(upcoming)
-            self.update_metric(train_metric, batch.label)
+            with _profiler.span("fit.metric"):
+                self.update_metric(train_metric, batch.label)
             if monitor is not None:
                 monitor.toc_print()
-            _fire(batch_end_callback,
-                  BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                eval_metric=train_metric, locals=locals()))
+            if batch_end_callback is not None:
+                with _profiler.span("fit.callbacks"):
+                    _fire(batch_end_callback,
+                          BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                        eval_metric=train_metric,
+                                        locals=locals()))
             if batch_ckpt is not None and (nbatch + 1) % batch_ckpt[0] == 0:
                 batch_ckpt[1](epoch, nbatch, state)
             if sup is not None and sup.check_preempt():

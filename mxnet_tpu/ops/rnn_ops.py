@@ -136,7 +136,10 @@ def _run_direction(x, h0, c0, w_i2h, w_h2h, b_i2h, b_h2h, mode, H,
             return step(carry, xp, w_h2h)
 
     carry = (h0, c0) if mode == "lstm" else (h0,)
-    carry, out = lax.scan(body, carry, xproj, reverse=reverse)
+    # named, so a device trace tells the recurrence (and, transposed, the
+    # cell's backward) from the projection matmuls around it
+    with jax.named_scope("scan"):
+        carry, out = lax.scan(body, carry, xproj, reverse=reverse)
     if mode == "lstm":
         hT, cT = carry
     else:
@@ -169,8 +172,10 @@ def _rnn_impl(rng, data, parameters, state, state_cell, state_size,
             idx = layer * D + d
             h0 = state[idx]
             c0 = state_cell[idx] if mode == "lstm" else None
-            out, hT, cT = _run_direction(x, h0, c0, w_i2h, w_h2h, b_i2h,
-                                         b_h2h, mode, H, reverse=(d == 1))
+            with jax.named_scope(f"layer{layer}" + ("_reverse" if d else "")):
+                out, hT, cT = _run_direction(
+                    x, h0, c0, w_i2h, w_h2h, b_i2h, b_h2h, mode, H,
+                    reverse=(d == 1))
             outs.append(out)
             h_states.append(hT)
             if mode == "lstm":

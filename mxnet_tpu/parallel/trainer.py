@@ -13,6 +13,7 @@ sync-BN — stronger than the reference's per-device statistics.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -21,6 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import initializer as _init_mod, optimizer as _opt_mod
+from .. import profiler as _profiler
 from ..analysis.annotations import hot_path
 from ..base import MXNetError
 from ..executor import build_graph_eval
@@ -37,6 +39,23 @@ __all__ = ["SPMDTrainer"]
 # (perf/step_runtime.py) so Module/Gluon/model.py trace the SAME update
 # math; this alias keeps the historical import path working.
 from ..perf.step_runtime import functional_update as _functional_update  # noqa: E402,E501
+
+
+def _fetching(iterable):
+    """``enumerate(iterable)`` for the fit loop: every ``next()`` is a
+    ``fit.fetch`` span, and the loop's body runs under the fetched batch's
+    ordinal (the k-th batch since the loop, which resets its iterator
+    first, began). ``base_module._lookahead`` is Module.fit's."""
+    it = iter(iterable)
+    for k in itertools.count():
+        try:
+            with _profiler.span("fit.fetch", batch=k):
+                batch = next(it)
+        except StopIteration:
+            _profiler.set_batch(None)
+            return
+        _profiler.set_batch(k)
+        yield k, batch
 
 
 class SPMDTrainer:
@@ -329,9 +348,11 @@ class SPMDTrainer:
             def loss_f(p):
                 merged = dict(inputs)
                 if compute_dtype is not None:
-                    p = {n: (v.astype(compute_dtype)
-                             if v.ndim >= 2 and v.dtype == jnp.float32 else v)
-                         for n, v in p.items()}
+                    with jax.named_scope("cast_params"):
+                        p = {n: (v.astype(compute_dtype)
+                                 if v.ndim >= 2 and v.dtype == jnp.float32
+                                 else v)
+                             for n, v in p.items()}
                 merged.update(p)
                 outs, aux_up = eval_fn(merged, aux, rng, True)
                 return outs, aux_up
@@ -353,7 +374,8 @@ class SPMDTrainer:
                 # heads ignore it, and bf16 shares fp32's exponent
                 # range; the schedule + skip are the portable contract)
                 from ..quant.loss_scale import tree_all_finite
-                finite = tree_all_finite(grads)
+                with jax.named_scope("loss_scale_guard"):
+                    finite = tree_all_finite(grads)
             new_ig = None
             if ig_cfg is not None:
                 # in-trace divergence sentinel over the raw (pre-select)
@@ -361,10 +383,12 @@ class SPMDTrainer:
                 # this program, only a sticky flag reaches the host —
                 # and only once per MXTPU_INTEGRITY_PERIOD
                 from ..resilience.integrity import update_sentinel
-                new_ig = update_sentinel(ig_cfg, ig, grads, t,
-                                         applied=finite)
+                with jax.named_scope("integrity_sentinel"):
+                    new_ig = update_sentinel(ig_cfg, ig, grads, t,
+                                             applied=finite)
             new_params, new_states = {}, {}
-            for n in params:
+
+            def updated(n):
                 g = grads[n]
                 if shard_opt and plan.zero_rs:
                     # comm-optimal mode (MXTPU_ZERO=2): pin the grad to
@@ -374,10 +398,10 @@ class SPMDTrainer:
                     # Different summation order than all-reduce:
                     # last-ulp drift vs replicated (documented).
                     g = jax.lax.with_sharding_constraint(g, state_sh[n])
-                    new_params[n], new_states[n] = update(
+                    return update(
                         params[n], g, states[n],
                         lr * lr_mult[n], wd_by_name[n], t)
-                elif shard_opt:
+                if shard_opt:
                     # bitwise ZeRO (default): materialize the fully-
                     # reduced grad first (the SAME all-reduce the
                     # replicated program runs), then run the update on
@@ -385,14 +409,16 @@ class SPMDTrainer:
                     # boundary keeps the slicing from re-laying-out
                     # the forward/backward (zero_sharded_update)
                     g = jax.lax.with_sharding_constraint(g, param_sh[n])
-                    new_params[n], new_states[n] = zero_sharded_update(
+                    return zero_sharded_update(
                         mesh, plan.data_axis, update, params[n], g,
                         states[n], lr * lr_mult[n], wd_by_name[n], t,
                         param_specs[n], state_specs[n])
-                else:
-                    new_params[n], new_states[n] = update(
-                        params[n], g, states[n],
-                        lr * lr_mult[n], wd_by_name[n], t)
+                return update(params[n], g, states[n],
+                              lr * lr_mult[n], wd_by_name[n], t)
+
+            with jax.named_scope("optimizer_update"):
+                for n in params:
+                    new_params[n], new_states[n] = updated(n)
             new_aux = dict(aux)
             new_aux.update(aux_up)
             new_ls = None
@@ -400,10 +426,11 @@ class SPMDTrainer:
                 # skipped step: params/state/aux pass through bitwise
                 from ..quant.loss_scale import (guarded_select,
                                                 next_state)
-                new_params = guarded_select(finite, new_params, params)
-                new_states = guarded_select(finite, new_states, states)
-                new_aux = guarded_select(finite, new_aux, aux)
-                new_ls = next_state(ls, finite, ls_cfg)
+                with jax.named_scope("loss_scale_guard"):
+                    new_params = guarded_select(finite, new_params, params)
+                    new_states = guarded_select(finite, new_states, states)
+                    new_aux = guarded_select(finite, new_aux, aux)
+                    new_ls = next_state(ls, finite, ls_cfg)
             # pin steady-state shardings: without this GSPMD may pick new
             # layouts for the donated outputs, forcing a recompile on the
             # next step when the re-fed params carry different shardings.
@@ -522,78 +549,83 @@ class SPMDTrainer:
         """Run one optimizer step on a global batch; returns outputs."""
         if self._step_fn is None:
             raise MXNetError("call bind() before step()")
-        # fault site only, no retry: the step donates its param/state
-        # buffers, so re-running a half-executed step is never safe —
-        # recovery from a failed step is restore_latest()+resume
-        from ..resilience import fault_point
-        from ..resilience.elastic import check_collective
-        fault_point("trainer.step")
-        # mesh.collective: a participant dying mid-collective surfaces as
-        # DeviceLost; fit(elastic=True) recovers via checkpoint restore
-        # onto the surviving devices (resilience/elastic.py)
-        check_collective()
-        inputs = {}
-        for n, v in batch.items():
-            if isinstance(v, NDArray):
-                # hand the underlying device array straight to device_put:
-                # an asnumpy() here would be a full device->host readback
-                # per batch
-                v = v._data
-            elif not isinstance(v, jax.Array):
-                # host-side input prep: device arrays took the _data path
-                # above, so this never reads back from the accelerator
-                v = np.asarray(v)  # tpu-lint: disable=host-sync-under-trace
-            # no-op when v is already device-resident with this sharding
-            inputs[n] = jax.device_put(v, self._in_shardings[n])
-        self._num_update += 1
-        self._rng, sub = jax.random.split(self._rng)
-        lr = jnp.float32(self._optimizer.lr
-                         if self._optimizer.lr_scheduler is None
-                         else self._optimizer.lr_scheduler(self._num_update))
-        t = jnp.float32(self._num_update)
-        # mesh-aware ops (MultiHeadAttention seq_axis, ...) consult the
-        # ambient mesh while the step traces (first call compiles)
-        from .mesh import mesh_scope
-        args = (self.params, self.states, self.aux, inputs, sub, lr, t)
-        if self._ls_cfg is not None or self._ig_cfg is not None:
-            # with only the integrity sentinel armed, ls rides as the
-            # None placeholder (an empty pytree: nothing is traced in)
-            args = args + (self._ls_state,)
-        if self._ig_cfg is not None:
-            args = args + (self._ig_state,)
-        if getattr(self, "_step_abstract_args", None) is None:
-            # one-time abstract arg snapshot (shapes + mesh shardings) so
-            # the compiled step's HLO stays inspectable after the donated
-            # buffers are consumed; single-device placements (rng key,
-            # scalars) stay unspecified or lower() rejects the device
-            # mix. Shapes/shardings are invariant after bind, so the
-            # first step's snapshot serves the trainer's lifetime.
-            def _abstract(x):
-                sh = getattr(x, "sharding", None)
-                if (not isinstance(sh, NamedSharding)
-                        or sh.mesh != self._mesh):
-                    sh = None
-                return jax.ShapeDtypeStruct(
-                    jnp.shape(x), jnp.result_type(x), sharding=sh)
+        with _profiler.span("fit.step"):
+            # fault site only, no retry: the step donates its param/state
+            # buffers, so re-running a half-executed step is never safe —
+            # recovery from a failed step is restore_latest()+resume
+            from ..resilience import fault_point
+            from ..resilience.elastic import check_collective
+            fault_point("trainer.step")
+            # mesh.collective: a participant dying mid-collective surfaces
+            # as DeviceLost; fit(elastic=True) recovers via checkpoint
+            # restore onto the surviving devices (resilience/elastic.py)
+            check_collective()
+            inputs = {}
+            with _profiler.span("step.place"):
+                for n, v in batch.items():
+                    if isinstance(v, NDArray):
+                        # hand the underlying device array straight to
+                        # device_put: an asnumpy() here would be a full
+                        # device->host readback per batch
+                        v = v._data
+                    elif not isinstance(v, jax.Array):
+                        # host-side input prep: device arrays took the
+                        # _data path above, so this never reads back from
+                        # the accelerator
+                        v = np.asarray(v)  # tpu-lint: disable=host-sync-under-trace
+                    # no-op when v already lives there with this sharding
+                    inputs[n] = jax.device_put(v, self._in_shardings[n])
+            self._num_update += 1
+            _profiler.count("step.count")
+            self._rng, sub = jax.random.split(self._rng)
+            opt = self._optimizer
+            lr = jnp.float32(opt.lr if opt.lr_scheduler is None
+                             else opt.lr_scheduler(self._num_update))
+            t = jnp.float32(self._num_update)
+            # mesh-aware ops (MultiHeadAttention seq_axis, ...) consult the
+            # ambient mesh while the step traces (first call compiles)
+            from .mesh import mesh_scope
+            args = (self.params, self.states, self.aux, inputs, sub, lr, t)
+            if self._ls_cfg is not None or self._ig_cfg is not None:
+                # with only the integrity sentinel armed, ls rides as the
+                # None placeholder (an empty pytree: nothing is traced in)
+                args = args + (self._ls_state,)
+            if self._ig_cfg is not None:
+                args = args + (self._ig_state,)
+            if getattr(self, "_step_abstract_args", None) is None:
+                # one-time abstract arg snapshot (shapes + mesh shardings)
+                # so the compiled step's HLO stays inspectable after the
+                # donated buffers are consumed; single-device placements
+                # (rng key, scalars) stay unspecified or lower() rejects
+                # the device mix. Shapes/shardings are invariant after
+                # bind, so the first step's snapshot serves the trainer's
+                # lifetime.
+                def _abstract(x):
+                    sh = getattr(x, "sharding", None)
+                    if (not isinstance(sh, NamedSharding)
+                            or sh.mesh != self._mesh):
+                        sh = None
+                    return jax.ShapeDtypeStruct(
+                        jnp.shape(x), jnp.result_type(x), sharding=sh)
 
-            self._step_abstract_args = jax.tree_util.tree_map(
-                _abstract, args)
-        with mesh_scope(self._mesh):
-            res = self._step_fn(*args)
-        self.params, self.states, self.aux, outs = res[:4]
-        tail = 4
-        if self._ls_cfg is not None:
-            self._ls_state = res[tail]
-            tail += 1
-        if self._ig_cfg is not None:
-            self._ig_state = res[tail]
-        # the lying-chip fault site (resilience/integrity.py): an armed
-        # mesh.silent_corrupt plan lands a seeded single-device bitflip
-        # HERE, after the updated params exist — and nothing raises;
-        # disarmed this is one active_plan()-is-None check
-        from ..resilience.integrity import corruption_point
-        corruption_point(self)
-        return outs
+                self._step_abstract_args = jax.tree_util.tree_map(
+                    _abstract, args)
+            with mesh_scope(self._mesh), _profiler.span("step.dispatch"):
+                res = self._step_fn(*args)
+            self.params, self.states, self.aux, outs = res[:4]
+            tail = 4
+            if self._ls_cfg is not None:
+                self._ls_state = res[tail]
+                tail += 1
+            if self._ig_cfg is not None:
+                self._ig_state = res[tail]
+            # the lying-chip fault site (resilience/integrity.py): an
+            # armed mesh.silent_corrupt plan lands a seeded single-device
+            # bitflip HERE, after the updated params exist — and nothing
+            # raises; disarmed this is one active_plan()-is-None check
+            from ..resilience.integrity import corruption_point
+            corruption_point(self)
+            return outs
 
     def compiled_step_hlo(self) -> str:
         """Optimized HLO text of the compiled training step.
@@ -1200,7 +1232,7 @@ class SPMDTrainer:
             # else: mid-epoch resume — the restored iterator already
             # sits at begin_batch; a reset would replay the epoch head
             nseen = 0
-            for k, batch in enumerate(train_data):
+            for k, batch in _fetching(train_data):
                 nbatch = begin_batch + k
                 nseen = k + 1
                 if iguard is not None \
@@ -1259,9 +1291,12 @@ class SPMDTrainer:
                     # unable to reach the save path below (the async
                     # gate is the second, belt-and-braces wall)
                     iguard.after_step(epoch, nbatch)
-                for cb in cbs:
-                    cb(BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                     eval_metric=None, locals=locals()))
+                if cbs:
+                    with _profiler.span("fit.callbacks"):
+                        for cb in cbs:
+                            cb(BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                             eval_metric=None,
+                                             locals=locals()))
                 if checkpoint_dir and bperiod and can_snapshot \
                         and (nbatch + 1) % bperiod == 0:
                     # state_dict() here is "about to fetch nbatch+1" —

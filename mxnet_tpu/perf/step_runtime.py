@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import profiler as _profiler
 from ..base import MXNetError, getenv
 from ..executor import _null_key, build_graph_eval
 from ..ops.registry import OP_TABLE
@@ -627,8 +628,9 @@ class FusedStep:
         def step(params, states, aux, inputs, rng, lr, t, ls=None, ig=None):
             def loss_f(p):
                 merged = dict(inputs)
-                for n, v in p.items():
-                    merged[n] = jax.tree_util.tree_map(cast, v)
+                with jax.named_scope("cast_params"):
+                    for n, v in p.items():
+                        merged[n] = jax.tree_util.tree_map(cast, v)
                 outs, aux_up = eval_fn(merged, aux, rng, True)
                 return outs, aux_up
 
@@ -659,17 +661,20 @@ class FusedStep:
                 # scale is live for the Gluon path — where the USER
                 # scales a real scalar loss — and for fp8-era formats.
                 from ..quant.loss_scale import tree_all_finite
-                finite = tree_all_finite(grads)
+                with jax.named_scope("loss_scale_guard"):
+                    finite = tree_all_finite(grads)
             new_ig = None
             if ig_cfg is not None:
                 # the divergence sentinel folds the raw grad-norm into
                 # its Welford stats in-trace; loss-scale-skipped steps
                 # are neither a breach nor a sample (applied=finite)
                 from ..resilience.integrity import update_sentinel
-                new_ig = update_sentinel(ig_cfg, ig, grads, t,
-                                         applied=finite)
+                with jax.named_scope("integrity_sentinel"):
+                    new_ig = update_sentinel(ig_cfg, ig, grads, t,
+                                             applied=finite)
             new_params, new_states = {}, {}
-            for n in params:
+
+            def updated(n):
                 w_leaves, treedef = jax.tree_util.tree_flatten(params[n])
                 g_leaves = jax.tree_util.tree_leaves(grads[n])
                 nw, ns = [], []
@@ -716,8 +721,11 @@ class FusedStep:
                                 x, _ssh(n, x)), s2)
                     nw.append(w2)
                     ns.append(s2)
-                new_params[n] = jax.tree_util.tree_unflatten(treedef, nw)
-                new_states[n] = ns
+                return jax.tree_util.tree_unflatten(treedef, nw), ns
+
+            with jax.named_scope("optimizer_update"):
+                for n in params:
+                    new_params[n], new_states[n] = updated(n)
             new_aux = dict(aux)
             new_aux.update(aux_up)
             if ls_cfg is not None:
@@ -725,10 +733,11 @@ class FusedStep:
                 # optimizer state and aux pass through bitwise unchanged
                 # and only the scale schedule moves
                 from ..quant.loss_scale import guarded_select, next_state
-                new_params = guarded_select(finite, new_params, params)
-                new_states = guarded_select(finite, new_states, states)
-                new_aux = guarded_select(finite, new_aux, aux)
-                new_ls = next_state(ls, finite, ls_cfg)
+                with jax.named_scope("loss_scale_guard"):
+                    new_params = guarded_select(finite, new_params, params)
+                    new_states = guarded_select(finite, new_states, states)
+                    new_aux = guarded_select(finite, new_aux, aux)
+                    new_ls = next_state(ls, finite, ls_cfg)
             if plan is not None:
                 new_aux = {n: jax.lax.with_sharding_constraint(v, _repl)
                            for n, v in new_aux.items()}
@@ -1009,10 +1018,34 @@ class ModuleStepper:
     def step(self, data_batch):
         from .. import random as _random
         from ..ndarray import NDArray
+
+        with _profiler.span("fit.step"):
+            if self._stale:
+                self.refresh()
+            mod = self._module
+            with _profiler.span("step.place"):
+                inputs = self._placed_inputs(data_batch)
+            rng = (_random.next_key() if self._fused.needs_rng
+                   else _null_key())
+            self._num_update += 1
+            _profiler.count("step.count")
+            opt = mod._optimizer
+            lr = jnp.float32(opt.lr if opt.lr_scheduler is None
+                             else opt.lr_scheduler(self._num_update))
+            t = jnp.float32(self._num_update)
+            with _profiler.span("step.dispatch"):
+                self._params, self._states, self._aux, outs = self._fused(
+                    self._params, self._states, self._aux, inputs, rng,
+                    lr, t)
+            mod._exec.outputs = [NDArray(o) for o in outs]
+            mod._params_dirty = True
+            self._synced = False
+            return outs
+
+    def _placed_inputs(self, data_batch):
+        """The batch and the frozen parameters where the step reads them."""
         from ..ndarray.ndarray import _as_jax
 
-        if self._stale:
-            self.refresh()
         mod = self._module
         exec_ = mod._exec
         plan = self._fused.plan
@@ -1039,19 +1072,7 @@ class ModuleStepper:
                     exec_.arg_dict[name]._data = v2
                 v = v2
             inputs[name] = v
-        rng = (_random.next_key() if self._fused.needs_rng
-               else _null_key())
-        self._num_update += 1
-        opt = mod._optimizer
-        lr = jnp.float32(opt.lr if opt.lr_scheduler is None
-                         else opt.lr_scheduler(self._num_update))
-        t = jnp.float32(self._num_update)
-        self._params, self._states, self._aux, outs = self._fused(
-            self._params, self._states, self._aux, inputs, rng, lr, t)
-        exec_.outputs = [NDArray(o) for o in outs]
-        mod._params_dirty = True
-        self._synced = False
-        return outs
+        return inputs
 
     def sync_to_module(self):
         """Write params/aux/optimizer-state back into the module."""

@@ -1,4 +1,4 @@
-"""Profiler: per-op / per-phase timing exported as Chrome trace JSON.
+"""Profiler: the program's own spans and counters, on one host clock.
 
 Reference surface: python/mxnet/profiler.py (profiler_set_config,
 profiler_set_state, dump_profile) over src/engine/profiler.{h,cc}, which
@@ -6,28 +6,68 @@ stamps operator start/end in ThreadedEngine::ExecuteOprBlock and dumps
 Chrome tracing JSON (profiler.h:106-124). Env controls
 MXNET_PROFILER_AUTOSTART / MXNET_PROFILER_MODE (docs/how_to/env_var.md).
 
-TPU-native rebuild: the phases we own (imperative op dispatch, executor
-forward/backward, io) are timed on the host — timing forces
-``block_until_ready`` so durations cover device execution, exactly like
-the reference's per-op engine stamps. For instruction-level device detail
-``start_xla_trace``/``stop_xla_trace`` wrap ``jax.profiler`` (XPlane/
-TensorBoard), which subsumes the reference's per-kernel visibility.
+TPU-native rebuild. The host's work is recorded where it happens, as
+*spans*: ``with profiler.span("fit.step"):`` stamps name, start and end
+(``time.perf_counter_ns``), the thread, the enclosing span on that thread
+(its cause) and a **batch ordinal**: the running number of the batch since
+the iterator's last ``reset()``, which the input pipeline's producer thread
+and the fit loop each count for themselves, so every span of one batch
+carries the same number without a field on ``DataBatch``.
+
+* Always (state 'stop', the default): a span goes into a bounded in-memory
+  ring and adds its duration to a per-name total. No lock beyond the GIL,
+  no device sync, no file. :func:`spans`, :func:`totals`, :func:`counters`
+  read them; ``perfbench/metrics/`` does.
+* ``profiler_set_state('run')`` / ``MXTPU_PROFILER_AUTOSTART``, or between
+  :func:`start_xla_trace` and :func:`stop_xla_trace`: each span is also a
+  ``jax.profiler.TraceAnnotation(name, batch=k)``, so in an XLA trace the
+  spans lie on the profiler's own clock in ``/host:CPU`` beside the
+  device's operations. :func:`dump_profile` writes the ring as Chrome JSON.
+
+Nothing here waits for the device: a span around a dispatch times the
+dispatch. Device time is the device trace's to give
+(:func:`start_xla_trace`), and :func:`op_scopes` names its instructions by
+the graph op that emitted them (``Convolution/stage1_unit1_conv1``).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
-from typing import List
+from collections import namedtuple
+from typing import Dict, List, Optional
 
 from .base import MXNetError, getenv
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
-           "start_xla_trace", "stop_xla_trace", "record_event", "is_running",
-           "profile_scope"]
+           "start_xla_trace", "stop_xla_trace", "is_running",
+           "span", "profile_scope", "count", "spans", "totals", "self_totals",
+           "counters", "set_batch", "op_scopes", "Span"]
 
 _MODES = ("symbolic", "imperative", "all")
+
+# One constant size. The fit thread records at most 10 spans a step and a
+# producer 5 a batch (``input.fetch``, and ``input.slice`` + ``input.h2d``
+# for the data and again for the label), so the ring holds the last ~4,000
+# steps.
+RING_SIZE = 1 << 16
+
+Span = namedtuple("Span", "seq name start_ns end_ns thread parent batch cat")
+
+
+class _ThreadState(threading.local):
+    """Per thread: the open spans, the loop's batch ordinal, and this
+    thread's share of the totals and counters (merged on read, so no two
+    threads ever write one cell)."""
+
+    def __init__(self):
+        self.stack: List["span"] = []
+        self.batch: Optional[int] = None
+        self.totals: Dict[str, list] = {}      # name -> [count, ns]
+        self.counts: Dict[str, int] = {}
+        _PROF.enrol(threading.current_thread(), self.totals, self.counts)
 
 
 class _Profiler:
@@ -35,93 +75,240 @@ class _Profiler:
         self.mode = "symbolic"
         self.filename = "profile.json"
         self.running = False
-        self.events: List[dict] = []
-        self.lock = threading.Lock()
-        self._t0 = time.perf_counter()
+        self.xla_tracing = False
+        self.annotation = None          # jax.profiler.TraceAnnotation
+        self.ring: List[Optional[Span]] = [None] * RING_SIZE
+        self.seq = itertools.count()    # next() is atomic under the GIL
+        self.mark_ns = 0                # dump_profile writes spans since
+        self.t0_ns = time.perf_counter_ns()
+        # (thread, its totals, its counts); a thread that has ended is
+        # folded into the first entry when the next one enrols
+        self.threads: list = [(None, {}, {})]
+        self.enrol_lock = threading.Lock()
 
-    def now_us(self):
-        return (time.perf_counter() - self._t0) * 1e6
+    def enrol(self, thread, totals, counts):
+        with self.enrol_lock:
+            ended = [t for t in self.threads[1:] if not t[0].is_alive()]
+            if ended:
+                _fold(ended, self.threads[0][1], self.threads[0][2])
+                self.threads = [t for t in self.threads if t not in ended]
+            self.threads.append((thread, totals, counts))
+
+
+def _fold(entries, totals, counts):
+    for _thread, t, c in entries:
+        for name, (n, ns) in list(t.items()):
+            have = totals.setdefault(name, [0, 0])
+            have[0] += n
+            have[1] += ns
+        for name, n in list(c.items()):
+            counts[name] = counts.get(name, 0) + n
 
 
 _PROF = _Profiler()
+_TLS = _ThreadState()
 
 
 def profiler_set_config(mode: str = "symbolic",
                         filename: str = "profile.json"):
-    """Configure what is recorded and where the trace is written.
+    """Configure which per-call spans are recorded and where
+    :func:`dump_profile` writes.
 
-    mode: 'symbolic' (executor phases), 'imperative' (nd.* op calls),
-    'all' (both; reference mode2int maps symbolic=0, all=1)."""
+    mode: 'symbolic' (executor Forward/ForwardBackward), 'imperative'
+    (one span per nd.* op call), 'all' (both; reference mode2int maps
+    symbolic=0, all=1). The fit-loop and input-pipeline spans are
+    recorded in every mode."""
     if mode not in _MODES:
         raise MXNetError(f"profiler mode must be one of {_MODES}")
     _PROF.mode = mode
     _PROF.filename = filename
 
 
+def _load_annotation():
+    if _PROF.annotation is None:
+        import jax
+        _PROF.annotation = jax.profiler.TraceAnnotation
+
+
 def profiler_set_state(state: str = "stop"):
-    """'run' starts collecting events, 'stop' halts collection."""
+    """'run': spans are also written as ``TraceAnnotation``s and the
+    per-call spans of the configured mode are recorded; 'stop' (the
+    default): spans go to the in-memory ring only."""
     if state not in ("run", "stop"):
         raise MXNetError("profiler state must be 'run' or 'stop'")
-    _PROF.running = state == "run"
+    run = state == "run"
+    if run and not _PROF.running:
+        _PROF.mark_ns = time.perf_counter_ns()
+        _load_annotation()
+    _PROF.running = run
 
 
 def is_running(kind: str = "symbolic") -> bool:
-    """Internal: should events of this kind be recorded now?"""
+    """Should the per-call spans of this kind be recorded now?"""
     return _PROF.running and (_PROF.mode == "all" or _PROF.mode == kind)
 
 
-def record_event(name: str, cat: str, start_us: float, end_us: float,
-                 tid: int = 0, args=None):
-    ev = {"name": name, "cat": cat, "ph": "X", "ts": start_us,
-          "dur": max(end_us - start_us, 0.01), "pid": 0, "tid": tid}
-    if args:
-        ev["args"] = args
-    with _PROF.lock:
-        _PROF.events.append(ev)
+class span:
+    """Context manager recording one span of host work.
 
+    ``batch``: the batch ordinal; left out, the span takes the enclosing
+    span's, or else the ordinal its thread's fit loop set
+    (:func:`set_batch`). ``kind`` ('symbolic' / 'imperative') marks a
+    per-call span that is recorded only while the profiler runs in that
+    mode; the named spans of the fit loops and the input pipeline pass
+    none and are always recorded."""
 
-class profile_scope:
-    """Context manager timing one phase into the trace (and forcing device
-    completion so the duration is real, not dispatch latency)."""
+    __slots__ = ("name", "batch", "cat", "seq", "parent", "start", "note")
 
-    def __init__(self, name: str, cat: str = "operator", kind: str = "symbolic",
-                 sync=None):
+    def __init__(self, name: str, batch: Optional[int] = None,
+                 cat: str = "span", kind: Optional[str] = None):
         self.name = name
+        self.batch = batch
         self.cat = cat
-        self.kind = kind
-        self.sync = sync
-        self.active = False
+        self.seq = None if kind is not None and not is_running(kind) else -1
 
     def __enter__(self):
-        self.active = is_running(self.kind)
-        if self.active:
-            self.start = _PROF.now_us()
+        if self.seq is None:
+            return self
+        tls = _TLS
+        stack = tls.stack
+        if stack:
+            top = stack[-1]
+            self.parent = top.seq
+            if self.batch is None:
+                self.batch = top.batch
+        else:
+            self.parent = -1
+            if self.batch is None:
+                self.batch = tls.batch
+        stack.append(self)
+        self.seq = next(_PROF.seq)
+        self.note = None
+        if _PROF.running or _PROF.xla_tracing:
+            self.note = (_PROF.annotation(self.name) if self.batch is None
+                         else _PROF.annotation(self.name, batch=self.batch))
+            self.note.__enter__()
+        self.start = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        if self.active:
-            if self.sync is not None:
-                try:
-                    import jax
-                    jax.block_until_ready(self.sync() if callable(self.sync)
-                                          else self.sync)
-                except Exception:  # sync is best-effort; timing still lands
-                    pass
-            record_event(self.name, self.cat, self.start, _PROF.now_us())
+        if self.seq is None:
+            return False
+        end = time.perf_counter_ns()
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        tls = _TLS
+        tls.stack.pop()
+        _PROF.ring[self.seq % RING_SIZE] = Span(
+            self.seq, self.name, self.start, end, threading.get_ident(),
+            self.parent, self.batch, self.cat)
+        cell = tls.totals.get(self.name)
+        if cell is None:
+            cell = tls.totals[self.name] = [0, 0]
+        cell[0] += 1
+        cell[1] += end - self.start
         return False
 
 
+def profile_scope(name: str, cat: str = "operator", kind: str = "symbolic"):
+    """The old name of :class:`span`, with its old argument order."""
+    return span(name, cat=cat, kind=kind)
+
+
+def count(name: str, n: int = 1):
+    """Add ``n`` to the counter ``name``."""
+    counts = _TLS.counts
+    counts[name] = counts.get(name, 0) + n
+
+
+def set_batch(k: Optional[int]):
+    """The batch ordinal of this thread's loop: spans opened on it outside
+    any other span, with no ordinal of their own, take this one."""
+    _TLS.batch = k
+
+
+def spans(since_ns: int = 0, until_ns: Optional[int] = None) -> List[Span]:
+    """The recorded spans that overlap ``[since_ns, until_ns]`` on
+    ``time.perf_counter_ns``, by start. The ring keeps the newest
+    ``RING_SIZE``; older ones are gone."""
+    out = [s for s in list(_PROF.ring)
+           if s is not None and s.end_ns >= since_ns
+           and (until_ns is None or s.start_ns <= until_ns)]
+    out.sort(key=lambda s: (s.start_ns, s.seq))
+    return out
+
+
+def _merged():
+    totals, counts = {}, {}
+    with _PROF.enrol_lock:
+        _fold(_PROF.threads, totals, counts)
+    return totals, counts
+
+
+def totals() -> Dict[str, tuple]:
+    """name -> (count, summed duration in ns) over every span recorded
+    since import, whether or not the ring still holds it."""
+    return {name: tuple(v) for name, v in _merged()[0].items()}
+
+
+def counters() -> Dict[str, int]:
+    """name -> value of every :func:`count`er since import."""
+    return _merged()[1]
+
+
+def self_totals(since_ns: int = 0,
+                until_ns: Optional[int] = None) -> Dict[str, int]:
+    """name -> summed self time in ns of the spans in the interval: a
+    span's duration less what its child spans (those it caused, on its
+    thread) cover of it."""
+    found = spans(since_ns, until_ns)
+    own = {s.seq: s.end_ns - s.start_ns for s in found}
+    for s in found:
+        if s.parent in own:
+            own[s.parent] -= s.end_ns - s.start_ns
+    out: Dict[str, int] = {}
+    for s in found:
+        out[s.name] = out.get(s.name, 0) + max(own[s.seq], 0)
+    return out
+
+
 def dump_profile():
-    """Write the Chrome trace JSON (chrome://tracing / perfetto format) and
-    stop the profiler (reference MXDumpProfile semantics)."""
+    """Write the spans recorded since the profiler was set to 'run' (or
+    since the last dump) as Chrome trace JSON (chrome://tracing / perfetto
+    format) and stop the profiler (reference MXDumpProfile semantics)."""
     profiler_set_state("stop")
-    with _PROF.lock:
-        events = list(_PROF.events)
-        _PROF.events.clear()
-    trace = {"traceEvents": events, "displayTimeUnit": "ms"}
+    found = spans(_PROF.mark_ns)
+    _PROF.mark_ns = time.perf_counter_ns()
+    names = {s.seq: s.name for s in found}
+    tids: Dict[int, int] = {}
+    events = []
+    for s in found:
+        args = {}
+        if s.batch is not None:
+            args["batch"] = s.batch
+        if s.parent in names:
+            args["parent"] = names[s.parent]
+        ev = {"name": s.name, "cat": s.cat, "ph": "X",
+              "ts": (s.start_ns - _PROF.t0_ns) / 1e3,
+              "dur": max((s.end_ns - s.start_ns) / 1e3, 0.01),
+              "pid": 0, "tid": tids.setdefault(s.thread, len(tids))}
+        if args:
+            ev["args"] = args
+        events.append(ev)
     with open(_PROF.filename, "w") as f:
-        json.dump(trace, f)
+        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
     return _PROF.filename
+
+
+def op_scopes(kind: str) -> Dict[str, str]:
+    """``{HLO instruction name: op_name path}`` of the program of this
+    ``kind`` ('spmd-step', 'fused-step', ...) that this process compiled
+    or loaded last: the names a device trace gives its operations
+    (``fusion.2089``), each with the scope of the graph op that emitted it
+    (``jit(step)/jit(main)/jvp(Convolution/stage1_unit1_conv1)/...``).
+    Empty where no such program exists or its map was never written."""
+    from .compiler import aot
+    return aot.op_map(kind)
 
 
 # -- deep device traces (TPU-native extra) ---------------------------------
@@ -131,11 +318,24 @@ _XLA_TRACE_DIR = None
 
 def start_xla_trace(logdir: str = "/tmp/mxtpu_xla_trace"):
     """Start a jax/XLA device trace (XPlane, viewable in TensorBoard or
-    xprof) — instruction-level TPU detail beyond the reference."""
+    xprof). Until :func:`stop_xla_trace` every span is also a
+    ``TraceAnnotation``, so the trace shows ``fit.fetch``, ``fit.step``,
+    ``input.fetch``... on the host's lines beside the device's operations,
+    on one clock. The host tracer runs at level 1, which keeps those and
+    drops the runtime's level-2 events. It does not make a host-fed run's trace
+    small: a ``device_put`` that changes the layout on the host writes one
+    ``Transpose`` event per tile at level 1 (11.8 M events, 408 MB, in 2 s
+    of a ResNet-50 run fed 154 MB a step), and slows the run it traces:
+    trace a second or two of such a run, not a window."""
     global _XLA_TRACE_DIR
     import jax
     os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+    _load_annotation()
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    _PROF.xla_tracing = True
     _XLA_TRACE_DIR = logdir
     return logdir
 
@@ -143,6 +343,7 @@ def start_xla_trace(logdir: str = "/tmp/mxtpu_xla_trace"):
 def stop_xla_trace():
     global _XLA_TRACE_DIR
     import jax
+    _PROF.xla_tracing = False
     jax.profiler.stop_trace()
     d, _XLA_TRACE_DIR = _XLA_TRACE_DIR, None
     return d

