@@ -170,10 +170,16 @@ class NDArrayIter(DataIter):
             self.idx = self.idx[:new_n]
 
         self.data_list = [x[1] for x in self.data] + [x[1] for x in self.label]
-        # one host copy per source up front; per-batch slicing then stays
-        # O(batch) instead of a whole-array device->host copy per batch
+        # one host copy per source up front, never written afterwards: a
+        # batch that is a run of rows is handed to the transfer as a view of
+        # it (_getdata), which costs nothing; any other batch is an O(batch)
+        # gather. A view, and on the CPU backend the jax.Array made from it,
+        # alias this memory, so the arrays are read-only: a write raises
+        # instead of changing batches already sent or still in flight.
         self._np_cache = {id(x): x.asnumpy()
                           for _, x in self.data + self.label}
+        for cached in self._np_cache.values():
+            cached.flags.writeable = False
         self.num_source = len(self.data_list)
         self.num_data = len(self.idx)
         assert self.num_data >= batch_size, \
@@ -266,14 +272,24 @@ class NDArrayIter(DataIter):
 
     def _getdata(self, data_source):
         assert self.cursor < self.num_data, "DataIter needs reset."
-        if self.cursor + self.batch_size <= self.num_data:
-            sel = self.idx[self.cursor:self.cursor + self.batch_size]
+        end = self.cursor + self.batch_size
+        # unshuffled, idx is arange (discard only truncates it): a batch
+        # that does not wrap past the end is a run of the cache's rows, and
+        # a basic slice of a run is a view. Nothing is copied on the host
+        # before the transfer; every other batch is gathered.
+        run = not self._shuffle and end <= self.num_data
+        if run:
+            sel = slice(self.cursor, end)
+        elif end <= self.num_data:
+            sel = self.idx[self.cursor:end]
         else:
-            pad = self.batch_size - self.num_data + self.cursor
-            sel = _np.concatenate([self.idx[self.cursor:], self.idx[:pad]])
+            sel = _np.concatenate([self.idx[self.cursor:],
+                                   self.idx[:end - self.num_data]])
         with _profiler.span("input.slice"):
             rows = [self._np_cache[id(x)][sel] for _, x in data_source]
         _profiler.count("input.bytes", sum(r.nbytes for r in rows))
+        if run:
+            _profiler.count("input.views", len(rows))
         with _profiler.span("input.h2d"):
             return [nd_array(r) for r in rows]
 

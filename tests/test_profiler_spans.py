@@ -180,6 +180,133 @@ def test_a_subclass_of_ndarrayiter_still_decides_what_a_batch_holds():
     assert [list(b.index) for b in batches] == [[0, 1, 2, 3], [4, 5, 6, 7]]
 
 
+# -- a run of rows goes to the transfer as a view of the host cache ----------
+
+def old_gather(it, source):
+    """The rows ``NDArrayIter._getdata`` gathered for the batch at ``it``'s
+    cursor before a run was served as a view: the reference."""
+    if it.cursor + it.batch_size <= it.num_data:
+        sel = it.idx[it.cursor:it.cursor + it.batch_size]
+    else:
+        pad = it.batch_size - it.num_data + it.cursor
+        sel = np.concatenate([it.idx[it.cursor:], it.idx[:pad]])
+    return source[sel]
+
+
+def sources(rows):
+    rng = np.random.RandomState(rows)
+    return (rng.randn(rows, 3, 2).astype(np.float32),
+            rng.randint(0, 10, rows).astype(np.float32))
+
+
+def handed_to_nd_array(monkeypatch):
+    """The host arrays ``_getdata`` hands to ``nd_array``, in order."""
+    handed = []
+
+    def recording(rows, *args, **kwargs):
+        handed.append(rows)
+        return mx.nd.array(rows, *args, **kwargs)
+
+    monkeypatch.setattr(mx.io, "nd_array", recording)
+    return handed
+
+
+@pytest.mark.parametrize("rows", [12, 14])
+@pytest.mark.parametrize("last_batch_handle", ["pad", "discard", "roll_over"])
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_a_run_of_rows_is_a_view_of_the_cache_and_every_batch_holds_the_old_bytes(
+        monkeypatch, shuffle, last_batch_handle, rows):
+    data, label = sources(rows)
+    it = mx.io.NDArrayIter(data, label, batch_size=4, shuffle=shuffle,
+                           last_batch_handle=last_batch_handle, seed=3)
+    caches = [it._np_cache[id(x)] for _, x in it.data + it.label]
+    handed = handed_to_nd_array(monkeypatch)
+    before = profiler.counters()
+    batches = runs = 0
+    for _ in range(2):
+        for batch in it:
+            is_run = not shuffle and \
+                it.cursor + it.batch_size <= it.num_data
+            want = [old_gather(it, data), old_gather(it, label)]
+            got = [batch.data[0].asnumpy(), batch.label[0].asnumpy()]
+            for g, w, sent, cache in zip(got, want, handed[-2:], caches):
+                assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+                assert sent.tobytes() == w.tobytes()
+                assert np.shares_memory(sent, cache) == is_run
+                assert sent.flags.writeable != is_run
+            batches += 1
+            runs += is_run
+        it.reset()
+    # two epochs of three whole batches, and of the tail where it is kept;
+    # roll_over's second epoch starts 2 rows in and ends on the last row
+    assert batches == {"discard": 6, "pad": 6 + 2 * (rows == 14),
+                       "roll_over": 6 + (rows == 14)}[last_batch_handle]
+    assert len(handed) == 2 * batches
+    assert runs == (0 if shuffle else 6)
+    after = profiler.counters()
+    assert after.get("input.views", 0) - before.get("input.views", 0) \
+        == 2 * runs
+    a_batch = data[:4].nbytes + label[:4].nbytes
+    assert after["input.bytes"] - before.get("input.bytes", 0) \
+        == batches * a_batch
+
+
+@pytest.mark.parametrize("last_batch_handle", ["pad", "discard", "roll_over"])
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_a_resume_mid_epoch_through_the_prefetcher_gives_the_same_batches(
+        shuffle, last_batch_handle):
+    data, label = sources(14)
+
+    def build():
+        return mx.io.PrefetchingIter(mx.io.NDArrayIter(
+            data, label, batch_size=4, shuffle=shuffle, seed=3,
+            last_batch_handle=last_batch_handle))
+
+    def held(batch):
+        return (batch.data[0].asnumpy().tobytes(),
+                batch.label[0].asnumpy().tobytes(), batch.pad)
+
+    def drain(it, epochs):
+        out = []
+        for _ in range(epochs):
+            out.extend(held(batch) for batch in it)
+            out.append("end")
+            it.reset()
+        return out
+
+    whole = drain(build(), 2)
+    first = build()
+    first.enable_state_snapshots()
+    head = [held(first.next()), held(first.next())]
+    state = json.loads(json.dumps(first.state_dict()))
+    views = profiler.counters().get("input.views", 0)
+    resumed = build()
+    resumed.load_state_dict(state)
+    assert head + drain(resumed, 2) == whole
+    resumed._slots[0].peek_filled()      # the producer has parked again
+    served = profiler.counters().get("input.views", 0) - views
+    assert (served == 0) if shuffle else (served >= 2 * 4)
+
+
+@pytest.mark.parametrize("as_ndarray", [False, True])
+def test_the_host_cache_is_read_only_and_the_callers_array_is_not(as_ndarray):
+    data, label = sources(8)
+    given = mx.nd.array(data) if as_ndarray else data
+    it = mx.io.NDArrayIter(given, label, batch_size=4)
+    for cache in it._np_cache.values():
+        assert not cache.flags.writeable
+        with pytest.raises(ValueError, match="read-only"):
+            cache[0] = 0
+    batch = it.next()
+    # a batch's own host copy is the caller's to write; the cache stands
+    host = batch.data[0].asnumpy()
+    host[:] = -1.0
+    data_was = data.copy()
+    data[0] = 5.0                      # the caller's array is still theirs
+    it.reset()
+    assert it.next().data[0].asnumpy().tobytes() == data_was[:4].tobytes()
+
+
 def mlp():
     data = mx.sym.var("data")
     net = mx.sym.FullyConnected(data, num_hidden=8, name="fc1")
