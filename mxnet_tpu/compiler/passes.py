@@ -237,6 +237,17 @@ class RematPolicy(Pass):
         if act_bytes is not None:
             ann["remat_activation_bytes_est"] = int(act_bytes)
         ann["remat"] = bool(decision)
+        # recomputation by block: where the model asks for it
+        # (``mx.AttrScope(__block__="layer3", __remat__="block")``) every
+        # named block of a training bind becomes a jax.checkpoint boundary
+        # (executor.build_graph_eval); the whole-graph decision above
+        # keeps nothing a block's boundary would
+        blocks = ctx.for_training and any(
+            n.scope_attrs.get("__remat__") == "block"
+            and n.scope_attrs.get("__block__")
+            for n in ir.nodes if not n.is_variable)
+        if blocks:
+            ann["remat_blocks"] = True
         return ir, {"remat_on": int(bool(decision))}
 
     @staticmethod
@@ -390,8 +401,14 @@ class OptimizeResult:
         return bool(self.annotations.get("remat"))
 
     @property
+    def remat_blocks(self) -> bool:
+        return bool(self.annotations.get("remat_blocks"))
+
+    @property
     def transform_sig(self) -> str:
         sig = f"passes={int(self.changed)};remat={int(self.remat)}"
+        if self.remat_blocks:
+            sig += ";rematblocks=1"
         # the sharding annotator (parallel/sharding.py) stamps the plan
         # signature so program keys built from this sig can never serve
         # an executable compiled for a different layout/ZeRO mode

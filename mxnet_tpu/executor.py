@@ -77,8 +77,25 @@ def _call_node(node, ins, rng, rng_index, is_train):
     return out if isinstance(out, tuple) else (out,)
 
 
-def build_graph_eval(symbol, collect_all=False, proxies=None):
-    """Build eval_fn(arg_vals: dict, aux_vals: dict, rng, is_train)
+def _block_segments(nodes):
+    """The op nodes in topological order, cut into runs that share a
+    ``__block__`` attribute (``mx.AttrScope(__block__="layer3")``): a list
+    of ``(block name or None, [nodes])``."""
+    runs = []
+    for node in nodes:
+        if node.is_variable:
+            continue
+        tag = node.scope_attrs.get("__block__")
+        if runs and runs[-1][0] == tag:
+            runs[-1][1].append(node)
+        else:
+            runs.append((tag, [node]))
+    return runs
+
+
+def build_graph_eval(symbol, collect_all=False, proxies=None,
+                     remat_blocks=False):
+    """Build eval_fn(arg_vals: dict, aux_vals: dict, rng, is_train: bool)
     -> (outputs: list, aux_updates: dict). Pure and jax-traceable.
 
     With ``collect_all`` the outputs list holds every op output in
@@ -89,7 +106,14 @@ def build_graph_eval(symbol, collect_all=False, proxies=None):
     Fed zeros it changes nothing, but its vjp cotangent is exactly the
     gradient at that op's output — the hook the sparse-grad Embedding
     path uses to obtain d(out) without differentiating through the
-    (vocab, dim) gather (see Executor)."""
+    (vocab, dim) gather (see Executor).
+
+    Nodes made under ``mx.AttrScope(__block__=<name>)`` run inside
+    ``jax.named_scope(<name>)``, so a model's blocks read ``layer3/...`` in
+    every instruction's ``op_name``. With ``remat_blocks`` (the remat-policy
+    pass sets it where the model asks, ``__remat__="block"``) each such run
+    of nodes is also a ``jax.checkpoint``: a training step keeps what
+    crosses a block's boundary and recomputes its inside in the backward."""
     nodes = symbol._topo_nodes()
     aux_ids = symbol._aux_node_ids()
     # deterministic per-random-node key folding. Only nodes that ACTUALLY
@@ -103,17 +127,23 @@ def build_graph_eval(symbol, collect_all=False, proxies=None):
     rng_index = {id(n): i for i, n in enumerate(random_nodes)}
     out_entries = list(symbol._outputs)
     proxies = proxies or {}
+    segments = _block_segments(nodes)
+    # what each block hands on: the entries a later node or the symbol's
+    # outputs read (every entry under ``collect_all``)
+    produced_in = {id(n): k for k, (_, seg) in enumerate(segments)
+                   for n in seg}
+    handed_on = [set() for _ in segments]
+    for k, (_, seg) in enumerate(segments):
+        for node in seg:
+            for p, i in node.inputs:
+                if produced_in.get(id(p), k) != k:
+                    handed_on[produced_in[id(p)]].add((id(p), i))
+    for n, i in out_entries:
+        if id(n) in produced_in:
+            handed_on[produced_in[id(n)]].add((id(n), i))
 
-    def eval_fn(arg_vals: Dict, aux_vals: Dict, rng, is_train: bool):
-        values = {}
-        aux_updates = {}
-        for node in nodes:
-            if node.is_variable:
-                if id(node) in aux_ids:
-                    values[(id(node), 0)] = aux_vals[node.name]
-                else:
-                    values[(id(node), 0)] = arg_vals[node.name]
-                continue
+    def run_nodes(seg, values, arg_vals, rng, is_train, aux_updates):
+        for node in seg:
             ins = [values[(id(p), i)] for p, i in node.inputs]
             out = _call_node(node, ins, rng, rng_index, is_train)
             pname = proxies.get(id(node))
@@ -127,6 +157,50 @@ def build_graph_eval(symbol, collect_all=False, proxies=None):
                         p, _ = node.inputs[in_idx]
                         if p.is_variable and id(p) in aux_ids:
                             aux_updates[p.name] = out[out_idx]
+
+    # what each block reads from outside itself, in a fixed order
+    reads_of = []
+    for _, seg in segments:
+        inside = {id(n) for n in seg}
+        reads_of.append(sorted({(id(p), i) for n in seg for p, i in n.inputs
+                                if id(p) not in inside}))
+    keeps_of = [sorted(entries) for entries in handed_on]
+
+    def run_block(k, values, arg_vals, rng, is_train, aux_updates):
+        """One named block: a function of the entries it reads from outside
+        to the entries it hands on, so that ``jax.checkpoint`` has a
+        boundary to keep."""
+        tag, seg = segments[k]
+        reads, keeps = reads_of[k], keeps_of[k]
+        used = [n for n in (proxies.get(id(node)) for node in seg)
+                if n is not None and n in arg_vals]
+
+        def block(read_vals, proxy_vals, key):
+            local = dict(zip(reads, read_vals))
+            ups = {}
+            with jax.named_scope(tag):
+                run_nodes(seg, local, proxy_vals, key, is_train, ups)
+            return [local[e] for e in keeps], ups
+
+        if remat_blocks and is_train:
+            block = jax.checkpoint(block)
+        kept, ups = block([values[e] for e in reads],
+                          {n: arg_vals[n] for n in used}, rng)
+        values.update(zip(keeps, kept))
+        aux_updates.update(ups)
+
+    def eval_fn(arg_vals: Dict, aux_vals: Dict, rng, is_train: bool):
+        values = {}
+        aux_updates = {}
+        for node in nodes:
+            if node.is_variable:
+                values[(id(node), 0)] = (aux_vals if id(node) in aux_ids
+                                         else arg_vals)[node.name]
+        for k, (tag, seg) in enumerate(segments):
+            if tag is None or collect_all:
+                run_nodes(seg, values, arg_vals, rng, is_train, aux_updates)
+            else:
+                run_block(k, values, arg_vals, rng, is_train, aux_updates)
         if collect_all:
             outputs = [values[(id(n), i)] for n in nodes
                        if not n.is_variable for i in range(n.num_outputs())]

@@ -9,7 +9,7 @@ units prefer; the reference's NCHW remains available via ``layout=``.
 from __future__ import annotations
 
 from ..base import MXNetError
-from . import (alexnet, googlenet, inception_bn, inception_resnet_v2,  # noqa: F401
+from . import (alexnet, decoder_lm, googlenet, inception_bn, inception_resnet_v2,  # noqa: F401
                inception_v3, inception_v4, lenet, mlp,
                mobilenet, resnet, resnext, transformer,
                transformer_sym, vgg)
@@ -31,6 +31,7 @@ _MODELS = {
     "mobilenet": mobilenet.get_symbol,
     "resnext": resnext.get_symbol,
     "transformer_lm": transformer_sym.get_symbol,
+    "decoder_lm": decoder_lm.get_symbol,
 }
 
 

@@ -13,6 +13,9 @@ anywhere from one chip to a 4-D mesh.
 """
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
 
 from ..base import AttrSpec, MXNetError
@@ -73,3 +76,112 @@ def _multi_head_attention(query, key, value, num_heads, causal=False,
         from ..parallel.sequence import _full_attn
         out = _full_attn(q, k, v, causal, None)
     return _merge_heads(out).astype(query.dtype)
+
+
+# -- the decoder's attention: rotary positions, grouped query heads ----------
+
+def rotary_frequencies(rotary_dim, theta, rope_type="default", factor=1.0,
+                       original_max_position=0, beta_fast=32.0,
+                       beta_slow=1.0):
+    """The rotary_dim / 2 inverse frequencies. ``default``:
+    theta^(-2i/r). ``yarn`` (Peng et al. 2023): frequencies whose wavelength
+    fits the original context many times keep their value, those that do
+    not are divided by ``factor``, with a linear ramp between the two over
+    the dimensions that turn ``beta_fast`` to ``beta_slow`` times in
+    ``original_max_position`` positions."""
+    half = rotary_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    if rope_type == "default":
+        return inv
+    if rope_type != "yarn":
+        raise MXNetError(f"RotaryEmbedding: unknown rope_type {rope_type!r}")
+
+    def turns_dim(turns):
+        return rotary_dim * math.log(
+            original_max_position / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), rotary_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inv / factor * ramp + inv * (1.0 - ramp)
+
+
+@register("RotaryEmbedding",
+          attrs=AttrSpec(head_dim=("int",), rotary_dim=("int", 0),
+                         theta=("float", 10000.0),
+                         rope_type=("str", "default"),
+                         factor=("float", 1.0),
+                         original_max_position=("int", 0),
+                         beta_fast=("float", 32.0),
+                         beta_slow=("float", 1.0),
+                         attention_factor=("float", 1.0)),
+          num_inputs=1, input_names=["data"])
+def _rotary_embedding(data, head_dim, rotary_dim=0, theta=10000.0,
+                      rope_type="default", factor=1.0,
+                      original_max_position=0, beta_fast=32.0,
+                      beta_slow=1.0, attention_factor=1.0):
+    """Rotary position embedding over (B, S, heads * head_dim): position s
+    of every head has its first ``rotary_dim`` dims (all of them when 0)
+    rotated by s times the frequencies, dimension i paired with
+    i + rotary_dim / 2; the rest pass. cos and sin are multiplied by
+    ``attention_factor`` (YaRN's scaling of the logits, applied where the
+    published models apply it). Angles in float32, output in the input's
+    dtype."""
+    b, s, e = data.shape
+    r = rotary_dim or head_dim
+    if e % head_dim or r > head_dim or r % 2:
+        raise MXNetError(
+            f"RotaryEmbedding: width {e}, head_dim {head_dim}, rotary_dim "
+            f"{r}: the width is a whole number of heads and the rotated "
+            f"part an even number of dims inside a head")
+    inv = rotary_frequencies(r, theta, rope_type, factor,
+                             original_max_position, beta_fast, beta_slow)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = (jnp.cos(angle) * attention_factor)[None, :, None, :]
+    sin = (jnp.sin(angle) * attention_factor)[None, :, None, :]
+    x = data.reshape(b, s, e // head_dim, head_dim).astype(jnp.float32)
+    x1, x2, rest = x[..., :r // 2], x[..., r // 2:r], x[..., r:]
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return out.reshape(b, s, e).astype(data.dtype)
+
+
+@register("GroupedQueryAttention",
+          attrs=AttrSpec(num_heads=("int",), num_kv_heads=("int",),
+                         window=("int", 0), causal=("bool", True),
+                         gated=("bool", False)),
+          num_inputs=None, input_names=["query", "key", "value", "gate"],
+          output_names=["output"])
+def _grouped_query_attention(*args, num_heads, num_kv_heads, window=0,
+                             causal=True, gated=False):
+    """Attention of ``num_heads`` query heads over ``num_kv_heads`` key/value
+    heads: query (B, S, num_heads * d), key and value (B, S, num_kv_heads *
+    d); query head h reads key/value head h // (num_heads / num_kv_heads).
+    Scores q k^T / sqrt(d); ``causal``; ``window`` > 0 lets key j be seen
+    from i only if i - window < j <= i. With ``gated`` a fourth input ``gate``
+    (B, S, num_heads) multiplies head h's output by sigmoid(gate_h).
+    On a TPU it runs ``ops/pallas/attention.py``'s flash kernel over the
+    band; elsewhere plain masked softmax."""
+    from .pallas.attention import grouped_query_attention
+    query, key, value = args[:3]
+    b, s, e = query.shape
+    if e % num_heads or key.shape[-1] % num_kv_heads \
+            or e // num_heads != key.shape[-1] // num_kv_heads:
+        raise MXNetError(
+            f"GroupedQueryAttention: query width {e} over {num_heads} "
+            f"heads against key width {key.shape[-1]} over {num_kv_heads}")
+    d = e // num_heads
+
+    def heads(x, n):
+        return x.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+
+    out = grouped_query_attention(
+        heads(query, num_heads), heads(key, num_kv_heads),
+        heads(value, num_kv_heads), causal=causal, window=window)
+    out = out.transpose(0, 2, 1, 3)                      # (B, S, H, d)
+    if gated:
+        gate = jax.nn.sigmoid(args[3].astype(jnp.float32))
+        out = (out.astype(jnp.float32) * gate[..., None]).astype(out.dtype)
+    return out.reshape(b, s, e).astype(query.dtype)

@@ -79,3 +79,73 @@ def _switch_ffn(data, gate_weight, expert_w1, expert_b1, expert_w2,
                                    capacity_factor=capacity_factor,
                                    top_k=top_k)
     return out.reshape(shape).astype(data.dtype), aux
+
+
+def _moe_ffn_param_shapes(attrs, shapes):
+    d = shapes[0][-1]
+    e, f = int(attrs["num_experts"]), int(attrs["hidden_size"])
+    held = int(attrs["experts_held"]) or e
+    fs = int(attrs["shared_hidden_size"])
+    out = [shapes[0], (e, d), (held, d, f), (held, d, f), (held, f, d), (3,)]
+    return out + ([(fs, d), (fs, d), (d, fs)] if fs else [])
+
+
+@register("MoEFFN",
+          attrs=AttrSpec(num_experts=("int",), hidden_size=("int",),
+                         top_k=("int", 1), experts_held=("int", 0),
+                         expert_offset=("int", 0),
+                         routed_scale=("float", 1.0),
+                         shared_hidden_size=("int", 0)),
+          num_inputs=None,
+          input_names=["data", "router_weight", "expert_gate_weight",
+                       "expert_up_weight", "expert_down_weight", "stats",
+                       "shared_gate_weight", "shared_up_weight",
+                       "shared_down_weight"],
+          param_shapes=_moe_ffn_param_shapes, needs_is_train=True,
+          aux_inputs=(5,), aux_update={1: 5},
+          aux_counters={5: ("moe.assignments_held", "moe.load_max",
+                            "moe.overflow")})
+def _moe_ffn(data, router_weight, expert_gate_weight, expert_up_weight,
+             expert_down_weight, stats, *shared, num_experts, hidden_size,
+             top_k=1, experts_held=0, expert_offset=0, routed_scale=1.0,
+             shared_hidden_size=0, _is_train=False):
+    """A routed feed-forward layer as one chip of an expert-parallel
+    deployment holds it, over (..., d) inputs.
+
+    The router scores all ``num_experts`` experts (sigmoid, float32), every
+    token keeps its ``top_k`` with weights ``routed_scale * s_e / sum of
+    the chosen s``, and the layer computes the part of the result that its
+    own experts give: those numbered ``expert_offset .. expert_offset +
+    experts_held - 1`` (all of them when ``experts_held`` is 0), each a
+    SwiGLU of width ``hidden_size``, by sort, grouped matmul and weighted
+    return (``parallel.moe.held_experts_apply``): no token is dropped, and
+    the grouped matmul runs every choice's row, held or not, so that a
+    step's time does not hang on the routing.
+    With ``shared_hidden_size`` a shared SwiGLU expert of that width
+    (three more inputs) is added for every token. The shares of all chips,
+    the shared expert counted once, add up to the whole layer.
+
+    The auxiliary state ``stats`` (3,) accumulates per training step, on
+    the device: the token-choices that fell on held experts, the fullest
+    held expert's tokens, and the choices left out for want of room
+    (always 0: the buffer holds the worst case): the op's ``aux_counters``
+    name them. Read them at a boundary (``SPMDTrainer.aux_counters``),
+    never every step."""
+    from ..parallel.moe import held_experts_apply
+    from .nn_ops import gated_ffn
+
+    shape = data.shape
+    toks = data.reshape(-1, shape[-1])
+    y, counts = held_experts_apply(
+        toks, router_weight, expert_gate_weight, expert_up_weight,
+        expert_down_weight, num_experts=num_experts, top_k=top_k,
+        expert_offset=expert_offset, routed_scale=routed_scale)
+    if shared_hidden_size:
+        with jax.named_scope("shared"):
+            y = y + gated_ffn(toks, *shared)
+    if _is_train:
+        step = jnp.stack([jnp.sum(counts), jnp.max(counts),
+                          jnp.zeros((), counts.dtype)])
+        stats = stats + step.astype(stats.dtype)
+    return (y.reshape(shape).astype(data.dtype),
+            jax.lax.stop_gradient(stats))

@@ -367,6 +367,40 @@ def _instance_norm(data, gamma, beta, eps=1e-3):
         + beta.reshape(bshape)
 
 
+@register("RMSNorm", num_inputs=2, input_names=["data", "gamma"],
+          param_shapes=lambda attrs, shapes: [shapes[0], (shapes[0][-1],)],
+          attrs=AttrSpec(eps=("float", 1e-6)))
+def _rms_norm(data, gamma, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, computed in
+    float32 and returned in the input's dtype."""
+    x = data.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+def _gated_ffn_param_shapes(attrs, shapes):
+    d, f = shapes[0][-1], int(attrs["num_hidden"])
+    return [shapes[0], (f, d), (f, d), (d, f)]
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """SwiGLU: (silu(x W_gate^T) * (x W_up^T)) W_down^T, weights (out, in)
+    as ``FullyConnected`` keeps them, matmuls in the activation's dtype."""
+    w_gate, w_up, w_down = (w.astype(x.dtype)
+                            for w in (w_gate, w_up, w_down))
+    hidden = jax.nn.silu(jnp.dot(x, w_gate.T)) * jnp.dot(x, w_up.T)
+    return jnp.dot(hidden, w_down.T)
+
+
+@register("GatedFFN", num_inputs=4,
+          input_names=["data", "gate_weight", "up_weight", "down_weight"],
+          param_shapes=_gated_ffn_param_shapes,
+          attrs=AttrSpec(num_hidden=("int",)))
+def _gated_ffn(data, gate_weight, up_weight, down_weight, num_hidden):
+    """Gated feed-forward (SwiGLU) over the last axis, no bias."""
+    return gated_ffn(data, gate_weight, up_weight, down_weight)
+
+
 @register("LRN", attrs=AttrSpec(alpha=("float", 1e-4), beta=("float", 0.75),
                                 knorm=("float", 2.0), nsize=("int",),
                                 axis=("int", 1)))
@@ -401,6 +435,8 @@ def _activation(data, act_type):
         return jax.nn.softplus(data)
     if act_type == "softsign":
         return data / (1 + jnp.abs(data))
+    if act_type == "silu":
+        return jax.nn.silu(data)
     raise MXNetError(f"unknown act_type {act_type}")
 
 
@@ -600,6 +636,21 @@ def _softmax_cross_entropy(data, label):
     logp = jax.nn.log_softmax(data, axis=-1)
     picked = jnp.take_along_axis(logp, label.astype(jnp.int32)[:, None], axis=-1)
     return -jnp.sum(picked).reshape(1)
+
+
+@register("TokenCrossEntropy", num_inputs=2, input_names=["data", "label"],
+          param_shapes=lambda attrs, shapes: [shapes[0], shapes[0][:-1]])
+def _token_cross_entropy(data, label):
+    """Mean cross-entropy of ``data`` (..., V) logits against ``label``
+    (...) class ids, shape (1,): a loss head whose output IS the loss, so
+    the step hands no (tokens, V) softmax back and a plain ``jax.vjp`` with
+    a ones cotangent gives the gradient of the mean. The log-softmax runs
+    in float32 whatever the logits' dtype."""
+    logits = data.reshape(-1, data.shape[-1]).astype(jnp.float32)
+    idx = label.reshape(-1).astype(jnp.int32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
+    return jnp.mean(lse - picked).reshape(1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))

@@ -451,6 +451,290 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
                              scale, block_q, block_k, force_pallas)
 
 
+# -- grouped query heads, causal window (the decoder's attention) -------------
+
+def _band(i, bq, bk, n_k, causal, window):
+    """First and last key block that query block ``i`` can see. ``i`` may
+    be traced; the rest is static. Keys [q - window + 1, q] under a window,
+    [0, q] under plain causality, all of them otherwise."""
+    if not causal:
+        return 0 * i, 0 * i + (n_k - 1)
+    last = (i * bq + bq - 1) // bk
+    if not window:
+        return 0 * i, last
+    return jnp.maximum(i * bq - (window - 1), 0) // bk, last
+
+
+def _band_steps(n_q, bq, bk, n_k, causal, window):
+    """The most key blocks any query block sees: the static length of the
+    kernel's innermost grid axis."""
+    if not causal:
+        return n_k
+    most = 0
+    for i in range(n_q):
+        last = (i * bq + bq - 1) // bk
+        first = max(i * bq - (window - 1), 0) // bk if window else 0
+        most = max(most, last - first + 1)
+    return most
+
+
+def _band_mask(qpos, kpos, causal, window):
+    """True where key ``kpos`` is seen from query ``qpos`` (broadcastable
+    int32 arrays), or None where every key is."""
+    if not causal:
+        return None
+    mask = kpos <= qpos
+    if window:
+        mask &= kpos > qpos - window
+    return mask
+
+
+def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None):
+    """Plain softmax attention with grouped query heads: q (B, H, S, D),
+    k/v (B, Hkv, S, D), query head h reads key/value head h // (H/Hkv);
+    ``window`` > 0 lets key j be seen from i only if i - window < j <= i.
+    Scores and softmax in float32. The CPU path, and what the kernel and
+    its backward are tested against."""
+    b, h, s, d = q.shape
+    hkv = k.shape[1]
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
+    q5 = q.reshape(b, hkv, h // hkv, s, d)
+    sc = jnp.einsum("bkgqd,bkcd->bkgqc", q5, k,
+                    preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(s)
+    mask = _band_mask(pos[:, None], pos[None, :], causal, window)
+    if mask is not None:
+        sc = jnp.where(mask, sc, _NEG)
+    p = jax.nn.softmax(sc, axis=-1)
+    out = jnp.einsum("bkgqc,bkcd->bkgqd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, s, d).astype(q.dtype)
+
+
+def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                causal, window, scale, n_k):
+    """The flash recurrence over the band only. Grid (B*H, n_q, steps):
+    step ``j`` of query block ``i`` reads key block ``first(i) + j`` (the
+    index map clamps it to ``last(i)``, so a step past the band moves no
+    data and computes nothing). Operands go to the MXU in their own dtype,
+    accumulation is float32."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    first, last = _band(i, bq, bk, n_k, causal, window)
+    kb = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    # bfloat16 operands are exact in one MXU pass; an ambient "highest"
+    # would ask Mosaic for a float32 matmul of them, which it refuses
+    one_pass = jax.lax.Precision.DEFAULT \
+        if q_ref.dtype == jnp.bfloat16 else None
+
+    @pl.when(kb <= last)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), precision=one_pass,
+            preferred_element_type=jnp.float32) * scale     # (BQ, BK)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        mask = _band_mask(i * bq + rows, kb * bk + cols, causal, window)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG)
+        m_prev = m_ref[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[:, None])
+        if mask is not None:
+            # a row whose keys in this block are all outside its window
+            # has s == m_new == _NEG, where exp gives 1
+            p = jnp.where(mask, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
+        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            precision=one_pass, preferred_element_type=jnp.float32)
+        m_ref[:] = m_new[:, None] + jnp.zeros_like(m_ref)
+        l_ref[:] = l_new[:, None] + jnp.zeros_like(l_ref)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        l = jnp.maximum(l_ref[:, 0], 1e-30)
+        o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
+
+
+def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
+                interpret):
+    b, h, s, d = q.shape
+    hkv = k.shape[1]
+    group = h // hkv
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    n_q, n_k = s // bq, s // bk
+    steps = _band_steps(n_q, bq, bk, n_k, causal, window)
+
+    def kv_index(bh, i, j):
+        first, last = _band(i, bq, bk, n_k, causal, window)
+        return bh // group, jnp.minimum(first + j, last), 0
+
+    kernel = functools.partial(_gqa_kernel, causal=causal, window=window,
+                               scale=scale, n_k=n_k)
+    out = pl.pallas_call(
+        kernel,
+        grid=(b * h, n_q, steps),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, d), kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, 128), jnp.float32),  # running max m
+            pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
+            pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
+        ],
+        interpret=interpret,
+        name="gqa_flash_attention",
+    )(q.reshape(b * h, s, d), k.reshape(b * hkv, s, d),
+      v.reshape(b * hkv, s, d))
+    return out.reshape(b, h, s, d)
+
+
+def _gqa_blockwise_bwd(q, k, v, out, do, causal, window, scale, block):
+    """The backward over the same band, in jnp: an outer scan over query
+    blocks, and for each a loop over the key blocks it sees (first the row
+    logsumexp, then the gradients), so the temporaries are one
+    (B, H, BQ, BK) tile and no key block outside the band is touched.
+    Matmul operands stay in the inputs' dtype with float32 accumulation."""
+    b, h, s, d = q.shape
+    hkv = k.shape[1]
+    g = h // hkv
+    blk = _pick_block(s, block)
+    n = s // blk
+    q5 = q.reshape(b, hkv, g, s, d)
+    do5 = do.reshape(b, hkv, g, s, d)
+    delta = jnp.sum(do5.astype(jnp.float32)
+                    * out.reshape(b, hkv, g, s, d).astype(jnp.float32), -1)
+    rows = jnp.arange(blk)
+
+    def probs(qi, kj, i, j, lse):
+        sc = jnp.einsum("bkgqd,bkcd->bkgqc", qi, kj,
+                        preferred_element_type=jnp.float32) * scale
+        mask = _band_mask((i * blk + rows)[:, None],
+                          (j * blk + rows)[None, :], causal, window)
+        if mask is not None:
+            sc = jnp.where(mask, sc, _NEG)
+        if lse is None:
+            return sc
+        p = jnp.exp(sc - lse[..., None])
+        return p if mask is None else jnp.where(mask, p, 0.0)
+
+    def key_block(x, j):
+        return jax.lax.dynamic_slice_in_dim(x, j * blk, blk, axis=2)
+
+    def query_block(carry, i):
+        dk, dv = carry
+        qi = jax.lax.dynamic_slice_in_dim(q5, i * blk, blk, axis=3)
+        doi = jax.lax.dynamic_slice_in_dim(do5, i * blk, blk, axis=3)
+        di = jax.lax.dynamic_slice_in_dim(delta, i * blk, blk, axis=3)
+        first, last = _band(i, blk, blk, n, causal, window)
+
+        def lse_step(j, ml):
+            m, l = ml
+            sc = probs(qi, key_block(k, j), i, j, None)
+            m_new = jnp.maximum(m, jnp.max(sc, -1))
+            l = l * jnp.exp(m - m_new) + jnp.sum(
+                jnp.exp(sc - m_new[..., None]), -1)
+            return m_new, l
+
+        m, l = jax.lax.fori_loop(
+            first, last + 1, lse_step,
+            (jnp.full((b, hkv, g, blk), _NEG, jnp.float32),
+             jnp.zeros((b, hkv, g, blk), jnp.float32)))
+        lse = m + jnp.log(jnp.maximum(l, 1e-30))
+
+        def grad_step(j, c):
+            dqi, dk, dv = c
+            kj, vj = key_block(k, j), key_block(v, j)
+            p = probs(qi, kj, i, j, lse)
+            dp = jnp.einsum("bkgqd,bkcd->bkgqc", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - di[..., None]) * scale).astype(q.dtype)
+            dqi = dqi + jnp.einsum("bkgqc,bkcd->bkgqd", ds, kj,
+                                   preferred_element_type=jnp.float32)
+            dk_j = jnp.einsum("bkgqc,bkgqd->bkcd", ds, qi,
+                              preferred_element_type=jnp.float32)
+            dv_j = jnp.einsum("bkgqc,bkgqd->bkcd", p.astype(do.dtype), doi,
+                              preferred_element_type=jnp.float32)
+            dk = jax.lax.dynamic_update_slice_in_dim(
+                dk, key_block(dk, j) + dk_j, j * blk, axis=2)
+            dv = jax.lax.dynamic_update_slice_in_dim(
+                dv, key_block(dv, j) + dv_j, j * blk, axis=2)
+            return dqi, dk, dv
+
+        dqi, dk, dv = jax.lax.fori_loop(
+            first, last + 1, grad_step,
+            (jnp.zeros((b, hkv, g, blk, d), jnp.float32), dk, dv))
+        return (dk, dv), dqi.astype(q.dtype)
+
+    zeros = jnp.zeros((b, hkv, s, d), jnp.float32)
+    (dk, dv), dq = jax.lax.scan(query_block, (zeros, zeros), jnp.arange(n))
+    dq = jnp.moveaxis(dq, 0, 3).reshape(b, h, s, d)   # (n,b,k,g,blk,d)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _gqa_attention(q, k, v, causal, window, scale, block, interpret):
+    return _gqa_pallas(q, k, v, causal, window, scale, block, block,
+                       interpret)
+
+
+def _gqa_fwd(q, k, v, causal, window, scale, block, interpret):
+    out = _gqa_pallas(q, k, v, causal, window, scale, block, block,
+                      interpret)
+    return out, (q, k, v, out)
+
+
+def _gqa_bwd(causal, window, scale, block, interpret, res, ct):
+    q, k, v, out = res
+    return _gqa_blockwise_bwd(q, k, v, out, ct, causal, window, scale,
+                              block)
+
+
+_gqa_attention.defvjp(_gqa_fwd, _gqa_bwd)
+
+
+def grouped_query_attention(q, k, v, causal=True, window=0, scale=None,
+                            block=512, force_pallas=False):
+    """Attention of H query heads over Hkv <= H key/value heads: q
+    (B, H, S, D), k/v (B, Hkv, S, D), H a multiple of Hkv; ``window`` > 0
+    (causal only) keeps keys i - window < j <= i.
+
+    On a TPU the forward is the flash kernel run over the band alone (key
+    blocks above the diagonal or behind the window are neither fetched nor
+    computed) and the backward the blockwise jnp recurrence over the same
+    band. Elsewhere it is :func:`gqa_attention_reference`, differentiated
+    by JAX; ``force_pallas`` runs kernel and backward through the Pallas
+    interpreter (tests)."""
+    b, h, s, d = q.shape
+    if k.shape != v.shape or k.shape[0] != b or k.shape[2:] != (s, d) \
+            or h % k.shape[1]:
+        raise ValueError(
+            f"grouped_query_attention: q {q.shape} against k {k.shape}, "
+            f"v {v.shape}: same batch, length and head size, and a number "
+            f"of query heads that is a multiple of the key/value heads")
+    if window and not causal:
+        raise ValueError("a window is causal: window > 0 needs causal=True")
+    scale = 1.0 / (d ** 0.5) if scale is None else float(scale)
+    on_tpu = jax.default_backend() == "tpu"
+    if not on_tpu and not force_pallas:
+        return gqa_attention_reference(q, k, v, causal, window, scale)
+    return _gqa_attention(q, k, v, bool(causal), int(window), scale,
+                          int(block), not on_tpu)
+
+
 from ..registry import register  # noqa: E402
 from ...base import AttrSpec  # noqa: E402
 

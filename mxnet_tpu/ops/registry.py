@@ -52,6 +52,7 @@ class OpDef:
         aux_inputs: Sequence[int] = (),
         param_shapes: Optional[Callable] = None,
         stateful: bool = False,
+        aux_counters: Optional[Dict[int, Sequence[str]]] = None,
     ):
         self.name = name
         self.fn = fn
@@ -83,6 +84,11 @@ class OpDef:
         # so forward-created state reaches backward (reference: stateful ops
         # save an OpStatePtr on the tape — SURVEY.md §3.3)
         self.stateful = stateful
+        # auxiliary input idx -> the names of the counters its vector holds,
+        # one an element, which the op adds to on the device every training
+        # step; a trainer reads them at a boundary (SPMDTrainer.aux_counters)
+        self.aux_counters = {k: tuple(v)
+                             for k, v in (aux_counters or {}).items()}
 
     def num_outputs(self, attrs) -> int:
         if callable(self._num_outputs):

@@ -22,7 +22,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..base import MXNetError
 
 __all__ = ["moe_apply", "moe_dense_apply", "top1_router", "topk_router",
-           "load_balance_loss"]
+           "load_balance_loss", "sigmoid_topk_router", "held_experts_apply"]
 
 
 def top1_router(x, router_w):
@@ -188,3 +188,109 @@ def moe_apply(x, router_w, expert_params, expert_fn: Callable, mesh: Mesh,
         out_specs=(P(axis_name), P()), check_vma=False)
     out, aux = fn(x, router_w, expert_params)
     return (out, aux) if return_aux else out
+
+
+# -- the share of a routed layer that one chip holds, with no token dropped ---
+
+def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0):
+    """Sigmoid scores over all experts in float32, the ``k`` largest, and
+    their weights ``scale * s_e / sum of the chosen s``. ``router_w`` is
+    (E, d) as ``FullyConnected`` keeps it. Returns (weights (T, k) float32,
+    indices (T, k) int32)."""
+    logits = jax.lax.dot_general(
+        x, router_w.astype(x.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    if k > scores.shape[-1]:
+        raise MXNetError(f"top_k={k} exceeds the number of experts "
+                         f"{scores.shape[-1]}")
+    top, idx = jax.lax.top_k(scores, k)
+    return scale * top / jnp.sum(top, -1, keepdims=True), idx
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_by_slot(x, take, put, k):
+    """``x[take // k]``: row r of the result is the token of slot
+    ``take[r]`` (k slots a token). ``put`` is the inverse permutation, so
+    the cotangent is a gather too: slot by slot, then summed over a token's
+    k slots; a scatter-add over 8 T rows is what this spares."""
+    return x[take // k]
+
+
+def _rows_by_slot_fwd(x, take, put, k):
+    return x[take // k], put
+
+
+def _rows_by_slot_bwd(k, put, g):
+    per_slot = g[put]
+    return per_slot.reshape(-1, k, g.shape[-1]).sum(1), None, None
+
+
+_rows_by_slot.defvjp(_rows_by_slot_fwd, _rows_by_slot_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, take, put):
+    """``x[take]`` for a permutation ``take`` whose inverse is ``put``: the
+    cotangent is ``g[put]``, a gather again."""
+    return x[take]
+
+
+_permute_rows.defvjp(lambda x, take, put: (x[take], put),
+                     lambda put, g: (g[put], None, None))
+
+
+def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
+                       top_k, expert_offset=0, routed_scale=1.0):
+    """What the experts held here add to a routed layer's output.
+
+    ``x`` (T, d) tokens; ``router_w`` (E, d) scores ALL ``num_experts``
+    experts and every token keeps its ``top_k``; the stacked weights
+    ``w_gate``/``w_up`` (Eh, d, f) and ``w_down`` (Eh, f, d) are those of
+    experts ``expert_offset .. expert_offset + Eh - 1``, each a SwiGLU.
+    The token-choices are sorted by expert, the absent experts' last, and
+    ALL of them run through a grouped matmul (``jax.lax.ragged_dot``): the
+    absent experts' choices ride at the end of the last held expert's
+    group and their results are put to zero, so a step costs the same
+    wherever the routing goes: the grouped matmul's time goes with the
+    rows in its groups, and with the held rows alone in them the step's
+    time moves by 4% between a routing that passes this chip by and one
+    that lands on it (it is a seed's coin which; PERF.md, PR 27). The
+    price is the matmul of 8 T rows a layer, always. The results return
+    to their tokens weighted: no (T, E, C) tensor, no capacity, nothing
+    dropped. What the absent experts would add is left out. Returns
+    (y (T, d) in x's dtype, counts (Eh,) int32: the choices that fell on
+    each held expert)."""
+    t, d = x.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("route"):
+        weights, chosen = sigmoid_topk_router(x, router_w, top_k,
+                                              routed_scale)
+    with jax.named_scope("dispatch"):
+        local = chosen.reshape(-1) - expert_offset          # slot -> expert
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        # slots in the order of their expert, the absent ones last
+        take = jnp.argsort(local, stable=True).astype(jnp.int32)
+        put = jnp.argsort(take).astype(jnp.int32)
+        counts = jnp.sum(
+            local[:, None] == jnp.arange(held, dtype=local.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        n_held = jnp.sum(counts)
+        # every row lies in a group (the grouped matmul writes no other)
+        groups = counts.at[-1].add((t * top_k - n_held).astype(counts.dtype))
+        is_held = (jnp.arange(t * top_k) < n_held)[:, None]
+        rows = _rows_by_slot(x, take, put, top_k)
+    with jax.named_scope("experts"):
+        dt = x.dtype
+        gate = jax.lax.ragged_dot(rows, w_gate.astype(dt), groups)
+        up = jax.lax.ragged_dot(rows, w_up.astype(dt), groups)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dt),
+                                 groups)
+        # selected, not multiplied: the cotangent of an absent row is zero
+        # too, so it reaches neither the tokens nor the last expert
+        out = jnp.where(is_held, out, 0)
+    with jax.named_scope("combine"):
+        per_slot = _permute_rows(out, put, take).reshape(t, top_k, d)
+        y = jnp.sum(per_slot.astype(jnp.float32) * weights[..., None],
+                    axis=1)
+    return y.astype(x.dtype), counts

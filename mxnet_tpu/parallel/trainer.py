@@ -303,7 +303,8 @@ class SPMDTrainer:
         # HBM budget gate; only the fingerprint/eval build remains here)
         self._graph_fingerprint = _compiler.graph_fingerprint(
             self._opt_res.symbol)
-        self._eval_fn = build_graph_eval(self._opt_res.symbol)
+        self._eval_fn = build_graph_eval(
+            self._opt_res.symbol, remat_blocks=self._opt_res.remat_blocks)
         eval_fn = self._eval_fn
         # the explicit mirror knob must survive MXTPU_GRAPH_PASSES=0
         from ..base import getenv as _getenv
@@ -654,6 +655,23 @@ class SPMDTrainer:
         scale, streak = self._ls_state
         return {"scale": float(np.asarray(scale)),
                 "finite_streak": int(np.asarray(streak))}
+
+    def aux_counters(self):
+        """Host snapshot of the counters the graph's ops keep on the device:
+        ``{node name: {counter name: value}}`` for every node whose op
+        declares ``aux_counters`` (a vector of an auxiliary state, one
+        element a counter, added to in the donated step since bind). A
+        boundary read (one small transfer per node), never on the step
+        path; {} for a graph with no such op."""
+        out = {}
+        for node in self._symbol._topo_nodes():
+            if node.is_variable or not node.op.aux_counters:
+                continue
+            found = out[node.name] = {}
+            for idx, names in node.op.aux_counters.items():
+                values = np.asarray(self.aux[node.inputs[idx][0].name])
+                found.update(zip(names, map(float, values)))
+        return out
 
     def integrity_stats(self):
         """Host snapshot of the in-trace divergence-sentinel state (None

@@ -799,6 +799,10 @@ def _expected_inputs(opdef: OpDef, attrs: Dict) -> int:
         return 2 if attrs.get("use_sequence_length") else 1
     if opdef.name == "UpSampling":
         return int(attrs.get("num_args", 1) or 1)
+    if opdef.name == "GroupedQueryAttention":
+        return 4 if attrs.get("gated") else 3
+    if opdef.name == "MoEFFN":
+        return 9 if attrs.get("shared_hidden_size") else 6
     if opdef.name == "_contrib_CTCLoss":
         return (2 + bool(attrs.get("use_data_lengths"))
                 + bool(attrs.get("use_label_lengths")))

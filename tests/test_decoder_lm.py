@@ -1,0 +1,452 @@
+"""The decoder's ops and model (``models/decoder_lm.py``) at a small size on
+the CPU: every new op against a ``jax.numpy`` one-liner, forward and
+gradient; the windowed grouped-query attention (plain path, and the kernel
+with its blockwise backward through the Pallas interpreter) against
+explicit-mask softmax; the routed layer's shares against the whole layer;
+recomputation by block; and the whole tiny model, every layer kind, through
+``SPMDTrainer.fit`` against the benchmark's plain reference."""
+import json
+import math
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import models
+from mxnet_tpu.ops.registry import get_op
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def _rand(*shape, seed=0, scale=1.0):
+    return scale * jax.random.normal(jax.random.PRNGKey(seed), shape,
+                                     jnp.float32)
+
+
+def _close(a, b, tol=2e-5):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                               atol=tol)
+
+
+def _same_with_gradients(fn, ref, *args, tol=2e-5):
+    """Value and every argument's gradient (under a random cotangent) of
+    ``fn`` against ``ref``, each traced once."""
+    idx = tuple(range(len(args)))
+    ct = _rand(*jax.eval_shape(ref, *args).shape, seed=99)
+
+    def both(f):
+        return jax.jit(lambda *a: (f(*a), jax.grad(
+            lambda *b: jnp.sum(f(*b) * ct), idx)(*a)))(*args)
+
+    (got, got_grads), (want, want_grads) = both(fn), both(ref)
+    _close(got, want, tol)
+    for g, w in zip(got_grads, want_grads):
+        _close(g, w, tol)
+
+
+# -- one op, one one-liner ------------------------------------------------------
+
+def test_rms_norm():
+    x, g = _rand(3, 5, 16), 1.0 + _rand(16, seed=1, scale=0.1)
+    _same_with_gradients(
+        lambda x, g: get_op("RMSNorm").fn(x, g, eps=1e-6),
+        lambda x, g: x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+        * g, x, g)
+
+
+def test_gated_ffn_and_silu():
+    x = _rand(7, 16)
+    w1, w3, w2 = _rand(24, 16, seed=1), _rand(24, 16, seed=2), \
+        _rand(16, 24, seed=3)
+    _same_with_gradients(
+        lambda *a: get_op("GatedFFN").fn(*a, num_hidden=24),
+        lambda x, w1, w3, w2: ((x @ w1.T) * jax.nn.sigmoid(x @ w1.T)
+                               * (x @ w3.T)) @ w2.T, x, w1, w3, w2)
+    _close(get_op("Activation").fn(x, act_type="silu"),
+           x * jax.nn.sigmoid(x))
+
+
+def test_token_cross_entropy_returns_the_mean_loss_only():
+    logits = _rand(2, 6, 11)
+    labels = jnp.asarray(np.random.default_rng(0).integers(0, 11, (2, 6)),
+                         jnp.float32)
+    fn = lambda z: get_op("TokenCrossEntropy").fn(z, labels)      # noqa: E731
+    ref = lambda z: -jnp.mean(jnp.take_along_axis(                # noqa: E731
+        jax.nn.log_softmax(z), labels.astype(jnp.int32)[..., None],
+        -1)).reshape(1)
+    _same_with_gradients(fn, ref, logits)
+    assert fn(logits).shape == (1,)
+    # computed in float32 from bfloat16 logits
+    low = fn(logits.astype(jnp.bfloat16))
+    assert low.dtype == jnp.float32
+    _close(low, ref(logits.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+def _rotate_by_hand(x, head_dim, r, inv, factor=1.0):
+    b, s, e = x.shape
+    x = np.asarray(x, np.float64).reshape(b, s, e // head_dim, head_dim)
+    out = x.copy()
+    for pos in range(s):
+        for i in range(r // 2):
+            c = math.cos(pos * inv[i]) * factor
+            sn = math.sin(pos * inv[i]) * factor
+            a, bb = x[:, pos, :, i], x[:, pos, :, i + r // 2]
+            out[:, pos, :, i] = a * c - bb * sn
+            out[:, pos, :, i + r // 2] = bb * c + a * sn
+    return out.reshape(b, s, e)
+
+
+def test_rotary_embedding_whole_head_and_partial_yarn():
+    x = _rand(2, 9, 3 * 8)
+    inv = [10000.0 ** (-2 * i / 8) for i in range(4)]
+    _close(get_op("RotaryEmbedding").fn(x, head_dim=8),
+           _rotate_by_hand(x, 8, 8, inv), 1e-5)
+    # YaRN on half of each head: theta 500000, factor 64 over 16 positions
+    attrs = dict(head_dim=8, rotary_dim=4, theta=500000.0, rope_type="yarn",
+                 factor=64.0, original_max_position=16, beta_fast=64.0,
+                 beta_slow=1.0, attention_factor=1.25)
+    base = [500000.0 ** (-2 * i / 4) for i in range(2)]
+
+    def dim_of(turns):
+        return 4 * math.log(16 / (turns * 2 * math.pi)) \
+            / (2 * math.log(500000.0))
+
+    low = max(math.floor(dim_of(64.0)), 0)
+    high = min(math.ceil(dim_of(1.0)), 3)
+    inv = []
+    for i, f in enumerate(base):
+        ramp = min(max((i - low) / max(high - low, 1e-3), 0.0), 1.0)
+        inv.append(f / 64.0 * ramp + f * (1 - ramp))
+    got = get_op("RotaryEmbedding").fn(x, **attrs)
+    _close(got, _rotate_by_hand(x, 8, 4, inv, 1.25), 1e-5)
+    # the dims past rotary_dim pass
+    _close(np.asarray(got).reshape(2, 9, 3, 8)[..., 4:],
+           np.asarray(x).reshape(2, 9, 3, 8)[..., 4:])
+    # a rotation: its transpose is its inverse, so gradients are checked by
+    # the norm it keeps (attention_factor 1)
+    plain = get_op("RotaryEmbedding").fn(x, head_dim=8)
+    _close(jnp.sum(plain ** 2), jnp.sum(x ** 2), 1e-4)
+
+
+def _attention_by_mask(q, k, v, gate, heads, kv, window):
+    """Explicit-mask softmax, the key/value heads repeated."""
+    b, s, _ = q.shape
+    d = q.shape[-1] // heads
+    qh = q.reshape(b, s, heads, d)
+    kh = jnp.repeat(k.reshape(b, s, kv, d), heads // kv, axis=2)
+    vh = jnp.repeat(v.reshape(b, s, kv, d), heads // kv, axis=2)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / math.sqrt(d)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = j <= i
+    if window:
+        seen &= j > i - window
+    p = jax.nn.softmax(jnp.where(seen, sc, -jnp.inf), -1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
+    return (out * jax.nn.sigmoid(gate)[..., None]).reshape(b, s, heads * d)
+
+
+@pytest.mark.parametrize("group,window", [(6, 0), (8, 5), (6, 7), (8, 0)])
+def test_grouped_query_attention_op(group, window):
+    kv, d, s = 2, 8, 24
+    heads = kv * group
+    q, k = _rand(2, s, heads * d), _rand(2, s, kv * d, seed=1)
+    v, gate = _rand(2, s, kv * d, seed=2), _rand(2, s, heads, seed=3)
+    _same_with_gradients(
+        lambda *a: get_op("GroupedQueryAttention").fn(
+            *a, num_heads=heads, num_kv_heads=kv, window=window, gated=True),
+        lambda *a: _attention_by_mask(*a, heads, kv, window), q, k, v, gate)
+
+
+@pytest.mark.parametrize("group,window,block", [(6, 16, 16), (8, 24, 16),
+                                                (6, 0, 16), (8, 40, 32)])
+def test_band_kernel_and_blockwise_backward_in_the_interpreter(group, window,
+                                                               block):
+    """What the chip runs: the flash kernel over the band and the blockwise
+    backward over the same band, against plain softmax differentiated by
+    JAX."""
+    from mxnet_tpu.ops.pallas.attention import (gqa_attention_reference,
+                                                grouped_query_attention)
+    kv, d, s = 2, 8, 64
+    q = _rand(2, kv * group, s, d)
+    k, v = _rand(2, kv, s, d, seed=1), _rand(2, kv, s, d, seed=2)
+    with jax.enable_x64(False):
+        _same_with_gradients(
+            lambda *a: grouped_query_attention(
+                *a, causal=True, window=window, block=block,
+                force_pallas=True),
+            lambda *a: gqa_attention_reference(*a, True, window), q, k, v)
+
+
+def test_band_skips_the_blocks_a_query_block_cannot_see():
+    from mxnet_tpu.ops.pallas.attention import _band, _band_steps
+    # 8192 positions in blocks of 512: a window of 512 sees two blocks
+    assert _band_steps(16, 512, 512, 16, True, 512) == 2
+    assert _band_steps(16, 512, 512, 16, True, 0) == 16
+    assert _band_steps(16, 512, 512, 16, False, 0) == 16
+    assert [int(x) for x in _band(jnp.int32(5), 512, 512, 16, True, 512)] \
+        == [4, 5]
+    assert [int(x) for x in _band(jnp.int32(5), 512, 512, 16, True, 0)] \
+        == [0, 5]
+
+
+# -- the routed layer ---------------------------------------------------------
+
+def _moe_inputs(t=40, d=16, e=16, f=8, fs=8):
+    return dict(
+        x=_rand(t, d), router=_rand(e, d, seed=1),
+        gate=_rand(e, d, f, seed=2, scale=0.3),
+        up=_rand(e, d, f, seed=3, scale=0.3),
+        down=_rand(e, f, d, seed=4, scale=0.3),
+        shared=(_rand(fs, d, seed=5, scale=0.3),
+                _rand(fs, d, seed=6, scale=0.3),
+                _rand(d, fs, seed=7, scale=0.3)))
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def _whole_layer(m, top_k, scale):
+    """The uncut layer, every token through every expert it chose."""
+    s = jax.nn.sigmoid(m["x"] @ m["router"].T)
+    top, idx = jax.lax.top_k(s, top_k)
+    w = scale * top / top.sum(-1, keepdims=True)
+    y = 0.0
+    for e in range(m["router"].shape[0]):
+        weight = jnp.sum(jnp.where(idx == e, w, 0.0), -1, keepdims=True)
+        y = y + weight * _swiglu(m["x"], m["gate"][e], m["up"][e],
+                                 m["down"][e])
+    sg, su, sd = m["shared"]
+    return y + _swiglu(m["x"], sg.T, su.T, sd.T)
+
+
+def _share(m, offset, held, top_k, scale, shared=True):
+    extra = m["shared"] if shared else ()
+    return get_op("MoEFFN").fn(
+        m["x"], m["router"], m["gate"][offset:offset + held],
+        m["up"][offset:offset + held], m["down"][offset:offset + held],
+        jnp.zeros(3), *extra, num_experts=m["router"].shape[0],
+        hidden_size=m["gate"].shape[-1], top_k=top_k, experts_held=held,
+        expert_offset=offset, routed_scale=scale,
+        shared_hidden_size=m["shared"][0].shape[0] if shared else 0,
+        _is_train=True)
+
+
+def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
+    """Offsets 0, E/4, ...: every share's routed part, the shared expert
+    counted once, is the uncut reference's layer output; and every
+    token-choice is counted by exactly one share."""
+    m = _moe_inputs()
+    total, choices = 0.0, 0.0
+    for k, offset in enumerate(range(0, 16, 4)):
+        y, stats = _share(m, offset, 4, 4, 2.5, shared=(k == 0))
+        total = total + y
+        choices += float(stats[0])
+        assert float(stats[2]) == 0.0
+    _close(total, _whole_layer(m, 4, 2.5), 1e-5)
+    assert choices == 40 * 4
+
+
+def test_moe_ffn_gradients_against_the_masked_sum():
+    m = _moe_inputs()
+
+    def fn(x, router, gate, up, down):
+        mm = dict(m, x=x, router=router, gate=gate, up=up, down=down)
+        return _share(mm, 0, 16, 4, 2.5)[0]
+
+    def ref(x, router, gate, up, down):
+        return _whole_layer(dict(m, x=x, router=router, gate=gate, up=up,
+                                 down=down), 4, 2.5)
+
+    _same_with_gradients(fn, ref, m["x"], m["router"], m["gate"], m["up"],
+                         m["down"], tol=5e-5)
+
+
+def test_nothing_is_dropped_when_every_token_chooses_one_expert():
+    m = _moe_inputs()
+    # expert 2's score is the largest for every token, top_k 1
+    m["x"] = jnp.abs(m["x"])
+    m["router"] = jnp.zeros_like(m["router"]).at[2].set(1.0)
+    y, stats = _share(m, 0, 4, 1, 1.0)
+    assert [float(v) for v in stats] == [40.0, 40.0, 0.0]
+    _close(y, _whole_layer(m, 1, 1.0), 1e-5)
+    # a share that does not hold it adds the shared expert alone
+    y, stats = _share(m, 4, 4, 1, 1.0, shared=False)
+    assert [float(v) for v in stats] == [0.0, 0.0, 0.0]
+    _close(y, jnp.zeros_like(y))
+
+
+@pytest.mark.parametrize("offset, on_held", [(0, 40), (4, 0), (12, 0)])
+def test_the_grouped_matmul_does_the_same_work_wherever_the_routing_goes(
+        monkeypatch, offset, on_held):
+    """Every choice lies in a group, held or not (a step's time must not
+    hang on the routing), and an absent expert's rows, which ride in the
+    last held expert's group, reach neither the output nor its gradient."""
+    m = _moe_inputs()
+    m["x"] = jnp.abs(m["x"])
+    m["router"] = jnp.zeros_like(m["router"]).at[3].set(1.0)   # all choose 3
+    seen = []
+    ragged_dot = jax.lax.ragged_dot
+
+    def counting(lhs, rhs, group_sizes, **kw):
+        seen.append((lhs.shape[0], int(jnp.sum(group_sizes))))
+        return ragged_dot(lhs, rhs, group_sizes, **kw)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", counting)
+    y, stats = _share(m, offset, 4, 1, 1.0, shared=False)
+    assert seen == [(40, 40)] * 3 and float(stats[0]) == on_held
+    monkeypatch.undo()
+
+    def loss(gate, up, down):
+        mm = dict(m, gate=gate, up=up, down=down)
+        return jnp.sum(_share(mm, offset, 4, 1, 1.0, shared=False)[0] ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(m["gate"], m["up"], m["down"])
+    for g in grads:
+        # only expert 3 was chosen: no other expert's weights move
+        moved = {int(e) for e in np.nonzero(np.abs(np.asarray(g)).reshape(
+            g.shape[0], -1).sum(1))[0]}
+        assert moved == ({3} if on_held else set())
+    if not on_held:
+        _close(y, jnp.zeros_like(y))
+
+
+# -- the model ------------------------------------------------------------------
+
+def _tiny(**over):
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "laguna-xs2.json")) as f:
+        cfg = json.load(f)
+    cfg.update(cfg["rehearse"])
+    # every kind of layer once: full and dense, sliding and sparse, full
+    # and sparse
+    cfg.update(num_hidden_layers=3, compute_dtype="float32",
+               layer_types=["full_attention", "sliding_attention",
+                            "full_attention"],
+               num_attention_heads_per_layer=[12, 16, 12],
+               mlp_layer_types=["dense", "sparse", "sparse"])
+    cfg.update(over)
+    return cfg
+
+
+def test_symbol_follows_the_per_layer_lists():
+    cfg = _tiny()
+    plan = models.decoder_lm.layer_plan(cfg)
+    assert [p["attention"] for p in plan] == [
+        "full_attention", "sliding_attention", "full_attention"]
+    assert [p["heads"] for p in plan] == [12, 16, 12]
+    assert [p["window"] for p in plan] == [0, 16, 0]
+    assert [p["mlp"] for p in plan] == ["dense", "sparse", "sparse"]
+    assert plan[0]["rope"]["rope_type"] == "yarn"
+    sym = models.get_symbol("decoder_lm", cfg=cfg)
+    assert sym.list_auxiliary_states() == [
+        f"layer{k}_moe_stats" for k in range(1, 3)]
+    shapes = dict(zip(sym.list_arguments(), sym.infer_shape(
+        data=(2, 32), softmax_label=(2, 32))[0]))
+    assert shapes["layer1_q_weight"] == (16 * 16, 64)
+    assert shapes["layer0_q_weight"] == (12 * 16, 64)
+    assert shapes["layer0_gate_weight"] == (12, 64)
+    assert shapes["layer1_moe_router_weight"] == (16, 64)
+    assert shapes["layer1_moe_expert_gate_weight"] == (4, 64, 32)
+    assert shapes["layer0_mlp_down_weight"] == (64, 128)
+    # every node of a layer carries its block and the ask to recompute it
+    blocks = {n.scope_attrs.get("__block__") for n in sym._topo_nodes()
+              if not n.is_variable}
+    assert blocks == {None, "loss_head"} | {f"layer{k}" for k in range(3)}
+
+
+def test_blocks_are_checkpoints_where_the_model_asks():
+    from mxnet_tpu import compiler
+    from mxnet_tpu.executor import build_graph_eval
+    cfg = _tiny(num_hidden_layers=2)
+    sym = models.get_symbol("decoder_lm", cfg=cfg)
+    assert compiler.optimize(sym, for_training=True).remat_blocks
+    assert "rematblocks=1" in compiler.optimize(sym).transform_sig
+    plain = models.get_symbol("decoder_lm", cfg=dict(cfg, recompute=None))
+    assert not compiler.optimize(plain, for_training=True).remat_blocks
+    assert not compiler.optimize(sym, for_training=False).remat_blocks
+
+    shapes = dict(zip(sym.list_arguments(), sym.infer_shape(
+        data=(1, 32), softmax_label=(1, 32))[0]))
+    rng = np.random.default_rng(0)
+    args = {n: jnp.asarray(0.1 * rng.standard_normal(s), jnp.float32)
+            for n, s in shapes.items()}
+    args["data"] = jnp.asarray(rng.integers(0, 96, (1, 32)), jnp.float32)
+    args["softmax_label"] = args["data"]
+    aux = {n: jnp.zeros(3) for n in sym.list_auxiliary_states()}
+
+    def loss(remat):
+        fn = build_graph_eval(sym, remat_blocks=remat)
+
+        def f(p):
+            outs, ups = fn(dict(args, **p), aux, None, True)
+            return outs[0][0], ups
+        params = {n: v for n, v in args.items()
+                  if n not in ("data", "softmax_label")}
+        step = jax.jit(jax.value_and_grad(f, has_aux=True))
+        return step(params), step.lower(params).as_text()
+
+    ((a, ups_a), ga), text_a = loss(True)
+    ((b, ups_b), gb), text_b = loss(False)
+    assert float(a) == pytest.approx(float(b), rel=1e-6)
+    for n in ga:
+        _close(ga[n], gb[n], 1e-5)
+    _close(ups_a["layer1_moe_stats"], ups_b["layer1_moe_stats"])
+    # a checkpoint keeps its inside from being shared with the backward
+    assert "optimization_barrier" in text_a
+    assert "optimization_barrier" not in text_b
+
+
+def test_tiny_model_trains_through_fit_like_the_reference():
+    """Every layer kind once, 16 experts of which 4 held, through
+    ``SPMDTrainer.fit`` fed by ``PrefetchingIter(NDArrayIter)``: loss of
+    each step, the first gradient and three Adam steps against the
+    benchmark's plain reference."""
+    from perfbench import compare, feed
+    from perfbench import run as harness
+    cell = harness.load_cell("laguna-xs2.train-fed-seq8k", rehearse=True)
+    cfg = _tiny(**{k: v for k, v in cell["cfg"].items()
+                   if k in ("flops_seq_len", "reference")})
+    traffic = cell["traffic_params"]
+    model = harness.load_module("models", "laguna-xs2")
+    driver = harness.load_module("drivers", "train_fit")
+    program = model.Program(cfg, traffic, 7, jax.devices())
+    batches = model.make_batches(cfg, traffic, 7)
+    window = feed.Window(feed.inner_iterator(
+        traffic, batches, program.input_shardings(), program.input_names))
+    record = driver.checked_steps(program, window, batches, 3)
+    # the first boundary read publishes nothing; the one after two more
+    # steps adds what the device's counters grew by to the program's own
+    counters = program.routed_counters()
+    by_node = program.trainer.aux_counters()
+    assert sorted(by_node) == ["layer1_moe", "layer2_moe"]
+    assert set(by_node["layer1_moe"]) == set(model.ROUTED)
+    before = mx.profiler.counters().get("moe.assignments_held", 0)
+    assert set(program.counters()) == {"step_programs"}
+    assert mx.profiler.counters().get("moe.assignments_held", 0) == before
+    window.arm(batches=2)
+    program.fit(window)
+    program.sync()
+    program.counters()
+    assert mx.profiler.counters()["moe.assignments_held"] - before == \
+        program.routed_counters()["moe.assignments_held"] \
+        - counters["moe.assignments_held"] > 0
+    program.close()
+    ref = model.reference(cfg, traffic, 7, devices=jax.devices())
+    numbers, _ = compare.gaps(record, ref)
+    assert numbers["loss_gap"] < 1e-5
+    assert numbers["grad_gap_worst"] < 2e-3
+    assert numbers["delta_gap_worst"] < 2e-3
+    # four batches ran (three checked and one more): every routed layer
+    # counted its held choices, none left out
+    tokens = 4 * model.items_per_batch(cfg, traffic)
+    assert 0 < counters["moe.assignments_held"] <= 2 * 4 * tokens
+    assert counters["moe.overflow"] == 0
+    assert counters["moe.load_max"] * 4 >= counters["moe.assignments_held"]

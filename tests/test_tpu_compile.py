@@ -100,6 +100,27 @@ def _masked(b, h, s, d, with_lengths):
     return build
 
 
+def _gqa(heads, s, window, backward):
+    """The decoder's attention at Laguna-XS.2's widths: ``heads`` query
+    heads over 8 key/value heads of 128, one document of ``s`` positions."""
+    scale = 1.0 / 128 ** 0.5
+
+    def fwd(q, k, v):
+        return attention._gqa_pallas(q, k, v, True, window, scale, 512, 512,
+                                     interpret=False)
+
+    def fwd_bwd(q, k, v, do):
+        out = fwd(q, k, v)
+        return out, attention._gqa_blockwise_bwd(q, k, v, out, do, True,
+                                                 window, scale, 512)
+
+    def build(struct):
+        q = struct((1, heads, s, 128), jnp.bfloat16)
+        kv = struct((1, 8, s, 128), jnp.bfloat16)
+        return (fwd_bwd, (q, kv, kv, q)) if backward else (fwd, (q, kv, kv))
+    return build
+
+
 _CASES = {
     "lstm-n64-h1024-f32": _lstm_cell(64, 1024, jnp.float32),
     "lstm-n64-h1024-bf16": _lstm_cell(64, 1024, jnp.bfloat16),
@@ -112,6 +133,9 @@ _CASES = {
     "flash-b4h16s2048d64-fwd": _dense(4, 16, 2048, 64, backward=False),
     "flash-b4h16s2048d64-fwd-bwd": _dense(4, 16, 2048, 64, backward=True),
     "flash-b1h8s32768d128-fwd": _dense(1, 8, 32768, 128, backward=False),
+    "gqa-h64kv8s8192d128-window512-fwd": _gqa(64, 8192, 512, False),
+    "gqa-h64kv8s8192d128-window512-fwd-bwd": _gqa(64, 8192, 512, True),
+    "gqa-h48kv8s8192d128-full-fwd-bwd": _gqa(48, 8192, 0, True),
     "masked-b4h16s2048d64-lengths": _masked(4, 16, 2048, 64, True),
     "masked-b4h16s2048d64-segment-ids": _masked(4, 16, 2048, 64, False),
 }
