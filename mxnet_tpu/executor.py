@@ -15,10 +15,11 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from . import autograd, random as _random
+from . import autograd, profiler, random as _random
 from .base import MXNetError, getenv
 from .ndarray import NDArray
 from .ndarray.ndarray import _as_jax
+from .ops.registry import KEPT_RESIDUAL
 
 __all__ = ["Executor", "build_graph_eval", "build_placed_graph_eval"]
 
@@ -93,6 +94,24 @@ def _block_segments(nodes):
     return runs
 
 
+_named_residuals = jax.checkpoint_policies.save_only_these_names(KEPT_RESIDUAL)
+
+
+def _keep_named_residuals(prim, *avals, **params):
+    """The block checkpoint's policy: recompute everything but the values an
+    op named with ``ops.registry.keep_residual``. JAX asks it once for each
+    equation of a block while the training step is traced, so the counters
+    ``remat.kept_values`` / ``remat.kept_bytes`` say what the step's
+    checkpoints were told to keep; they never grow once the step is
+    compiled."""
+    keep = _named_residuals(prim, *avals, **params)
+    if keep:
+        profiler.count("remat.kept_values")
+        profiler.count("remat.kept_bytes",
+                       sum(a.size * a.dtype.itemsize for a in avals))
+    return keep
+
+
 def build_graph_eval(symbol, collect_all=False, proxies=None,
                      remat_blocks=False):
     """Build eval_fn(arg_vals: dict, aux_vals: dict, rng, is_train: bool)
@@ -113,7 +132,11 @@ def build_graph_eval(symbol, collect_all=False, proxies=None,
     every instruction's ``op_name``. With ``remat_blocks`` (the remat-policy
     pass sets it where the model asks, ``__remat__="block"``) each such run
     of nodes is also a ``jax.checkpoint``: a training step keeps what
-    crosses a block's boundary and recomputes its inside in the backward."""
+    crosses a block's boundary and recomputes its inside in the backward,
+    but for the values an op named with ``ops.registry.keep_residual``
+    (the band attention's output and row logsumexp: the layer's dearest
+    operation, one activation in size), which it keeps too. A block that
+    names nothing is a plain checkpoint."""
     nodes = symbol._topo_nodes()
     aux_ids = symbol._aux_node_ids()
     # deterministic per-random-node key folding. Only nodes that ACTUALLY
@@ -183,7 +206,7 @@ def build_graph_eval(symbol, collect_all=False, proxies=None,
             return [local[e] for e in keeps], ups
 
         if remat_blocks and is_train:
-            block = jax.checkpoint(block)
+            block = jax.checkpoint(block, policy=_keep_named_residuals)
         kept, ups = block([values[e] for e in reads],
                           {n: arg_vals[n] for n in used}, rng)
         values.update(zip(keeps, kept))

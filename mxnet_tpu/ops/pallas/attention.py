@@ -15,6 +15,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..registry import keep_residual, register
+from ...base import AttrSpec
+
 _NEG = -1e30
 
 
@@ -511,13 +514,17 @@ def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None):
     return out.reshape(b, h, s, d).astype(q.dtype)
 
 
-def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                causal, window, scale, n_k):
+def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                *, causal, window, scale, n_k):
     """The flash recurrence over the band only. Grid (B*H, n_q, steps):
     step ``j`` of query block ``i`` reads key block ``first(i) + j`` (the
     index map clamps it to ``last(i)``, so a step past the band moves no
     data and computes nothing). Operands go to the MXU in their own dtype,
-    accumulation is float32."""
+    accumulation is float32. Beside the output it writes each row's
+    logsumexp of the scaled scores over its band, ``m + log(l)`` in
+    float32: what the backward needs to rebuild the probabilities without
+    a pass of its own. ``lse_ref`` is one head's (n_q, 1, BQ), resident
+    while the head's query blocks run; block ``i`` fills row ``i`` of it."""
     i, j = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
     first, last = _band(i, bq, bk, n_k, causal, window)
@@ -561,8 +568,19 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:], 1e-30)        # (BQ, 128), lanes equal
+        o_ref[0] = (acc_ref[:] / l[:, :1]).astype(o_ref.dtype)
+        # the rows lie along sublanes here (every lane of a row equal) and
+        # along lanes in the output: 128 rows at a time, keep the diagonal
+        # and add the sublanes up (exact: the other terms are zeros)
+        lse = m_ref[:] + jnp.log(l)
+        n = min(bq, 128)
+        diagonal = jax.lax.broadcasted_iota(jnp.int32, (n, 128), 0) \
+            == jax.lax.broadcasted_iota(jnp.int32, (n, 128), 1)
+        for r in range(0, bq, n):
+            lse_ref[0, i, :, r:r + n] = jnp.sum(
+                jnp.where(diagonal, lse[r:r + n], 0.0), axis=0,
+                keepdims=True)[:, :n]
 
 
 def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
@@ -580,7 +598,7 @@ def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
 
     kernel = functools.partial(_gqa_kernel, causal=causal, window=window,
                                scale=scale, n_k=n_k)
-    out = pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, n_q, steps),
         in_specs=[
@@ -588,8 +606,14 @@ def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
             pl.BlockSpec((1, bk, d), kv_index),
             pl.BlockSpec((1, bk, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        out_specs=[
+            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, n_q, 1, bq), lambda bh, i, j: (bh, 0, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, n_q, 1, bq), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),  # running max m
             pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
@@ -599,15 +623,18 @@ def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
         name="gqa_flash_attention",
     )(q.reshape(b * h, s, d), k.reshape(b * hkv, s, d),
       v.reshape(b * hkv, s, d))
-    return out.reshape(b, h, s, d)
+    return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
 
-def _gqa_blockwise_bwd(q, k, v, out, do, causal, window, scale, block):
+def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block):
     """The backward over the same band, in jnp: an outer scan over query
-    blocks, and for each a loop over the key blocks it sees (first the row
-    logsumexp, then the gradients), so the temporaries are one
-    (B, H, BQ, BK) tile and no key block outside the band is touched.
-    Matmul operands stay in the inputs' dtype with float32 accumulation."""
+    blocks, and for each ONE loop over the key blocks it sees, so the
+    temporaries are one (B, H, BQ, BK) tile and no key block outside the
+    band is touched. ``lse`` (B, H, S) float32 is the forward kernel's row
+    logsumexp: the probabilities are ``exp(scores - lse)``, so the scores
+    are computed once here (five matmuls and one ``exp`` a tile) and not a
+    second time to find their normalizer. Matmul operands stay in the
+    inputs' dtype with float32 accumulation."""
     b, h, s, d = q.shape
     hkv = k.shape[1]
     g = h // hkv
@@ -615,20 +642,19 @@ def _gqa_blockwise_bwd(q, k, v, out, do, causal, window, scale, block):
     n = s // blk
     q5 = q.reshape(b, hkv, g, s, d)
     do5 = do.reshape(b, hkv, g, s, d)
+    lse4 = lse.reshape(b, hkv, g, s)
     delta = jnp.sum(do5.astype(jnp.float32)
                     * out.reshape(b, hkv, g, s, d).astype(jnp.float32), -1)
     rows = jnp.arange(blk)
 
-    def probs(qi, kj, i, j, lse):
+    def probs(qi, kj, i, j, lse_i):
         sc = jnp.einsum("bkgqd,bkcd->bkgqc", qi, kj,
                         preferred_element_type=jnp.float32) * scale
         mask = _band_mask((i * blk + rows)[:, None],
                           (j * blk + rows)[None, :], causal, window)
         if mask is not None:
             sc = jnp.where(mask, sc, _NEG)
-        if lse is None:
-            return sc
-        p = jnp.exp(sc - lse[..., None])
+        p = jnp.exp(sc - lse_i[..., None])
         return p if mask is None else jnp.where(mask, p, 0.0)
 
     def key_block(x, j):
@@ -639,26 +665,13 @@ def _gqa_blockwise_bwd(q, k, v, out, do, causal, window, scale, block):
         qi = jax.lax.dynamic_slice_in_dim(q5, i * blk, blk, axis=3)
         doi = jax.lax.dynamic_slice_in_dim(do5, i * blk, blk, axis=3)
         di = jax.lax.dynamic_slice_in_dim(delta, i * blk, blk, axis=3)
+        lse_i = jax.lax.dynamic_slice_in_dim(lse4, i * blk, blk, axis=3)
         first, last = _band(i, blk, blk, n, causal, window)
-
-        def lse_step(j, ml):
-            m, l = ml
-            sc = probs(qi, key_block(k, j), i, j, None)
-            m_new = jnp.maximum(m, jnp.max(sc, -1))
-            l = l * jnp.exp(m - m_new) + jnp.sum(
-                jnp.exp(sc - m_new[..., None]), -1)
-            return m_new, l
-
-        m, l = jax.lax.fori_loop(
-            first, last + 1, lse_step,
-            (jnp.full((b, hkv, g, blk), _NEG, jnp.float32),
-             jnp.zeros((b, hkv, g, blk), jnp.float32)))
-        lse = m + jnp.log(jnp.maximum(l, 1e-30))
 
         def grad_step(j, c):
             dqi, dk, dv = c
             kj, vj = key_block(k, j), key_block(v, j)
-            p = probs(qi, kj, i, j, lse)
+            p = probs(qi, kj, i, j, lse_i)
             dp = jnp.einsum("bkgqd,bkcd->bkgqc", doi, vj,
                             preferred_element_type=jnp.float32)
             ds = (p * (dp - di[..., None]) * scale).astype(q.dtype)
@@ -688,18 +701,24 @@ def _gqa_blockwise_bwd(q, k, v, out, do, causal, window, scale, block):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _gqa_attention(q, k, v, causal, window, scale, block, interpret):
     return _gqa_pallas(q, k, v, causal, window, scale, block, block,
-                       interpret)
+                       interpret)[0]
 
 
 def _gqa_fwd(q, k, v, causal, window, scale, block, interpret):
-    out = _gqa_pallas(q, k, v, causal, window, scale, block, block,
-                      interpret)
-    return out, (q, k, v, out)
+    """The residual contract: ``(q, k, v, out, lse)``. ``out`` and ``lse``
+    carry the name a block's checkpoint keeps (``keep_residual``), and the
+    NAMED values are both the primal output and the residuals: a name put
+    on the output outside this function would mark another value, and the
+    backward's ``out`` would be the kernel run again."""
+    out, lse = _gqa_pallas(q, k, v, causal, window, scale, block, block,
+                           interpret)
+    out, lse = keep_residual(out), keep_residual(lse)
+    return out, (q, k, v, out, lse)
 
 
 def _gqa_bwd(causal, window, scale, block, interpret, res, ct):
-    q, k, v, out = res
-    return _gqa_blockwise_bwd(q, k, v, out, ct, causal, window, scale,
+    q, k, v, out, lse = res
+    return _gqa_blockwise_bwd(q, k, v, out, lse, ct, causal, window, scale,
                               block)
 
 
@@ -715,9 +734,16 @@ def grouped_query_attention(q, k, v, causal=True, window=0, scale=None,
     On a TPU the forward is the flash kernel run over the band alone (key
     blocks above the diagonal or behind the window are neither fetched nor
     computed) and the backward the blockwise jnp recurrence over the same
-    band. Elsewhere it is :func:`gqa_attention_reference`, differentiated
-    by JAX; ``force_pallas`` runs kernel and backward through the Pallas
-    interpreter (tests)."""
+    band. The forward hands the backward ``(q, k, v, out, lse)``, ``lse``
+    the kernel's row logsumexp (B, H, S) in float32, so a training step
+    computes the scores twice: once in the kernel, once in the backward.
+    ``out`` and ``lse`` are named for the executor's block checkpoint
+    (``ops.registry.keep_residual``), which keeps them where it recomputes
+    the rest of a layer: ``out`` is one activation in size and the dearest
+    operation of the layer to compute again, and ``lse`` is a 64th of it.
+    Elsewhere it is :func:`gqa_attention_reference`, differentiated
+    by JAX, which names nothing; ``force_pallas`` runs kernel and backward
+    through the Pallas interpreter (tests)."""
     b, h, s, d = q.shape
     if k.shape != v.shape or k.shape[0] != b or k.shape[2:] != (s, d) \
             or h % k.shape[1]:
@@ -733,10 +759,6 @@ def grouped_query_attention(q, k, v, causal=True, window=0, scale=None,
         return gqa_attention_reference(q, k, v, causal, window, scale)
     return _gqa_attention(q, k, v, bool(causal), int(window), scale,
                           int(block), not on_tpu)
-
-
-from ..registry import register  # noqa: E402
-from ...base import AttrSpec  # noqa: E402
 
 
 @register("_contrib_flash_attention", aliases=["flash_attention_op"],

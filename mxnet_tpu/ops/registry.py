@@ -17,11 +17,26 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 
+from jax.ad_checkpoint import checkpoint_name
+
 from ..base import AttrSpec, MXNetError
 
-__all__ = ["OpDef", "register", "get_op", "list_ops", "OP_TABLE", "alias"]
+__all__ = ["OpDef", "register", "get_op", "list_ops", "OP_TABLE", "alias",
+           "KEPT_RESIDUAL", "keep_residual"]
 
 OP_TABLE: Dict[str, "OpDef"] = {}
+
+# The one name an op gives a value that a block's checkpoint
+# (``executor.build_graph_eval(remat_blocks=True)``) keeps for the backward
+# where it recomputes the rest of the block.
+KEPT_RESIDUAL = "kept_residual"
+
+
+def keep_residual(x):
+    """``x`` under the name the block checkpoint keeps. For a value that is
+    dear to compute again and small beside what the block holds anyway; it
+    is kept only if the named value itself is what the backward reads."""
+    return checkpoint_name(x, KEPT_RESIDUAL)
 
 
 class OpDef:

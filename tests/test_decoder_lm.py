@@ -5,6 +5,7 @@ with its blockwise backward through the Pallas interpreter) against
 explicit-mask softmax; the routed layer's shares against the whole layer;
 recomputation by block; and the whole tiny model, every layer kind, through
 ``SPMDTrainer.fit`` against the benchmark's plain reference."""
+import functools
 import json
 import math
 import os
@@ -164,24 +165,101 @@ def test_grouped_query_attention_op(group, window):
         lambda *a: _attention_by_mask(*a, heads, kv, window), q, k, v, gate)
 
 
-@pytest.mark.parametrize("group,window,block", [(6, 16, 16), (8, 24, 16),
-                                                (6, 0, 16), (8, 40, 32)])
+# (group, window, block): the last two a window inside one block and a
+# window over three blocks
+_BANDS = [(6, 16, 16), (8, 24, 16), (6, 0, 16), (8, 40, 32), (6, 5, 16),
+          (4, 33, 16)]
+
+
+def _band_inputs(group, kv=2, d=8, s=64):
+    return (_rand(2, kv * group, s, d), _rand(2, kv, s, d, seed=1),
+            _rand(2, kv, s, d, seed=2))
+
+
+@pytest.mark.parametrize("group,window,block", _BANDS)
 def test_band_kernel_and_blockwise_backward_in_the_interpreter(group, window,
                                                                block):
     """What the chip runs: the flash kernel over the band and the blockwise
-    backward over the same band, against plain softmax differentiated by
-    JAX."""
+    backward over the same band, reading the row logsumexp the kernel handed
+    it, against plain softmax differentiated by JAX."""
     from mxnet_tpu.ops.pallas.attention import (gqa_attention_reference,
                                                 grouped_query_attention)
-    kv, d, s = 2, 8, 64
-    q = _rand(2, kv * group, s, d)
-    k, v = _rand(2, kv, s, d, seed=1), _rand(2, kv, s, d, seed=2)
+    q, k, v = _band_inputs(group)
     with jax.enable_x64(False):
         _same_with_gradients(
             lambda *a: grouped_query_attention(
                 *a, causal=True, window=window, block=block,
                 force_pallas=True),
             lambda *a: gqa_attention_reference(*a, True, window), q, k, v)
+
+
+@pytest.mark.parametrize("group,window,block", _BANDS)
+def test_band_kernel_hands_over_the_row_logsumexp(group, window, block):
+    """The kernel's second output against a plain masked ``logsumexp`` of the
+    scaled scores over each row's band, (B, H, S) in float32."""
+    from mxnet_tpu.ops.pallas.attention import _gqa_pallas
+    q, k, v = _band_inputs(group)
+    b, h, s, d = q.shape
+    scale = 1.0 / math.sqrt(d)
+    with jax.enable_x64(False):
+        out, lse = _gqa_pallas(q, k, v, True, window, scale, block, block,
+                               interpret=True)
+    assert out.shape == q.shape
+    assert lse.shape == (b, h, s) and lse.dtype == jnp.float32
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, axis=1)) \
+        * scale
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = (j <= i) & ((j > i - window) if window else True)
+    _close(lse, jax.nn.logsumexp(jnp.where(seen, sc, -jnp.inf), axis=-1))
+
+
+def _count_eqns(jaxpr, name):
+    """Equations of primitive ``name`` in ``jaxpr`` and every jaxpr inside
+    its equations' parameters."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_eqns(sub, name)
+    return n
+
+
+def _kept_since(before):
+    """What the ``remat.*`` counters grew by since ``before``."""
+    now = mx.profiler.counters()
+    return {n: now.get(n, 0) - before.get(n, 0)
+            for n in ("remat.kept_values", "remat.kept_bytes")}
+
+
+@pytest.mark.parametrize("policy,kernels", [("executor", 1), ("plain", 2)])
+def test_a_checkpoint_with_the_executors_policy_runs_the_kernel_once(
+        policy, kernels):
+    """Forward and backward of the attention under ``jax.checkpoint``: a
+    plain one runs the kernel again in the backward for the residuals; the
+    block checkpoint's policy keeps ``out`` and ``lse``, so one call is all
+    the step holds, and says what it kept."""
+    from mxnet_tpu.executor import _keep_named_residuals
+    from mxnet_tpu.ops.pallas.attention import grouped_query_attention
+    q, k, v = _band_inputs(6)
+
+    def loss(q, k, v):
+        return jnp.sum(grouped_query_attention(
+            q, k, v, window=16, block=16, force_pallas=True) ** 2)
+
+    before = mx.profiler.counters()
+    with jax.enable_x64(False):
+        traced = jax.make_jaxpr(jax.grad(jax.checkpoint(
+            loss, policy=_keep_named_residuals if policy == "executor"
+            else None), (0, 1, 2)))(q, k, v)
+    assert _count_eqns(traced.jaxpr, "pallas_call") == kernels
+    # out (B, H, S, d) and lse (B, H, S), float32 here
+    assert _kept_since(before) == ({"remat.kept_values": 2,
+                     "remat.kept_bytes": 4 * (q.size + q.size // q.shape[-1])}
+                    if policy == "executor" else
+                    {"remat.kept_values": 0, "remat.kept_bytes": 0})
 
 
 def test_band_skips_the_blocks_a_query_block_cannot_see():
@@ -362,9 +440,69 @@ def test_symbol_follows_the_per_layer_lists():
     assert blocks == {None, "loss_head"} | {f"layer{k}" for k in range(3)}
 
 
-def test_blocks_are_checkpoints_where_the_model_asks():
+def _through_the_kernel(monkeypatch):
+    """Send ``GroupedQueryAttention`` down the chip's path on the CPU: the
+    kernel and its backward through the Pallas interpreter."""
+    from mxnet_tpu.ops.pallas import attention
+    monkeypatch.setattr(attention, "grouped_query_attention", functools.partial(
+        attention.grouped_query_attention, force_pallas=True))
+
+
+def _tiny_graph(cfg):
+    """The symbol with one document of 32 tokens: arguments and auxiliary
+    states to call its graph with."""
+    sym = models.get_symbol("decoder_lm", cfg=cfg)
+    shapes = dict(zip(sym.list_arguments(), sym.infer_shape(
+        data=(1, 32), softmax_label=(1, 32))[0]))
+    rng = np.random.default_rng(0)
+    args = {n: jnp.asarray(0.1 * rng.standard_normal(shape), jnp.float32)
+            for n, shape in shapes.items()}
+    args["data"] = jnp.asarray(rng.integers(0, 96, (1, 32)), jnp.float32)
+    args["softmax_label"] = args["data"]
+    aux = {n: jnp.zeros(3) for n in sym.list_auxiliary_states()}
+    return sym, args, aux
+
+
+@pytest.mark.parametrize("case,kept", [
+    ("kernel, checkpoints, training", 4), ("kernel, checkpoints, inference", 0),
+    ("kernel, no checkpoints, training", 0),
+    ("plain path, checkpoints, training", 0)])
+def test_counters_say_what_the_block_checkpoints_keep(monkeypatch, case,
+                                                      kept):
+    """``remat.kept_values`` / ``remat.kept_bytes`` grow while a training
+    step whose blocks are checkpoints is traced, by ``out`` and ``lse`` of
+    every attention that ran the kernel, and at no other time."""
+    from mxnet_tpu.executor import build_graph_eval
+    path, blocks, mode = case.split(", ")
+    if path == "kernel":
+        _through_the_kernel(monkeypatch)
+    cfg = _tiny(num_hidden_layers=2)
+    sym, args, aux = _tiny_graph(cfg)
+    fn = build_graph_eval(sym, remat_blocks=blocks == "checkpoints")
+    is_train = mode == "training"
+
+    def f(p):
+        return fn(dict(args, **p), aux, None, is_train)[0][0][0]
+
+    params = {n: v for n, v in args.items()
+              if n not in ("data", "softmax_label")}
+    before = mx.profiler.counters()
+    with jax.enable_x64(False):
+        jax.make_jaxpr(jax.grad(f) if is_train else f)(params)
+    # a layer keeps out (1, H, 32, 16) and lse (1, H, 32) in float32
+    heads = sum(cfg["num_attention_heads_per_layer"][:2])
+    assert _kept_since(before) == {
+        "remat.kept_values": kept,
+        "remat.kept_bytes": 4 * heads * 32 * (16 + 1) if kept else 0}
+
+
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_blocks_are_checkpoints_where_the_model_asks(monkeypatch, path):
     from mxnet_tpu import compiler
     from mxnet_tpu.executor import build_graph_eval
+    if path == "kernel":
+        # the checkpoints then keep the attention's out and lse
+        _through_the_kernel(monkeypatch)
     cfg = _tiny(num_hidden_layers=2)
     sym = models.get_symbol("decoder_lm", cfg=cfg)
     assert compiler.optimize(sym, for_training=True).remat_blocks
@@ -373,14 +511,7 @@ def test_blocks_are_checkpoints_where_the_model_asks():
     assert not compiler.optimize(plain, for_training=True).remat_blocks
     assert not compiler.optimize(sym, for_training=False).remat_blocks
 
-    shapes = dict(zip(sym.list_arguments(), sym.infer_shape(
-        data=(1, 32), softmax_label=(1, 32))[0]))
-    rng = np.random.default_rng(0)
-    args = {n: jnp.asarray(0.1 * rng.standard_normal(s), jnp.float32)
-            for n, s in shapes.items()}
-    args["data"] = jnp.asarray(rng.integers(0, 96, (1, 32)), jnp.float32)
-    args["softmax_label"] = args["data"]
-    aux = {n: jnp.zeros(3) for n in sym.list_auxiliary_states()}
+    _, args, aux = _tiny_graph(cfg)
 
     def loss(remat):
         fn = build_graph_eval(sym, remat_blocks=remat)

@@ -110,8 +110,10 @@ def _gqa(heads, s, window, backward):
                                      interpret=False)
 
     def fwd_bwd(q, k, v, do):
-        out = fwd(q, k, v)
-        return out, attention._gqa_blockwise_bwd(q, k, v, out, do, True,
+        # what jax.grad of grouped_query_attention runs on the chip: the
+        # kernel, then the blockwise jnp backward reading its logsumexp
+        out, lse = fwd(q, k, v)
+        return out, attention._gqa_blockwise_bwd(q, k, v, out, lse, do, True,
                                                  window, scale, 512)
 
     def build(struct):
