@@ -5,7 +5,11 @@ Reference analogue: the whole update path of stack §3.1 —
 ``Updater`` (module.py:556-615, model.py:105-132, comm.h reduce) — fused
 into a single XLA program: forward, backward (vjp), cross-device gradient
 reduction (psum inserted by the SPMD partitioner), and the optimizer
-update, with parameter/optimizer-state buffers donated in place.
+update, with parameter/optimizer-state buffers donated in place. That
+program is ``perf.step_runtime.FusedStep``'s, built under this trainer's
+``ShardingPlan``: the trainer is its mesh front end (shapes, the plan,
+placement of parameters and state, the fit loop, checkpoints) and holds no
+step body of its own.
 
 BatchNorm note: batch statistics are computed over the *global* sharded
 batch (XLA lowers the mean/var to cross-replica collectives), i.e.
@@ -25,20 +29,12 @@ from .. import initializer as _init_mod, optimizer as _opt_mod
 from .. import profiler as _profiler
 from ..analysis.annotations import hot_path
 from ..base import MXNetError
-from ..executor import build_graph_eval
 from ..ndarray import NDArray
+from ..perf.step_runtime import CompileGuard, FusedStep
 from .mesh import make_mesh
-from .sharding import (ShardingPlan, batch_pspec, divisibility_error,
-                       fit_spec_to_shape as _fit, plan_scope,
-                       zero_sharded_update)
+from .sharding import ShardingPlan, batch_pspec, divisibility_error
 
 __all__ = ["SPMDTrainer"]
-
-
-# The functional optimizer rules moved to the shared step runtime
-# (perf/step_runtime.py) so Module/Gluon/model.py trace the SAME update
-# math; this alias keeps the historical import path working.
-from ..perf.step_runtime import functional_update as _functional_update  # noqa: E402,E501
 
 
 def _fetching(iterable):
@@ -60,7 +56,17 @@ def _fetching(iterable):
 
 class SPMDTrainer:
     """Train a symbol SPMD over a named mesh (dp via ``data`` axis, tp via
-    ``model`` axis; further axes compose through custom param rules)."""
+    ``model`` axis; further axes compose through custom param rules).
+
+    The mesh front end of the one training step
+    (:class:`~mxnet_tpu.perf.step_runtime.FusedStep`; ``Module.fit`` is
+    the other). ``bind`` infers shapes, builds the ``ShardingPlan``,
+    builds the step under it (graph passes, HBM budget gate, evaluator,
+    riders' state, program key, jit: all the step's own), then
+    initialises and places parameters, aux and optimizer state;
+    ``params[n]`` is one array and ``states[n]`` the optimizer rule's own
+    state, as checkpoints hold them. ``step`` places the batch and calls
+    the step; ``fit`` is the loop with its checkpoints and recovery."""
 
     def __init__(self, symbol, optimizer="sgd", optimizer_params=None,
                  mesh=None, data_names: Sequence[str] = ("data",),
@@ -100,37 +106,32 @@ class SPMDTrainer:
         # (True / LossScaleConfig / False).
         self._compute_dtype = compute_dtype
         self._loss_scale_req = loss_scale
-        self._ls_cfg = None
-        self._ls_state = None
         # silent-failure integrity guard (resilience/integrity.py): the
         # divergence sentinel rides the donated step like the loss-scale
         # state; None defers to MXTPU_INTEGRITY_PERIOD (0 = off,
         # bitwise-identical program), True/False/IntegrityConfig override
         self._integrity_req = integrity
         self._ig_cfg = None
-        self._ig_state = None
         if isinstance(optimizer, str):
             optimizer = _opt_mod.create(optimizer, **(optimizer_params or {}))
         self._optimizer = optimizer
-        # graph passes (DCE/CSE/remat policy) run in bind(), where input
-        # shapes are known so the remat-policy activation estimate can
-        # engage; the trainer keeps the ORIGINAL symbol for naming/shape
-        # surfaces and traces the optimized one (mxnet_tpu/compiler)
+        # the step program (perf/step_runtime.py), built in bind(), where
+        # input shapes are known so the remat-policy activation estimate
+        # can engage; the trainer keeps the ORIGINAL symbol for naming/
+        # shape surfaces, the FusedStep traces the optimized one, and
+        # _opt_res is its record of the passes (mxnet_tpu/compiler)
+        self._fused: Optional[FusedStep] = None
         self._opt_res = None
-        self._graph_fingerprint = None
-        self._eval_fn = None
         self.params: Dict[str, jax.Array] = {}
         self.states: Dict[str, object] = {}
         self.aux: Dict[str, jax.Array] = {}
         self._num_update = 0
-        self._step_fn = None
         self._rng = jax.random.PRNGKey(0)
         # donation is the default (in-place param/state update); tests
         # toggle it off to prove bitwise equivalence of the two modes
         self._donate = bool(donate_buffers)
-        # retrace detector shared with the Module/Gluon runtimes: steps
-        # after the first compile must hit the trace cache
-        from ..perf import CompileGuard
+        # retrace detector: steps after the first compile must hit the
+        # trace cache. The bound step's own guard from bind() on.
         self.retrace_guard = CompileGuard("spmd-step")
 
     # -- initialization ----------------------------------------------------
@@ -171,7 +172,7 @@ class SPMDTrainer:
         self._plan = plan
         self._shard_opt = plan.zero
         # validate up front, BEFORE any state is replaced: failing after
-        # params/_step_fn were rebuilt would leave a torn half-bound
+        # params or the step were rebuilt would leave a torn half-bound
         # trainer behind the error. This is the first wall an elastic
         # re-mesh hits when it picks an incompatible device count, so
         # it must be the framework's own error (raised while the
@@ -195,41 +196,30 @@ class SPMDTrainer:
         param_names = [n for n in arg_names if n not in io_names]
         shapes = dict(zip(arg_names, arg_shapes))
 
-        # graph passes with the now-known bind shapes (remat budget can
-        # price the activations); re-run on every (re)bind — a remesh
-        # changes nothing structural, so the fingerprint is stable.
-        # Runs HERE, before any param/state allocation, so the HBM
-        # budget gate below fails while the trainer is still intact
+        # static per-param wd (lr multipliers fold into the dynamic lr
+        # input); recompute multipliers now that idx2name is known so
+        # biases/BN params get wd_mult=0 (reference: optimizer.py
+        # set_wd_mult). The step reads them when it is built.
+        self._optimizer.idx2name = dict(enumerate(param_names))
+        self._optimizer.set_wd_mult(dict(self._optimizer.wd_mult))
+        self._optimizer.set_lr_mult(dict(self._optimizer.lr_mult))
+        # the step program: graph passes with the now-known bind shapes
+        # (remat budget can price the activations), the HBM budget gate
+        # (MXTPU_HBM_BUDGET_MB: the typed MemoryBudgetError), the
+        # evaluator, the riders' state, the program key and the jit —
+        # all FusedStep's, rebuilt on every (re)bind. Built HERE, before
+        # any param/state of a previous bind is replaced, so an
+        # over-budget re-mesh fails while the trainer is still intact
         # (same contract as the divisibility wall above).
-        from .. import compiler as _compiler
         all_shapes = dict(shapes)
         all_shapes.update(dict(zip(aux_names, aux_shapes)))
-        # plan_scope: the sharding annotator stamps this plan's specs +
-        # signature into the IR annotations, so transform_sig (and every
-        # program key derived from it) carries the sharding layout
-        with plan_scope(plan):
-            self._opt_res = _compiler.optimize(
-                self._symbol, for_training=True,
-                input_shapes=all_shapes,
-                input_dtypes={n: str(self._dtype) for n in all_shapes})
-        # bind-time HBM budget gate (MXTPU_HBM_BUDGET_MB): over budget
-        # raises the typed MemoryBudgetError naming the contributors
-        # and fitting knobs (ZeRO, MXTPU_REMAT_MB, int8) BEFORE any
-        # state is replaced — never an XLA allocation death at step one
-        _budget = _compiler.memory.hbm_budget_mb()
-        if _budget is not None:
-            from ..base import getenv as _getenv
-            _est = _compiler.memory.estimate_peak_bytes(
-                _compiler.GraphIR.from_symbol(self._opt_res.symbol),
-                plan=plan, input_shapes=all_shapes,
-                input_dtypes={n: str(self._dtype) for n in all_shapes},
-                param_names=param_names, optimizer=self._optimizer,
-                for_training=True,
-                remat=bool(self._opt_res.remat
-                           or _getenv("MXTPU_BACKWARD_DO_MIRROR", 0, int)),
-                quant=self._opt_res.annotations.get("quant"))
-            _compiler.memory.check_budget(
-                _est, _budget, "SPMDTrainer.bind", plan=plan)
+        fused = FusedStep(
+            self._symbol, self._optimizer, param_names,
+            compute_dtype=self._compute_dtype, donate=self._donate,
+            name="spmd-step", input_shapes=all_shapes,
+            input_dtypes={n: str(self._dtype) for n in all_shapes},
+            sharding=plan, loss_scale=self._loss_scale_req,
+            integrity=self._integrity_req, kind="spmd-step")
 
         mesh = self._mesh
         layouts = self._symbol._arg_layouts()
@@ -262,9 +252,6 @@ class SPMDTrainer:
         # optimizer-state sharding from the plan: param spec, plus (in
         # ZeRO mode) the first mesh-divisible unsharded dim split over
         # the data axis (sharding.zero_shard_spec)
-        param_specs = {n: plan.param_spec(n, shapes[n])
-                       for n in param_names}
-        state_specs = {n: plan.state_spec(n, shapes[n]) for n in param_names}
         if plan.zero:
             # ZeRO contract check: a param whose every dim is either
             # already sharded or data-indivisible keeps replicated state —
@@ -278,234 +265,17 @@ class SPMDTrainer:
                     "divisible by the data axis (%d) and keep REPLICATED "
                     "optimizer state: %s", len(unsharded),
                     mesh.shape["data"], unsharded[:8])
-        state_sh = {n: NamedSharding(mesh, state_specs[n])
-                    for n in param_names}
-        init_state, update = _functional_update(self._optimizer)
         states = {}
         for n, w in params.items():
+            state_sh = plan.state_sharding(n, shapes[n])
             states[n] = jax.tree_util.tree_map(
-                lambda x, _sh=state_sh[n]: jax.device_put(x, _sh),
-                init_state(w))
+                lambda x, _sh=state_sh: jax.device_put(x, _sh),
+                fused._init_state(w))
         self.params, self.states, self.aux = params, states, aux
-
-        # static per-param wd (lr multipliers fold into the dynamic lr input);
-        # recompute multipliers now that idx2name is known so biases/BN
-        # params get wd_mult=0 (reference: optimizer.py set_wd_mult)
-        self._optimizer.idx2name = dict(enumerate(param_names))
-        self._optimizer.set_wd_mult(dict(self._optimizer.wd_mult))
-        self._optimizer.set_lr_mult(dict(self._optimizer.lr_mult))
-        wd_by_name = {n: float(self._optimizer.wd
-                               * self._optimizer.wd_mult.get(n, 1.0))
-                      for n in param_names}
-        lr_mult = {n: float(self._optimizer.lr_mult.get(n, 1.0))
-                   for n in param_names}
-        # (graph passes already ran above, pre-allocation, feeding the
-        # HBM budget gate; only the fingerprint/eval build remains here)
-        self._graph_fingerprint = _compiler.graph_fingerprint(
-            self._opt_res.symbol)
-        self._eval_fn = build_graph_eval(
-            self._opt_res.symbol, remat_blocks=self._opt_res.remat_blocks)
-        eval_fn = self._eval_fn
-        # the explicit mirror knob must survive MXTPU_GRAPH_PASSES=0
-        from ..base import getenv as _getenv
-        remat = bool(self._opt_res.remat
-                     or _getenv("MXTPU_BACKWARD_DO_MIRROR", 0, int))
-        param_sh = {n: params[n].sharding for n in params}
-        aux_sh = {n: NamedSharding(mesh, P()) for n in aux}
-
-        from ..perf.step_runtime import (precision_compute_dtype,
-                                         precision_loss_scale)
-        cdt = precision_compute_dtype(self._compute_dtype)
-        compute_dtype = jnp.dtype(cdt) if cdt else None
-        shard_opt = self._shard_opt
-        # the MXTPU_PRECISION-mode loss-scale guard: (scale, streak)
-        # ride the donated step; a non-finite step is skipped bitwise
-        # and only the schedule moves (quant/loss_scale.py)
-        ls_cfg = precision_loss_scale(self._loss_scale_req)
-        self._ls_cfg = ls_cfg
-        if ls_cfg is not None:
-            from ..quant.loss_scale import init_state as _ls_init
-            repl_sh = NamedSharding(mesh, P())
-            self._ls_state = tuple(jax.device_put(x, repl_sh)
-                                   for x in _ls_init(ls_cfg))
-        else:
-            self._ls_state = None
-        # the integrity sentinel state rides the SAME donated-state seam
-        # as the loss-scale pair: replicated scalars in, updated scalars
-        # out, read by the host only at the amortized integrity boundary
-        from ..resilience.integrity import (init_sentinel as _ig_init,
-                                            resolve_config as _ig_resolve)
-        ig_cfg = _ig_resolve(self._integrity_req)
-        self._ig_cfg = ig_cfg
-        if ig_cfg is not None:
-            repl_sh = NamedSharding(mesh, P())
-            self._ig_state = tuple(jax.device_put(x, repl_sh)
-                                   for x in _ig_init())
-        else:
-            self._ig_state = None
-
-        def step(params, states, aux, inputs, rng, lr, t, ls=None,
-                 ig=None):
-            def loss_f(p):
-                merged = dict(inputs)
-                if compute_dtype is not None:
-                    with jax.named_scope("cast_params"):
-                        p = {n: (v.astype(compute_dtype)
-                                 if v.ndim >= 2 and v.dtype == jnp.float32
-                                 else v)
-                             for n, v in p.items()}
-                merged.update(p)
-                outs, aux_up = eval_fn(merged, aux, rng, True)
-                return outs, aux_up
-
-            if remat:
-                # remat-policy pass decision (MXTPU_REMAT_MB budget /
-                # MXNET_BACKWARD_DO_MIRROR): recompute activations in
-                # the backward instead of holding them
-                loss_f = jax.checkpoint(loss_f)
-            (outs, aux_up), vjp_fn = jax.vjp(loss_f, params)
-            cts = [jnp.ones_like(o) for o in outs]
-            zero_aux = jax.tree_util.tree_map(jnp.zeros_like, aux_up)
-            (grads,) = vjp_fn((cts, zero_aux))
-            finite = None
-            if ls_cfg is not None:
-                # gradient finiteness decides whether this step APPLIES,
-                # in-program (the cotangent is deliberately unscaled:
-                # see perf/step_runtime.py — implicit-gradient loss
-                # heads ignore it, and bf16 shares fp32's exponent
-                # range; the schedule + skip are the portable contract)
-                from ..quant.loss_scale import tree_all_finite
-                with jax.named_scope("loss_scale_guard"):
-                    finite = tree_all_finite(grads)
-            new_ig = None
-            if ig_cfg is not None:
-                # in-trace divergence sentinel over the raw (pre-select)
-                # gradients: z/abs tests + the Welford fold run inside
-                # this program, only a sticky flag reaches the host —
-                # and only once per MXTPU_INTEGRITY_PERIOD
-                from ..resilience.integrity import update_sentinel
-                with jax.named_scope("integrity_sentinel"):
-                    new_ig = update_sentinel(ig_cfg, ig, grads, t,
-                                             applied=finite)
-            new_params, new_states = {}, {}
-
-            def updated(n):
-                g = grads[n]
-                if shard_opt and plan.zero_rs:
-                    # comm-optimal mode (MXTPU_ZERO=2): pin the grad to
-                    # the state sharding — GSPMD lowers the batch-axis
-                    # gradient reduction to a reduce_scatter and each
-                    # device runs the update on its 1/N slice only.
-                    # Different summation order than all-reduce:
-                    # last-ulp drift vs replicated (documented).
-                    g = jax.lax.with_sharding_constraint(g, state_sh[n])
-                    return update(
-                        params[n], g, states[n],
-                        lr * lr_mult[n], wd_by_name[n], t)
-                if shard_opt:
-                    # bitwise ZeRO (default): materialize the fully-
-                    # reduced grad first (the SAME all-reduce the
-                    # replicated program runs), then run the update on
-                    # 1/N slices inside a shard_map whose pinned
-                    # boundary keeps the slicing from re-laying-out
-                    # the forward/backward (zero_sharded_update)
-                    g = jax.lax.with_sharding_constraint(g, param_sh[n])
-                    return zero_sharded_update(
-                        mesh, plan.data_axis, update, params[n], g,
-                        states[n], lr * lr_mult[n], wd_by_name[n], t,
-                        param_specs[n], state_specs[n])
-                return update(params[n], g, states[n],
-                              lr * lr_mult[n], wd_by_name[n], t)
-
-            with jax.named_scope("optimizer_update"):
-                for n in params:
-                    new_params[n], new_states[n] = updated(n)
-            new_aux = dict(aux)
-            new_aux.update(aux_up)
-            new_ls = None
-            if ls_cfg is not None:
-                # skipped step: params/state/aux pass through bitwise
-                from ..quant.loss_scale import (guarded_select,
-                                                next_state)
-                with jax.named_scope("loss_scale_guard"):
-                    new_params = guarded_select(finite, new_params, params)
-                    new_states = guarded_select(finite, new_states, states)
-                    new_aux = guarded_select(finite, new_aux, aux)
-                    new_ls = next_state(ls, finite, ls_cfg)
-            # pin steady-state shardings: without this GSPMD may pick new
-            # layouts for the donated outputs, forcing a recompile on the
-            # next step when the re-fed params carry different shardings.
-            # Under shard_opt the param constraint is the all_gather that
-            # rebuilds full params from the updated 1/N slices.
-            new_params = {n: jax.lax.with_sharding_constraint(v, param_sh[n])
-                          for n, v in new_params.items()}
-            new_states = {n: jax.tree_util.tree_map(
-                lambda x, _sh=state_sh[n]:
-                    jax.lax.with_sharding_constraint(x, _sh),
-                new_states[n]) for n in new_states}
-            new_aux = {n: jax.lax.with_sharding_constraint(v, aux_sh[n])
-                       for n, v in new_aux.items()}
-            # pin the outputs to the batch layout: without this the
-            # partitioner is free to pick a different forward layout per
-            # program (observed: ZeRO chose class-dim-sharded softmax,
-            # whose row-sum is a different cross-device reduction —
-            # breaking ZeRO-vs-replicated bitwise equality)
-            outs = [jax.lax.with_sharding_constraint(
-                o, NamedSharding(mesh, _fit(batch_pspec(mesh, o.ndim),
-                                            o.shape, mesh)))
-                    for o in outs]
-            extra = ()
-            if ls_cfg is not None:
-                extra = extra + (new_ls,)
-            if ig_cfg is not None:
-                extra = extra + (new_ig,)
-            if extra:
-                return (new_params, new_states, new_aux, outs) + extra
-            return new_params, new_states, new_aux, outs
-
-        self.retrace_guard.rebind()     # fresh program after (re)bind
-        guard = self.retrace_guard
-
-        def materialized(kind):
-            if kind == "loaded":
-                # persisted-cache hit: the traced body never runs, so the
-                # guard's one expected compile is credited by hand
-                guard.count += 1
-
-        # everything static that enters the traced step joins the
-        # persistent-program identity: graph + pass decisions, mesh,
-        # optimizer rule statics, sharding layout, ZeRO mode, precision
-        shard_sig = sorted((n, str(state_specs[n])) for n in param_names)
-        key_parts = (
-            self._graph_fingerprint, self._opt_res.transform_sig,
-            f"effremat={int(remat)}",
-            "mesh=" + _compiler.mesh_signature(mesh),
-            _compiler.fingerprint.optimizer_signature(self._optimizer),
-            f"wd={sorted(wd_by_name.items())}",
-            f"lrm={sorted(lr_mult.items())}",
-            f"zero={int(shard_opt)}", f"cdt={compute_dtype}",
-            f"plan={plan.signature_hash()}", f"shards={shard_sig}",
-            "-" if ls_cfg is None else ls_cfg.signature(),
-            "-" if ig_cfg is None else ig_cfg.signature())
-
-        donate = (0, 1, 2) if self._donate else ()
-        if self._donate and ls_cfg is not None:
-            donate = donate + (7,)  # the loss-scale state rides donated
-        if self._donate and ig_cfg is not None:
-            donate = donate + (8,)  # ... and so does the sentinel state
-
-        def _build_step_fn():
-            self._step_fn = _compiler.PersistentJit(
-                self.retrace_guard.wrap(step), kind="spmd-step",
-                key_parts=key_parts,
-                donate_argnums=donate,
-                on_materialize=materialized)
-
-        # kept for rebind_step(): the stall-escalation ladder rebuilds
-        # the program without re-running bind (resilience/supervisor.py)
-        self._rebuild_step_fn = _build_step_fn
-        _build_step_fn()
-        self._step_abstract_args = None  # re-snapshot after (re)bind
+        self._fused = fused
+        self._opt_res = fused._opt_res
+        self._ig_cfg = fused._ig_cfg
+        self.retrace_guard = fused.guard    # a fresh program's, count 0
         # sequence parallelism: shard the sequence dim (dim 1) of token
         # inputs over the axis the graph's attention ops actually name —
         # not a hardcoded literal — so inputs arrive pre-sharded for the
@@ -537,10 +307,9 @@ class SPMDTrainer:
         wedged executable/dispatch is abandoned for a fresh jit. The
         retrace guard treats this as a new program lifetime, and the
         abstract-args snapshot survives (shapes/shardings unchanged)."""
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("call bind() before rebind_step()")
-        self.retrace_guard.rebind()
-        self._rebuild_step_fn()
+        self._fused.rebind()
         return self
 
     # -- stepping ----------------------------------------------------------
@@ -548,7 +317,7 @@ class SPMDTrainer:
     @hot_path("the per-step training path (ISSUE: SPMDTrainer.step)")
     def step(self, batch: Dict[str, np.ndarray]):
         """Run one optimizer step on a global batch; returns outputs."""
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("call bind() before step()")
         with _profiler.span("fit.step"):
             # fault site only, no retry: the step donates its param/state
@@ -583,43 +352,11 @@ class SPMDTrainer:
             lr = jnp.float32(opt.lr if opt.lr_scheduler is None
                              else opt.lr_scheduler(self._num_update))
             t = jnp.float32(self._num_update)
-            # mesh-aware ops (MultiHeadAttention seq_axis, ...) consult the
-            # ambient mesh while the step traces (first call compiles)
-            from .mesh import mesh_scope
-            args = (self.params, self.states, self.aux, inputs, sub, lr, t)
-            if self._ls_cfg is not None or self._ig_cfg is not None:
-                # with only the integrity sentinel armed, ls rides as the
-                # None placeholder (an empty pytree: nothing is traced in)
-                args = args + (self._ls_state,)
-            if self._ig_cfg is not None:
-                args = args + (self._ig_state,)
-            if getattr(self, "_step_abstract_args", None) is None:
-                # one-time abstract arg snapshot (shapes + mesh shardings)
-                # so the compiled step's HLO stays inspectable after the
-                # donated buffers are consumed; single-device placements
-                # (rng key, scalars) stay unspecified or lower() rejects
-                # the device mix. Shapes/shardings are invariant after
-                # bind, so the first step's snapshot serves the trainer's
-                # lifetime.
-                def _abstract(x):
-                    sh = getattr(x, "sharding", None)
-                    if (not isinstance(sh, NamedSharding)
-                            or sh.mesh != self._mesh):
-                        sh = None
-                    return jax.ShapeDtypeStruct(
-                        jnp.shape(x), jnp.result_type(x), sharding=sh)
-
-                self._step_abstract_args = jax.tree_util.tree_map(
-                    _abstract, args)
-            with mesh_scope(self._mesh), _profiler.span("step.dispatch"):
-                res = self._step_fn(*args)
-            self.params, self.states, self.aux, outs = res[:4]
-            tail = 4
-            if self._ls_cfg is not None:
-                self._ls_state = res[tail]
-                tail += 1
-            if self._ig_cfg is not None:
-                self._ig_state = res[tail]
+            # the call runs under the mesh (FusedStep.__call__): mesh-aware
+            # ops consult it while the step traces (first call compiles)
+            with _profiler.span("step.dispatch"):
+                self.params, self.states, self.aux, outs = self._fused(
+                    self.params, self.states, self.aux, inputs, sub, lr, t)
             # the lying-chip fault site (resilience/integrity.py): an
             # armed mesh.silent_corrupt plan lands a seeded single-device
             # bitflip HERE, after the updated params exist — and nothing
@@ -636,25 +373,16 @@ class SPMDTrainer:
         turned the gradient all-reduce into reduce-scatter + all-gather
         (trainer docstring; reference analogue: the dist server's
         key-sharded update, kvstore_dist_server.h:175-186)."""
-        if getattr(self, "_step_abstract_args", None) is None:
+        if self._fused is None:
             raise MXNetError("run at least one step() first")
-        from .mesh import mesh_scope
-        # this abstract lower is a deliberate extra trace, not a step
-        # retrace — raise the guard's budget so it stays quiet
-        self.retrace_guard.expected += 1
-        with mesh_scope(self._mesh):
-            lowered = self._step_fn.jit.lower(*self._step_abstract_args)
-        return lowered.compile().as_text()
+        return self._fused.compiled_hlo()
 
     def loss_scale_stats(self):
         """Host snapshot of the loss-scale guard state (None unless the
         MXTPU_PRECISION mode / ``loss_scale=`` armed it) — a boundary
         read for callbacks and tests, never on the step path."""
-        if self._ls_cfg is None or self._ls_state is None:
-            return None
-        scale, streak = self._ls_state
-        return {"scale": float(np.asarray(scale)),
-                "finite_streak": int(np.asarray(streak))}
+        return None if self._fused is None \
+            else self._fused.loss_scale_stats()
 
     def aux_counters(self):
         """Host snapshot of the counters the graph's ops keep on the device:
@@ -678,21 +406,25 @@ class SPMDTrainer:
         unless MXTPU_INTEGRITY_PERIOD / ``integrity=`` armed the guard)
         — a boundary read for the IntegrityGuard and tests, never on
         the step path (resilience/integrity.py)."""
-        if self._ig_cfg is None or self._ig_state is None:
-            return None
-        from ..resilience.integrity import sentinel_stats
-        return sentinel_stats(self._ig_state)
+        return None if self._fused is None \
+            else self._fused.integrity_stats()
+
+    @property
+    def _ig_state(self):
+        """The sentinel's state, which the step carries (the integrity
+        guard and the fault injectors of its tests read and set it)."""
+        return None if self._fused is None else self._fused._ig_state
+
+    @_ig_state.setter
+    def _ig_state(self, state):
+        self._fused._ig_state = state
 
     def _reset_integrity_state(self):
         """Fresh sentinel statistics (same shapes/shardings/dtypes, so
         no retrace): called after any rollback/recovery — the restored
         params' gradient distribution starts a new regime."""
-        if self._ig_cfg is None:
-            return
-        from ..resilience.integrity import init_sentinel
-        repl_sh = NamedSharding(self._mesh, P())
-        self._ig_state = tuple(jax.device_put(x, repl_sh)
-                               for x in init_sentinel())
+        if self._fused is not None:
+            self._fused.reset_integrity_state()
 
     def get_params(self):
         """Gather (host) copies, reference Module.get_params."""
@@ -729,7 +461,7 @@ class SPMDTrainer:
 
         from ..resilience import guarded_call
 
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("bind() before save_checkpoint()")
         path = os.path.join(os.path.abspath(directory), f"step_{step}")
         state = self._ckpt_state()
@@ -771,7 +503,7 @@ class SPMDTrainer:
         from ..resilience import faults, guarded_call
         from ..resilience import checkpoint as _ckpt
 
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("bind() before save_checkpoint()")
         base = os.path.abspath(directory)
         path = os.path.join(base, f"step_{step}")
@@ -828,7 +560,7 @@ class SPMDTrainer:
 
         from ..resilience import guarded_call
 
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("bind() before restore_checkpoint()")
         path = os.path.join(os.path.abspath(directory), f"step_{step}")
         from ..resilience import checkpoint as _ckpt
@@ -932,7 +664,7 @@ class SPMDTrainer:
         trainer re-initializes and the caller restores a checkpoint —
         after a mid-step device loss the donated buffers are untrusted
         and the dead device's shards are gone."""
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("call bind() before remesh()")
         old_params, old_states, old_aux = self.params, self.states, self.aux
         self._mesh = mesh
@@ -997,7 +729,7 @@ class SPMDTrainer:
         so they are durable before the run exits; a background write
         failure surfaces as a typed ``AsyncCheckpointError`` on the
         next checkpoint call (docs/how_to/fault_tolerance.md)."""
-        if self._step_fn is None:
+        if self._fused is None:
             raise MXNetError("call bind() before fit()")
         from ..resilience import supervisor as _sup_mod
         sup = _sup_mod.resolve(supervisor)

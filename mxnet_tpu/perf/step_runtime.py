@@ -473,37 +473,51 @@ def _functional_state_to_imp(kind, fstate, existing):
 # ---------------------------------------------------------------------------
 
 class FusedStep:
-    """One symbol, one optimizer, one compiled training step.
+    """One symbol, one optimizer, one compiled training step: THE step
+    program of the framework, with two front ends. ``ModuleStepper``
+    (``Module.fit``, kind ``"fused-step"``) and ``SPMDTrainer`` (kind
+    ``"spmd-step"``) both hand their symbol and optimizer here; the
+    graph passes, the HBM budget gate, the graph evaluator with its
+    block checkpoints, the precision mode, the loss-scale and integrity
+    state, the step body, the program key and the ``PersistentJit`` are
+    built in this constructor and nowhere else.
 
     Functional core: ``step(params, states, aux, inputs, rng, lr, t)``
     returns ``(params', states', aux', outputs)`` with the first three
-    donated. ``params`` values are jax arrays — or piece-trees for
-    packed RNN parameters (:func:`plan_param_layouts`). ``inputs`` holds
-    batch data/labels plus any frozen (non-trainable) parameters.
+    donated. A name of ``params`` holds one array with the rule's own
+    state under the same name in ``states`` (what ``SPMDTrainer`` keeps
+    and checkpoints), or what :meth:`init` builds: an array or a
+    piece-tree for a packed RNN parameter (:func:`plan_param_layouts`)
+    with a list of states, one a leaf. The body reads which from the
+    state it is given. ``inputs`` holds batch data/labels plus any
+    frozen (non-trainable) parameters.
 
-    ``compute_dtype`` mirrors SPMDTrainer mixed precision: fp32 master
-    params, 2-D+ leaves cast once inside the step so the MXU sees bf16
-    operands — including embedding tables, which are cast BEFORE the
-    gather (casting after would stream the full fp32 activation).
+    ``compute_dtype``: fp32 master params, 2-D+ leaves cast once inside
+    the step so the MXU sees bf16 operands — including embedding tables,
+    which are cast BEFORE the gather (casting after would stream the
+    full fp32 activation).
 
     ``mesh``/``sharding`` make the SAME donated program SPMD over a
     named mesh (parallel/sharding.py's rule engine): parameters and
     optimizer state are placed by the plan's specs, the batch arrives
-    split over the ``data`` axis, and — in the plan's ZeRO mode — each
-    gradient is pinned to the state spec (lowering the batch all-reduce
-    to a reduce-scatter), the update runs on each replica's 1/N slice,
-    and the updated parameter is constrained back to its param spec:
-    the all-gather happens via the interconnect INSIDE the donated
-    step, never as a separate dispatch (arxiv 2004.13336). This is the
-    one seam that gives Module and the Gluon Trainer the multichip
-    weight-update sharding SPMDTrainer has.
+    split over the ``data`` axis, and in the plan's ZeRO mode the update
+    runs on each replica's 1/N slice — bitwise (``MXTPU_ZERO=1``: the
+    fully reduced gradient pinned to the parameter's layout, then
+    ``zero_sharded_update``) or comm-optimal (``=2``: the gradient
+    pinned to the state spec, a reduce-scatter; arxiv 2004.13336). After
+    the loss-scale select, parameters, states and aux are pinned to
+    their steady-state layouts (the parameter pin is the in-step
+    all-gather) and the outputs to the batch layout.
+
+    ``kind`` names the stored program and its op map
+    (``profiler.op_scopes(kind)``): a constant of the front end.
     """
 
     def __init__(self, symbol, optimizer, param_names: Sequence[str],
                  compute_dtype=None, donate: bool = True,
                  name: str = "fused-step", input_shapes=None,
                  input_dtypes=None, mesh=None, sharding=None,
-                 loss_scale=None, integrity=None):
+                 loss_scale=None, integrity=None, kind: str = "fused-step"):
         from .. import compiler as _compiler
         from ..parallel.sharding import ShardingPlan, plan_scope
         from ..quant import loss_scale as _ls_mod
@@ -511,67 +525,68 @@ class FusedStep:
         self._symbol = symbol
         self._optimizer = optimizer
         self._param_names = list(param_names)
-        # the MXTPU_PRECISION mode: bf16 cast + the dynamic loss-scale
-        # guard traced into this one donated program (the cast policy
-        # travels with the step, docs/how_to/quantization.md)
-        compute_dtype = precision_compute_dtype(compute_dtype)
-        self._ls_cfg = precision_loss_scale(loss_scale)
-        self._ls_state = (None if self._ls_cfg is None
-                          else _ls_mod.init_state(self._ls_cfg))
-        # the integrity divergence sentinel rides the same donated-state
-        # seam (MXTPU_INTEGRITY_PERIOD; resilience/integrity.py) — the
-        # Module/Gluon step carries it exactly like SPMDTrainer's
-        self._ig_cfg = _ig_mod.resolve_config(integrity)
-        self._ig_state = (None if self._ig_cfg is None
-                          else _ig_mod.init_sentinel())
+        self._program_kind = kind
         if sharding is not None and mesh is None:
             mesh = sharding.mesh
         if mesh is not None and sharding is None:
             sharding = ShardingPlan(mesh)
         self.mesh = mesh
-        self.plan = sharding
-        if self.plan is not None and self._ls_state is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            _repl0 = NamedSharding(self.plan.mesh, PartitionSpec())
-            self._ls_state = tuple(jax.device_put(x, _repl0)
-                                   for x in self._ls_state)
-        if self.plan is not None and self._ig_state is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            _repl0 = NamedSharding(self.plan.mesh, PartitionSpec())
-            self._ig_state = tuple(jax.device_put(x, _repl0)
-                                   for x in self._ig_state)
-        # graph passes at bind time (DCE/CSE/remat policy); the fused
-        # step traces the optimized graph, the module keeps the
-        # original. input_shapes/dtypes (every bound arg + aux) feed
-        # the remat-policy activation estimate — without them the
+        self.plan = plan = sharding
+        # graph passes at bind time (DCE/CSE/remat policy); the step
+        # traces the optimized graph, the front end keeps the original.
+        # input_shapes/dtypes (every bound arg + aux) feed the
+        # remat-policy activation estimate — without them the
         # MXTPU_REMAT_MB budget cannot engage. plan_scope: the sharding
         # annotator stamps the plan into the IR annotations, so
         # transform_sig (and the program key) carries the layout.
-        with plan_scope(self.plan):
+        with plan_scope(plan):
             opt_res = _compiler.optimize(symbol, for_training=True,
                                          input_shapes=input_shapes,
                                          input_dtypes=input_dtypes)
+        self._opt_res = opt_res
         opt_sym = opt_res.symbol
         # the explicit mirror knob must survive MXTPU_GRAPH_PASSES=0
         # (with passes on, the remat-policy pass already folds it in)
         self._remat = bool(opt_res.remat
                            or getenv("MXTPU_BACKWARD_DO_MIRROR", 0, int))
         # bind-time HBM budget gate (MXTPU_HBM_BUDGET_MB): price the
-        # program while nothing has been traced or replaced — over
-        # budget is the framework's typed MemoryBudgetError naming the
-        # contributors + fitting knobs, not an XLA allocation failure
+        # program while nothing has been traced, placed or replaced —
+        # over budget is the framework's typed MemoryBudgetError naming
+        # the front end, the contributors and the fitting knobs (ZeRO,
+        # MXTPU_REMAT_MB, int8), not an XLA allocation failure at step
+        # one. A program whose shapes cannot be inferred is not priced.
         budget = _compiler.memory.hbm_budget_mb()
-        if budget is not None and input_shapes:
+        if budget is not None:
             est = _compiler.memory.estimate_peak_bytes(
-                _compiler.GraphIR.from_symbol(opt_sym), plan=self.plan,
+                _compiler.GraphIR.from_symbol(opt_sym), plan=plan,
                 input_shapes=input_shapes, input_dtypes=input_dtypes,
                 param_names=self._param_names, optimizer=optimizer,
                 for_training=True, remat=self._remat,
                 quant=opt_res.annotations.get("quant"))
-            _compiler.memory.check_budget(est, budget,
-                                          f"FusedStep({name!r}) bind",
-                                          plan=self.plan)
-        self._eval_fn = build_graph_eval(opt_sym)
+            _compiler.memory.check_budget(
+                est, budget, "SPMDTrainer.bind" if kind == "spmd-step"
+                else f"FusedStep({name!r}) bind", plan=plan)
+        # the MXTPU_PRECISION mode: bf16 cast + the dynamic loss-scale
+        # guard traced into this one donated program (the cast policy
+        # travels with the step, docs/how_to/quantization.md): (scale,
+        # streak) ride the donated step; a non-finite step is skipped
+        # bitwise and only the schedule moves (quant/loss_scale.py)
+        compute_dtype = precision_compute_dtype(compute_dtype)
+        self._ls_cfg = ls_cfg = precision_loss_scale(loss_scale)
+        self._ls_state = (None if ls_cfg is None
+                          else self._replicated(_ls_mod.init_state(ls_cfg)))
+        # the integrity divergence sentinel rides the same donated-state
+        # seam (MXTPU_INTEGRITY_PERIOD; resilience/integrity.py):
+        # replicated scalars in, updated scalars out, read by the host
+        # only at the amortized integrity boundary
+        self._ig_cfg = ig_cfg = _ig_mod.resolve_config(integrity)
+        self._ig_state = None
+        self.reset_integrity_state()
+        # a block of the graph marked __remat__="block" is one
+        # checkpoint here, whichever front end binds it (the pass's
+        # decision is part of transform_sig)
+        self._eval_fn = build_graph_eval(
+            opt_sym, remat_blocks=opt_res.remat_blocks)
         self.needs_rng = bool(getattr(self._eval_fn, "needs_rng", True))
         self.layouts = {n: lo for n, lo in plan_param_layouts(opt_sym).items()
                         if n in self._param_names}
@@ -579,27 +594,36 @@ class FusedStep:
         self.guard = CompileGuard(name)
         self._kind = type(optimizer).__name__.lower()
         self._init_state, update = functional_update(optimizer)
-        # persistent-program identity: everything static that enters the
-        # traced step — graph, pass decisions, optimizer rule + statics,
-        # layout hoists, compute dtype (donation joins via donate_argnums)
-        self._program_key_parts = (
-            _compiler.graph_fingerprint(opt_sym), opt_res.transform_sig,
-            f"effremat={int(self._remat)}",
-            _compiler.fingerprint.optimizer_signature(optimizer),
-            f"wd={sorted((n, float(optimizer.wd * optimizer.wd_mult.get(n, 1.0))) for n in self._param_names)}",
-            f"lrm={sorted((n, float(optimizer.lr_mult.get(n, 1.0))) for n in self._param_names)}",
-            f"cdt={compute_dtype}",
-            f"layouts={sorted(self.layouts)}",
-            f"plan={'-' if self.plan is None else self.plan.signature_hash()}",
-            "-" if self._ls_cfg is None else self._ls_cfg.signature(),
-            "-" if self._ig_cfg is None else self._ig_cfg.signature())
-
         # static per-param wd / lr multipliers (reference: set_wd_mult —
         # biases/BN params get wd 0); the dynamic base lr stays an input
         wd_by_name = {n: float(optimizer.wd * optimizer.wd_mult.get(n, 1.0))
                       for n in self._param_names}
         lr_mult = {n: float(optimizer.lr_mult.get(n, 1.0))
                    for n in self._param_names}
+        # persistent-program identity: everything static that enters the
+        # traced step — graph, pass decisions, optimizer rule + statics,
+        # layout hoists, compute dtype and, under a plan, the mesh, the
+        # ZeRO mode and each state's spec (donation joins via
+        # donate_argnums)
+        self._program_key_parts = (
+            _compiler.graph_fingerprint(opt_sym), opt_res.transform_sig,
+            f"effremat={int(self._remat)}",
+            _compiler.fingerprint.optimizer_signature(optimizer),
+            f"wd={sorted(wd_by_name.items())}",
+            f"lrm={sorted(lr_mult.items())}",
+            f"cdt={compute_dtype}",
+            f"layouts={sorted(self.layouts)}",
+            f"plan={'-' if plan is None else plan.signature_hash()}",
+            "-" if ls_cfg is None else ls_cfg.signature(),
+            "-" if ig_cfg is None else ig_cfg.signature())
+        if plan is not None:
+            shard_sig = sorted(
+                (n, str(plan.state_spec(n, input_shapes[n])))
+                for n in self._param_names) if input_shapes else "-"
+            self._program_key_parts += (
+                "mesh=" + _compiler.mesh_signature(plan.mesh),
+                f"zero={int(plan.zero)}", f"shards={shard_sig}")
+
         eval_fn = self._eval_fn
         cdt = jnp.dtype(compute_dtype) if compute_dtype else None
         self.compute_dtype = cdt
@@ -610,20 +634,13 @@ class FusedStep:
             return v
 
         remat = self._remat
-        plan = self.plan
         if plan is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            def _psh(n, v):
-                return NamedSharding(plan.mesh, plan.param_spec(n, v.shape))
-
-            def _ssh(n, v):
-                return NamedSharding(plan.mesh, plan.state_spec(n, v.shape))
-
+            from ..parallel.sharding import (fit_spec_to_shape,
+                                             zero_sharded_update)
+            pin = jax.lax.with_sharding_constraint
             _repl = NamedSharding(plan.mesh, PartitionSpec())
-
-        ls_cfg = self._ls_cfg
-        ig_cfg = self._ig_cfg
 
         def step(params, states, aux, inputs, rng, lr, t, ls=None, ig=None):
             def loss_f(p):
@@ -665,64 +682,55 @@ class FusedStep:
                     finite = tree_all_finite(grads)
             new_ig = None
             if ig_cfg is not None:
-                # the divergence sentinel folds the raw grad-norm into
-                # its Welford stats in-trace; loss-scale-skipped steps
-                # are neither a breach nor a sample (applied=finite)
+                # the divergence sentinel folds the raw (pre-select)
+                # grad-norm into its Welford stats in-trace, only a
+                # sticky flag reaches the host, once a
+                # MXTPU_INTEGRITY_PERIOD; loss-scale-skipped steps are
+                # neither a breach nor a sample (applied=finite)
                 from ..resilience.integrity import update_sentinel
                 with jax.named_scope("integrity_sentinel"):
                     new_ig = update_sentinel(ig_cfg, ig, grads, t,
                                              applied=finite)
-            new_params, new_states = {}, {}
+
+            def apply(n, w, g, s):
+                """One array of parameter ``n`` through the rule."""
+                if plan is not None and plan.zero and plan.zero_rs:
+                    # comm-optimal ZeRO (MXTPU_ZERO=2): pin the grad to
+                    # the state spec — GSPMD lowers the batch-axis
+                    # gradient reduction to a reduce_scatter and each
+                    # replica updates only its 1/N slice
+                    # (arxiv 2004.13336). Last-ulp drift vs replicated:
+                    # a different summation order.
+                    g = pin(g, plan.state_sharding(n, w.shape))
+                elif plan is not None and plan.zero:
+                    # bitwise ZeRO (default): materialize the fully
+                    # reduced grad first (the SAME all-reduce the
+                    # replicated program runs), then run the update on
+                    # 1/N slices inside a shard_map whose pinned
+                    # boundary keeps the slicing from re-laying-out the
+                    # forward/backward (zero_sharded_update)
+                    g = pin(g, plan.param_sharding(n, w.shape))
+                    return zero_sharded_update(
+                        plan.mesh, plan.data_axis, update, w, g, s,
+                        lr * lr_mult[n], wd_by_name[n], t,
+                        plan.param_spec(n, w.shape),
+                        plan.state_spec(n, w.shape))
+                return update(w, g, s, lr * lr_mult[n], wd_by_name[n], t)
 
             def updated(n):
+                if not isinstance(states[n], list):
+                    # one array with the rule's own state
+                    return apply(n, params[n], grads[n], states[n])
+                # what init() builds: a state a leaf of the (piece-)tree
                 w_leaves, treedef = jax.tree_util.tree_flatten(params[n])
-                g_leaves = jax.tree_util.tree_leaves(grads[n])
-                nw, ns = [], []
-                for w, g, s in zip(w_leaves, g_leaves, states[n]):
-                    if plan is not None and plan.zero and plan.zero_rs:
-                        # comm-optimal ZeRO (MXTPU_ZERO=2): pin the grad
-                        # to the state spec — GSPMD lowers the batch-axis
-                        # gradient reduction to a reduce_scatter and each
-                        # replica updates only its 1/N slice
-                        # (arxiv 2004.13336). Last-ulp drift vs
-                        # replicated: a different summation order.
-                        g = jax.lax.with_sharding_constraint(g, _ssh(n, g))
-                        w2, s2 = update(w, g, s, lr * lr_mult[n],
-                                        wd_by_name[n], t)
-                    elif plan is not None and plan.zero:
-                        # bitwise ZeRO (default): the full all-reduce
-                        # runs in the replicated program's order, then
-                        # the update slices inside a shard_map whose
-                        # pinned boundary keeps the 1/N layout from
-                        # re-laying-out the forward/backward
-                        # no explicit grad pin: the shard_map's own
-                        # replicated in_spec places the exact demand the
-                        # replicated program's elementwise update does,
-                        # so both programs' forward/backward regions
-                        # carry identical constraints
-                        from ..parallel.sharding import \
-                            zero_sharded_update
-                        w2, s2 = zero_sharded_update(
-                            plan.mesh, plan.data_axis, update, w, g, s,
-                            lr * lr_mult[n], wd_by_name[n], t,
-                            plan.param_spec(n, w.shape),
-                            plan.state_spec(n, w.shape))
-                    else:
-                        w2, s2 = update(w, g, s, lr * lr_mult[n],
-                                        wd_by_name[n], t)
-                    if plan is not None:
-                        # the param constraint is the in-step all_gather
-                        # rebuilding full params from the updated slices
-                        # (and, ZeRO off, pins the steady-state layout so
-                        # donated outputs never flap shardings)
-                        w2 = jax.lax.with_sharding_constraint(w2, _psh(n, w2))
-                        s2 = jax.tree_util.tree_map(
-                            lambda x: jax.lax.with_sharding_constraint(
-                                x, _ssh(n, x)), s2)
-                    nw.append(w2)
-                    ns.append(s2)
-                return jax.tree_util.tree_unflatten(treedef, nw), ns
+                pairs = [apply(n, w, g, s) for w, g, s in zip(
+                    w_leaves, jax.tree_util.tree_leaves(grads[n]),
+                    states[n])]
+                return (jax.tree_util.tree_unflatten(
+                    treedef, [w2 for w2, _ in pairs]),
+                    [s2 for _, s2 in pairs])
 
+            new_params, new_states = {}, {}
             with jax.named_scope("optimizer_update"):
                 for n in params:
                     new_params[n], new_states[n] = updated(n)
@@ -739,8 +747,30 @@ class FusedStep:
                     new_aux = guarded_select(finite, new_aux, aux)
                     new_ls = next_state(ls, finite, ls_cfg)
             if plan is not None:
-                new_aux = {n: jax.lax.with_sharding_constraint(v, _repl)
-                           for n, v in new_aux.items()}
+                # pin steady-state shardings: without this GSPMD may pick
+                # new layouts for the donated outputs, forcing a
+                # recompile on the next step when the re-fed params carry
+                # different shardings. Under ZeRO the param constraint is
+                # the all_gather that rebuilds full params from the
+                # updated 1/N slices.
+                new_params = {n: jax.tree_util.tree_map(
+                    lambda x, _n=n: pin(
+                        x, plan.param_sharding(_n, x.shape)), v)
+                    for n, v in new_params.items()}
+                new_states = {n: jax.tree_util.tree_map(
+                    lambda x, _n=n: pin(
+                        x, plan.state_sharding(_n, x.shape)), v)
+                    for n, v in new_states.items()}
+                new_aux = {n: pin(v, _repl) for n, v in new_aux.items()}
+                # pin the outputs to the batch layout: without this the
+                # partitioner is free to pick a different forward layout
+                # per program (observed: ZeRO chose class-dim-sharded
+                # softmax, whose row-sum is a different cross-device
+                # reduction — breaking ZeRO-vs-replicated bitwise
+                # equality)
+                outs = [pin(o, NamedSharding(plan.mesh, fit_spec_to_shape(
+                    plan.batch_spec(o.ndim), o.shape, plan.mesh)))
+                    for o in outs]
             extra = ()
             if ls_cfg is not None:
                 extra += (new_ls,)
@@ -751,7 +781,19 @@ class FusedStep:
             return new_params, new_states, new_aux, outs
 
         self._step_body = step
+        # the abstract arguments of the first call: compiled_hlo() lowers
+        # from them after the buffers themselves were donated
+        self._abstract_args = None
         self._compile_step()
+
+    def _replicated(self, scalars):
+        """A rider's scalars where the step reads them: on the plan's
+        mesh, replicated."""
+        if self.plan is None:
+            return tuple(jnp.asarray(x) for x in scalars)
+        from jax.sharding import NamedSharding, PartitionSpec
+        repl = NamedSharding(self.plan.mesh, PartitionSpec())
+        return tuple(jax.device_put(x, repl) for x in scalars)
 
     def _compile_step(self):
         from ..compiler import PersistentJit
@@ -770,7 +812,7 @@ class FusedStep:
         if self.donate and self._ig_cfg is not None:
             donate = donate + (8,)  # ...and so does the sentinel
         self._step_fn = PersistentJit(
-            self.guard.wrap(self._step_body), kind="fused-step",
+            self.guard.wrap(self._step_body), kind=self._program_kind,
             key_parts=self._program_key_parts,
             donate_argnums=donate,
             on_materialize=materialized)
@@ -899,48 +941,74 @@ class FusedStep:
         return sentinel_stats(self._ig_state)
 
     def reset_integrity_state(self):
-        """Fresh sentinel after a recovery rollback (same shapes/dtypes,
-        so no retrace)."""
+        """Fresh sentinel after a recovery rollback (same shapes/dtypes/
+        shardings, so no retrace): the restored params' gradient
+        distribution starts a new regime."""
         if self._ig_cfg is None:
             return
         from ..resilience.integrity import init_sentinel
-        state = tuple(jnp.asarray(x) for x in init_sentinel())
-        if self.plan is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            _repl0 = NamedSharding(self.plan.mesh, PartitionSpec())
-            state = tuple(jax.device_put(x, _repl0) for x in state)
-        self._ig_state = state
+        self._ig_state = self._replicated(init_sentinel())
+
+    def _scoped(self):
+        """What a call of the step runs under. Mesh-aware ops
+        (MultiHeadAttention seq_axis, ...) consult the ambient mesh
+        while the step traces (first call only)."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        from ..parallel.mesh import mesh_scope
+        return mesh_scope(self.mesh)
 
     def __call__(self, params, states, aux, inputs, rng, lr, t):
-        with _quiet_donation():
-            if self.mesh is None:
-                return self._run(params, states, aux, inputs, rng, lr, t)
-            # mesh-aware ops (MultiHeadAttention seq_axis, ...) consult
-            # the ambient mesh while the step traces (first call only)
-            from ..parallel.mesh import mesh_scope
-            with mesh_scope(self.mesh):
-                return self._run(params, states, aux, inputs, rng, lr, t)
-
-    def _run(self, params, states, aux, inputs, rng, lr, t):
-        if self._ls_cfg is None and self._ig_cfg is None:
-            return self._step_fn(params, states, aux, inputs, rng, lr, t)
         # the guard states are internal to the FusedStep: callers keep
         # the classic 7-arg contract, the donated program carries (and
         # returns) the loss-scale pair / integrity sentinel alongside.
-        # With only the sentinel armed, _ls_state (None) still rides at
-        # slot 7 so the sentinel's donated slot stays fixed at 8.
-        args = (params, states, aux, inputs, rng, lr, t, self._ls_state)
+        # With only the sentinel armed, _ls_state (None, an empty
+        # pytree) still rides at slot 7 so the sentinel's donated slot
+        # stays fixed at 8.
+        args = (params, states, aux, inputs, rng, lr, t)
+        if self._ls_cfg is not None or self._ig_cfg is not None:
+            args = args + (self._ls_state,)
         if self._ig_cfg is not None:
             args = args + (self._ig_state,)
-        res = self._step_fn(*args)
-        params, states, aux, outs = res[:4]
+        if self._abstract_args is None:
+            self._abstract_args = jax.tree_util.tree_map(
+                self._abstract, args)
+        with _quiet_donation(), self._scoped():
+            res = self._step_fn(*args)
+        if len(res) == 4:
+            return res
         tail = 4
         if self._ls_cfg is not None:
             self._ls_state = res[tail]
             tail += 1
         if self._ig_cfg is not None:
             self._ig_state = res[tail]
-        return params, states, aux, outs
+        return res[:4]
+
+    def _abstract(self, x):
+        """Shape, dtype and mesh sharding of one argument. Single-device
+        placements (rng key, scalars) stay unspecified, or lower() rejects
+        the device mix."""
+        from jax.sharding import NamedSharding
+        sh = getattr(x, "sharding", None)
+        if not isinstance(sh, NamedSharding) or sh.mesh != self.mesh:
+            sh = None
+        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                    sharding=sh)
+
+    def compiled_hlo(self) -> str:
+        """Optimized HLO text of the compiled step, lowered again from
+        the first call's abstract arguments (shapes and shardings do not
+        change after bind): what tests and tools read the communication
+        pattern from."""
+        if self._abstract_args is None:
+            raise MXNetError("run at least one step first")
+        # a deliberate extra trace, not a retrace of the step: raise the
+        # guard's budget so it stays quiet
+        self.guard.expected += 1
+        with self._scoped():
+            lowered = self._step_fn.jit.lower(*self._abstract_args)
+        return lowered.compile().as_text()
 
 
 # ---------------------------------------------------------------------------

@@ -222,6 +222,26 @@ def test_spmd_bind_over_budget_raises_before_state_replaced(monkeypatch):
     assert not getattr(tr, "states", None)
 
 
+def test_gate_is_the_steps_own_and_names_its_front_end(monkeypatch):
+    """One gate, in ``FusedStep``, on one condition (a budget is set):
+    ``SPMDTrainer.bind`` above and a ``FusedStep`` built by hand both
+    reach it, each named in the error; a program whose shapes were not
+    given cannot be priced and is not refused."""
+    from mxnet_tpu import optimizer as opt_mod
+    from mxnet_tpu.perf.step_runtime import FusedStep
+    monkeypatch.setenv("MXTPU_HBM_BUDGET_MB", "0.001")
+    symb = _mlp_sym()
+    shapes = {"data": (BATCH, 16), "softmax_label": (BATCH,)}
+    arg_shapes, _, _ = symb.infer_shape(**shapes)
+    shapes = dict(zip(symb.list_arguments(), arg_shapes))
+    names = [n for n in shapes if n not in ("data", "softmax_label")]
+    sgd = opt_mod.create("sgd", learning_rate=0.1)
+    with pytest.raises(MemoryBudgetError) as exc:
+        FusedStep(symb, sgd, names, name="probe", input_shapes=shapes)
+    assert "FusedStep('probe') bind: estimated peak HBM" in str(exc.value)
+    assert FusedStep(symb, sgd, names, name="probe") is not None
+
+
 def test_spmd_bind_within_budget_is_untouched(monkeypatch):
     monkeypatch.setenv("MXTPU_HBM_BUDGET_MB", "10000")
     tr = _bound_trainer(zero=1)

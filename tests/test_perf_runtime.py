@@ -73,74 +73,169 @@ def params_of(mod):
 # donation equivalence — Module / Gluon / SPMDTrainer
 # ---------------------------------------------------------------------------
 
-def test_module_donation_equivalence():
-    batch = lstm_batch()
-    results = []
-    for donate in (True, False):
-        mod = lstm_module()
-        stepper = perf.module_stepper(mod, donate=donate)
-        assert stepper is not None
-        for _ in range(2):
-            stepper.step(batch)
-        results.append(params_of(mod))
-    donated, undonated = results
-    for n in donated:
-        assert np.array_equal(donated[n], undonated[n]), n
+def _module_two_steps(donate):
+    mod = lstm_module()
+    stepper = perf.module_stepper(mod, donate=donate)
+    assert stepper is not None
+    for _ in range(2):
+        stepper.step(lstm_batch())
+    return params_of(mod)
 
 
-def test_gluon_trainer_donation_equivalence():
-    def run(donate):
-        mx.random.seed(11)
-        np.random.seed(11)
-        net = nn.Sequential(prefix="deq_")
-        with net.name_scope():
-            net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
-        net.initialize()
-        tr = gluon.Trainer(net.collect_params(), "sgd",
-                           {"learning_rate": 0.1, "momentum": 0.9})
-        tr._donate_buffers = donate
-        x = mx.nd.array(np.random.RandomState(3).rand(8, 12))
-        y = mx.nd.array(np.random.RandomState(4).randint(0, 4, (8,)))
-        loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-        for _ in range(2):
-            with mx.autograd.record():
-                loss = loss_fn(net(x), y)
-            loss.backward()
-            tr.step(8)
-        assert tr._fused_apply not in (None, False)  # fused path taken
-        return {k: v.data().asnumpy()
-                for k, v in net.collect_params().items()}
-
-    donated, undonated = run(True), run(False)
-    assert donated.keys() == undonated.keys() and donated
-    for k in donated:
-        assert np.array_equal(donated[k], undonated[k]), k
+def _gluon_two_steps(donate):
+    mx.random.seed(11)
+    np.random.seed(11)
+    net = nn.Sequential(prefix="deq_")
+    with net.name_scope():
+        net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
+    net.initialize()
+    tr = gluon.Trainer(net.collect_params(), "sgd",
+                       {"learning_rate": 0.1, "momentum": 0.9})
+    tr._donate_buffers = donate
+    x = mx.nd.array(np.random.RandomState(3).rand(8, 12))
+    y = mx.nd.array(np.random.RandomState(4).randint(0, 4, (8,)))
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    for _ in range(2):
+        with mx.autograd.record():
+            loss = loss_fn(net(x), y)
+        loss.backward()
+        tr.step(8)
+    assert tr._fused_apply not in (None, False)  # fused path taken
+    return {k: v.data().asnumpy() for k, v in net.collect_params().items()}
 
 
-def test_spmd_trainer_donation_equivalence():
+def _spmd_two_steps(donate):
     import jax
     from mxnet_tpu.parallel import SPMDTrainer, make_mesh
 
     rng = np.random.RandomState(0)
     x = rng.rand(8, 12).astype(np.float32)
     y = rng.randint(0, 4, (8,)).astype(np.float32)
-    results = []
-    for donate in (True, False):
-        mx.random.seed(21)      # identical parameter init across runs
-        mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
-        tr = SPMDTrainer(mlp_symbol(), optimizer="sgd",
-                         optimizer_params={"learning_rate": 0.1,
-                                           "momentum": 0.9},
-                         mesh=mesh, donate_buffers=donate)
-        tr.bind(data_shapes={"data": (8, 12)},
-                label_shapes={"softmax_label": (8,)})
-        for _ in range(2):
-            tr.step({"data": x, "softmax_label": y})
-        arg, _ = tr.get_params()
-        results.append({n: v.asnumpy() for n, v in arg.items()})
-    donated, undonated = results
+    mx.random.seed(21)      # identical parameter init across runs
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    tr = SPMDTrainer(mlp_symbol(), optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                     mesh=mesh, donate_buffers=donate)
+    tr.bind(data_shapes={"data": (8, 12)},
+            label_shapes={"softmax_label": (8,)})
+    for _ in range(2):
+        tr.step({"data": x, "softmax_label": y})
+    arg, _ = tr.get_params()
+    return {n: v.asnumpy() for n, v in arg.items()}
+
+
+@pytest.mark.parametrize("two_steps", [
+    _module_two_steps, _gluon_two_steps, _spmd_two_steps],
+    ids=["module", "gluon_trainer", "spmd_trainer"])
+def test_donation_equivalence(two_steps):
+    donated, undonated = two_steps(True), two_steps(False)
+    assert donated.keys() == undonated.keys() and donated
     for n in donated:
         assert np.array_equal(donated[n], undonated[n]), n
+
+
+# ---------------------------------------------------------------------------
+# one step body, two front ends (SPMDTrainer, Module through module_stepper)
+# ---------------------------------------------------------------------------
+
+def two_block_symbol():
+    """An MLP whose two hidden layers are blocks that ask for a checkpoint."""
+    x = mx.sym.var("data")
+    for k in range(2):
+        with mx.AttrScope(__block__=f"block{k}", __remat__="block"):
+            x = mx.sym.FullyConnected(x, num_hidden=16, name=f"fc{k}")
+            x = mx.sym.Activation(x, act_type="tanh", name=f"act{k}")
+    x = mx.sym.FullyConnected(x, num_hidden=4, name="head")
+    return mx.sym.SoftmaxOutput(x, mx.sym.var("softmax_label"),
+                                name="softmax")
+
+
+def _spmd_front_end(symbol, **kw):
+    """(the FusedStep, its (params, states, aux)) of a bound SPMDTrainer."""
+    import jax
+    from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+    tr = SPMDTrainer(symbol, optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                     mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]),
+                     **kw)
+    tr.bind({"data": (8, 12)}, {"softmax_label": (8,)})
+    return tr._fused, (tr.params, tr.states, tr.aux)
+
+
+def _module_front_end(symbol, **kw):
+    """The same of a Module through ``module_stepper``."""
+    mod = mx.mod.Module(symbol)
+    mod.bind(data_shapes=[DataDesc("data", (8, 12))],
+             label_shapes=[DataDesc("softmax_label", (8,))])
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(optimizer="sgd", optimizer_params={
+        "learning_rate": 0.1, "momentum": 0.9})
+    stepper = perf.module_stepper(mod, **kw)
+    assert stepper is not None
+    return stepper._fused, (stepper._params, stepper._states, stepper._aux)
+
+
+@pytest.mark.parametrize("front_end", [_spmd_front_end, _module_front_end],
+                         ids=["spmd_trainer", "module"])
+def test_blocks_are_checkpoints_under_every_front_end(front_end):
+    """``__remat__="block"`` reaches the evaluator from the one place that
+    builds it: the lowered step holds one checkpoint a block and the
+    step body's scopes, whichever front end bound it."""
+    import jax
+    import jax.numpy as jnp
+    fused, state = front_end(two_block_symbol(), compute_dtype="bfloat16")
+    assert fused._opt_res.remat_blocks
+    inputs = {"data": jnp.zeros((8, 12), jnp.float32),
+              "softmax_label": jnp.zeros((8,), jnp.float32)}
+    with fused._scoped():
+        text = jax.jit(fused._step_body).lower(
+            *state, inputs, jax.random.PRNGKey(0), jnp.float32(0.1),
+            jnp.float32(1.0)).as_text(debug_info=True)
+    # a checkpoint keeps its inside from being shared with the backward:
+    # one barrier a block
+    assert text.count("optimization_barrier") == 2
+    assert "cast_params" in text and "optimizer_update" in text
+
+
+def test_spmd_trainer_and_module_fit_take_the_same_steps():
+    """One device, float32, SGD with momentum, the same symbol, weights
+    and three batches: ``SPMDTrainer`` on a one-device mesh and
+    ``Module.fit`` run one step body, so parameters and momentum come out
+    equal bit for bit."""
+    import jax
+    from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+
+    rng = np.random.RandomState(5)
+    x = rng.rand(24, 12).astype(np.float32)
+    y = rng.randint(0, 4, (24,)).astype(np.float32)
+    opt = {"learning_rate": 0.1, "momentum": 0.9, "rescale_grad": 1.0 / 8}
+    start = {"fc1_weight": rng.randn(16, 12), "fc1_bias": rng.randn(16),
+             "fc2_weight": rng.randn(4, 16), "fc2_bias": rng.randn(4)}
+    start = {n: v.astype(np.float32) * 0.3 for n, v in start.items()}
+
+    mod = mx.mod.Module(mlp_symbol())
+    mod.fit(NDArrayIter(x, y, batch_size=8), num_epoch=1, optimizer="sgd",
+            optimizer_params=dict(opt), eval_metric="acc",
+            arg_params={n: mx.nd.array(v) for n, v in start.items()},
+            aux_params={})
+    stepper = mod._fused_stepper
+    assert stepper is not None and stepper.guard.count == 1
+
+    tr = SPMDTrainer(mlp_symbol(), optimizer="sgd",
+                     optimizer_params=dict(opt),
+                     mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
+    tr.bind({"data": (8, 12)}, {"softmax_label": (8,)}, arg_params=start)
+    tr.fit(NDArrayIter(x, y, batch_size=8), num_epoch=1)
+    assert tr.retrace_guard.count == 1
+
+    for n in start:
+        assert np.array_equal(np.asarray(tr.params[n]),
+                              np.asarray(stepper._params[n])), n
+        # the trainer keeps the rule's own state, the stepper a list of
+        # one state a leaf: the one body reads either
+        momentum = np.asarray(tr.states[n])
+        assert np.any(momentum != 0)
+        assert np.array_equal(momentum, np.asarray(stepper._states[n][0])), n
 
 
 # ---------------------------------------------------------------------------
