@@ -572,9 +572,11 @@ def main():
                              platform=first.platform)
     if args.rehearse:
         # off the chip the dispatcher picks the jnp cell; the rehearsal
-        # wants the kernel's control flow, through the interpreter
+        # wants the kernels' control flow, through the interpreter
         lstm_kernel.lstm_cell_fused = functools.partial(
             lstm_kernel.lstm_cell_fused, impl="interpret")
+        lstm_kernel.lstm_recurrence = functools.partial(
+            lstm_kernel.lstm_recurrence, impl="interpret")
 
     print(json.dumps({"start": device,
                       "jax": jax.__version__,

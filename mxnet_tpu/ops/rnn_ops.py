@@ -5,8 +5,13 @@ In the reference it is cuDNN-only — the CPU forward/backward are empty TODO
 stubs (rnn-inl.h:123-153); this rebuild's version runs everywhere. The TPU
 formulation: the input projection for the WHOLE sequence is one large matmul
 (MXU-friendly, done outside the scan), and ``lax.scan`` carries only the
-``h @ R^T`` recurrence; gradients come from jax.vjp through the scan, which
-is exactly the memory-efficient scan-transpose cuDNN implements by hand.
+``h @ R^T`` recurrence. Gradients of ``gru`` / ``rnn_*`` come from jax.vjp
+through the scan (its transpose); mode ``lstm`` runs
+``ops.pallas.lstm.lstm_recurrence``, whose backward is written as a whole the
+way cuDNN's is: what it keeps of the forward is ``(xproj, h0, c0, h and c of
+every step, W_hh)``, its walk back over the time steps holds the pointwise
+adjoints and the one matmul ``dgates_t @ W_hh``, and the gate recomputation
+and ``dW_hh`` are one matmul each outside it.
 
 Weight packing follows the reference's cuDNN convention (rnn_cell.py
 FusedRNNCell.unpack_weights): all layer weights first — for each layer, each
@@ -83,18 +88,8 @@ def _unpack(params, num_layers, input_size, state_size, mode, bidirectional):
 
 
 def _cell_step(mode, H):
-    """Returns step(carry, gates_in) for one timestep given precomputed
-    x-projection + biases; carry is h (and c for lstm)."""
-    if mode == "lstm":
-        from .pallas.lstm import lstm_cell_fused
-
-        def step(carry, xproj, w_h2h):
-            h, c = carry
-            # fused pallas cell on TPU (jnp elsewhere); custom VJP keeps
-            # the scan differentiable
-            h_new, c_new = lstm_cell_fused(xproj, h, c, w_h2h)
-            return (h_new, c_new), h_new
-        return step
+    """Returns step(carry, gates_in) for one timestep of a ``gru`` or
+    ``rnn_*`` layer given precomputed x-projection + biases; carry is h."""
     if mode == "gru":
         def step(carry, xproj, w_h2h, b_h2h):
             (h,) = carry
@@ -119,32 +114,29 @@ def _run_direction(x, h0, c0, w_i2h, w_h2h, b_i2h, b_h2h, mode, H,
     """One direction of one layer. x: (T, N, in). Returns (out(T,N,H), hT, cT)."""
     # whole-sequence input projection: one MXU matmul outside the scan
     T, N = x.shape[0], x.shape[1]
-    if mode == "gru":
-        # GRU keeps h2h bias separate (reset gate multiplies h-projection)
-        xproj = x.reshape(T * N, -1) @ w_i2h.T + b_i2h
-        xproj = xproj.reshape(T, N, -1)
-        step = _cell_step(mode, H)
-
-        def body(carry, xp):
-            return step(carry, xp, w_h2h, b_h2h)
-    else:
-        xproj = x.reshape(T * N, -1) @ w_i2h.T + (b_i2h + b_h2h)
-        xproj = xproj.reshape(T, N, -1)
-        step = _cell_step(mode, H)
-
-        def body(carry, xp):
-            return step(carry, xp, w_h2h)
-
-    carry = (h0, c0) if mode == "lstm" else (h0,)
-    # named, so a device trace tells the recurrence (and, transposed, the
-    # cell's backward) from the projection matmuls around it
+    # GRU keeps h2h bias separate (reset gate multiplies h-projection)
+    bias = b_i2h if mode == "gru" else b_i2h + b_h2h
+    # the product comes out in the dtype the sum with the bias will have
+    # (float32 under bf16 weights and a float32 bias: the accumulator, not a
+    # bf16 rounding of it), and the bias joins the (T, N, G*H) stack rather
+    # than the flat product: the TPU compiler then writes the stack
+    # time-major in one pass, as the recurrence reads it
+    xproj = jnp.matmul(x.reshape(T * N, -1), w_i2h.T,
+                       preferred_element_type=jnp.result_type(
+                           x.dtype, w_i2h.dtype, bias.dtype)
+                       ).reshape(T, N, -1) + bias
+    # named, so a device trace tells the recurrence (and its backward) from
+    # the projection matmuls around it
     with jax.named_scope("scan"):
-        carry, out = lax.scan(body, carry, xproj, reverse=reverse)
-    if mode == "lstm":
-        hT, cT = carry
-    else:
-        (hT,), cT = carry, None
-    return out, hT, cT
+        if mode == "lstm":
+            # looked up at the call: the chip rehearsal steers its ``impl``
+            from .pallas import lstm
+            return lstm.lstm_recurrence(xproj, h0, c0, w_h2h, reverse=reverse)
+        step = _cell_step(mode, H)
+        rest = (w_h2h, b_h2h) if mode == "gru" else (w_h2h,)
+        (hT,), out = lax.scan(lambda carry, xp: step(carry, xp, *rest),
+                              (h0,), xproj, reverse=reverse)
+    return out, hT, None
 
 
 def _rnn_impl(rng, data, parameters, state, state_cell, state_size,

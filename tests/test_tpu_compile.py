@@ -63,6 +63,25 @@ def _lstm_cell(n, hdim, dtype):
     return build
 
 
+def _lstm_layer(t, n, hdim, state_dtype, w_dtype, walk_kernels):
+    """One direction of one layer, forward and backward, as ``jax.grad`` of
+    the RNN op runs it on the chip; ``walk_kernels``: whether W_hh fits in
+    VMEM, so that one kernel walks the time steps each way."""
+    def fwd_bwd(xproj, h0, c0, w, dout):
+        (out, hT, cT), vjp = jax.vjp(
+            lambda *a: lstm.lstm_recurrence(*a, impl="pallas"),
+            xproj, h0, c0, w)
+        return out, vjp((dout, hT, cT))
+
+    def build(struct):
+        state = struct((n, hdim), state_dtype)
+        return fwd_bwd, (struct((t, n, 4 * hdim), jnp.float32), state, state,
+                         struct((4 * hdim, hdim), w_dtype),
+                         struct((t, n, hdim), state_dtype))
+    build.walk_kernels = walk_kernels
+    return build
+
+
 def _dense(b, h, s, d, backward):
     scale = 1.0 / d ** 0.5
 
@@ -132,6 +151,15 @@ _CASES = {
     "lstm-n32-h64-f32": _lstm_cell(32, 64, jnp.float32),
     "lstm-n32-h200-bf16": _lstm_cell(32, 200, jnp.bfloat16),
     "lstm-n20-h650-f32": _lstm_cell(20, 650, jnp.float32),
+    # the LM cell's layer (perfbench lstm-ptb-large.train-fed-seq128; 40 s:
+    # the kernels' gate slices are off the lane tiling)
+    "lstm-layer-t8-n256-h1500-bf16-weight": _lstm_layer(
+        8, 256, 1500, jnp.float32, jnp.bfloat16, walk_kernels=True),
+    "lstm-layer-t32-n64-h1024-bf16": _lstm_layer(
+        32, 64, 1024, jnp.bfloat16, jnp.bfloat16, walk_kernels=True),
+    # W_hh is 128 MiB: lax.scan of the per-step kernel, the jnp walk back
+    "lstm-layer-t8-n64-h4096-bf16": _lstm_layer(
+        8, 64, 4096, jnp.bfloat16, jnp.bfloat16, walk_kernels=False),
     "flash-b4h16s2048d64-fwd": _dense(4, 16, 2048, 64, backward=False),
     "flash-b4h16s2048d64-fwd-bwd": _dense(4, 16, 2048, 64, backward=True),
     "flash-b1h8s32768d128-fwd": _dense(1, 8, 32768, 128, backward=False),
@@ -153,4 +181,9 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     # off, and Mosaic takes no int64 block index
     with jax.enable_x64(False):
         compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    walk_kernels = getattr(_CASES[case], "walk_kernels", None)
+    if walk_kernels is not None:
+        assert ("lstm_cell_scan" in text) == walk_kernels
+        assert ("lstm_bwd_step" in text) == walk_kernels
