@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..base import AttrSpec
-from .registry import register
+from .registry import OP_TABLE, register
 
 
 def _switch_param_shapes(attrs, shapes):
@@ -81,12 +81,28 @@ def _switch_ffn(data, gate_weight, expert_w1, expert_b1, expert_w2,
     return out.reshape(shape).astype(data.dtype), aux
 
 
+def _moe_ffn_input_names(attrs):
+    """The inputs of this instantiation, in order: the bias of the
+    selection and the shared expert's three weights are there where the
+    attrs ask for them."""
+    names = ["data", "router_weight", "expert_gate_weight",
+             "expert_up_weight", "expert_down_weight", "stats"]
+    if attrs.get("use_expert_bias"):
+        names.append("expert_bias")
+    if attrs.get("shared_hidden_size"):
+        names += ["shared_gate_weight", "shared_up_weight",
+                  "shared_down_weight"]
+    return names
+
+
 def _moe_ffn_param_shapes(attrs, shapes):
     d = shapes[0][-1]
     e, f = int(attrs["num_experts"]), int(attrs["hidden_size"])
     held = int(attrs["experts_held"]) or e
     fs = int(attrs["shared_hidden_size"])
     out = [shapes[0], (e, d), (held, d, f), (held, d, f), (held, f, d), (3,)]
+    if attrs.get("use_expert_bias"):
+        out.append((e,))
     return out + ([(fs, d), (fs, d), (d, fs)] if fs else [])
 
 
@@ -95,35 +111,43 @@ def _moe_ffn_param_shapes(attrs, shapes):
                          top_k=("int", 1), experts_held=("int", 0),
                          expert_offset=("int", 0),
                          routed_scale=("float", 1.0),
-                         shared_hidden_size=("int", 0)),
+                         shared_hidden_size=("int", 0),
+                         use_expert_bias=("bool", False),
+                         renorm_eps=("float", 0.0)),
           num_inputs=None,
-          input_names=["data", "router_weight", "expert_gate_weight",
-                       "expert_up_weight", "expert_down_weight", "stats",
-                       "shared_gate_weight", "shared_up_weight",
-                       "shared_down_weight"],
+          input_names=_moe_ffn_input_names(
+              {"use_expert_bias": True, "shared_hidden_size": 1}),
           param_shapes=_moe_ffn_param_shapes, needs_is_train=True,
-          aux_inputs=(5,), aux_update={1: 5},
+          aux_inputs=lambda attrs: (5, 6) if attrs.get("use_expert_bias")
+          else (5,),
+          aux_update={1: 5},
           aux_counters={5: ("moe.assignments_held", "moe.load_max",
                             "moe.overflow")})
 def _moe_ffn(data, router_weight, expert_gate_weight, expert_up_weight,
-             expert_down_weight, stats, *shared, num_experts, hidden_size,
+             expert_down_weight, stats, *rest, num_experts, hidden_size,
              top_k=1, experts_held=0, expert_offset=0, routed_scale=1.0,
-             shared_hidden_size=0, _is_train=False):
+             shared_hidden_size=0, use_expert_bias=False, renorm_eps=0.0,
+             _is_train=False):
     """A routed feed-forward layer as one chip of an expert-parallel
     deployment holds it, over (..., d) inputs.
 
     The router scores all ``num_experts`` experts (sigmoid, float32), every
-    token keeps its ``top_k`` with weights ``routed_scale * s_e / sum of
-    the chosen s``, and the layer computes the part of the result that its
-    own experts give: those numbered ``expert_offset .. expert_offset +
-    experts_held - 1`` (all of them when ``experts_held`` is 0), each a
-    SwiGLU of width ``hidden_size``, by sort, grouped matmul and weighted
-    return (``parallel.moe.held_experts_apply``): no token is dropped, and
-    the grouped matmul runs every choice's row, held or not, so that a
-    step's time does not hang on the routing.
-    With ``shared_hidden_size`` a shared SwiGLU expert of that width
-    (three more inputs) is added for every token. The shares of all chips,
-    the shared expert counted once, add up to the whole layer.
+    token keeps its ``top_k`` with weights ``routed_scale * s_e / (sum of
+    the chosen s + renorm_eps)``, and the layer computes the part of the
+    result that its own experts give: those numbered ``expert_offset ..
+    expert_offset + experts_held - 1`` (all of them when ``experts_held``
+    is 0), each a SwiGLU of width ``hidden_size``, by sort, grouped matmul
+    and weighted return (``parallel.moe.held_experts_apply``): no token is
+    dropped, and the grouped matmul runs every choice's row, held or not,
+    so that a step's time does not hang on the routing.
+    With ``use_expert_bias`` a second auxiliary input ``expert_bias`` (E,)
+    float32 follows ``stats``: the ``top_k`` are those with the largest
+    ``s + expert_bias``, their weights still come from ``s`` alone. It is a
+    buffer: no gradient reaches it, no optimizer touches it, and the op
+    does not update it (a rule that balances the load with it is the
+    caller's). With ``shared_hidden_size`` a shared SwiGLU expert of that
+    width (three more inputs, last) is added for every token. The shares of
+    all chips, the shared expert counted once, add up to the whole layer.
 
     The auxiliary state ``stats`` (3,) accumulates per training step, on
     the device: the token-choices that fell on held experts, the fullest
@@ -136,10 +160,12 @@ def _moe_ffn(data, router_weight, expert_gate_weight, expert_up_weight,
 
     shape = data.shape
     toks = data.reshape(-1, shape[-1])
+    bias, shared = (rest[0], rest[1:]) if use_expert_bias else (None, rest)
     y, counts = held_experts_apply(
         toks, router_weight, expert_gate_weight, expert_up_weight,
         expert_down_weight, num_experts=num_experts, top_k=top_k,
-        expert_offset=expert_offset, routed_scale=routed_scale)
+        expert_offset=expert_offset, routed_scale=routed_scale,
+        select_bias=bias, renorm_eps=renorm_eps)
     if shared_hidden_size:
         with jax.named_scope("shared"):
             y = y + gated_ffn(toks, *shared)
@@ -149,3 +175,6 @@ def _moe_ffn(data, router_weight, expert_gate_weight, expert_up_weight,
         stats = stats + step.astype(stats.dtype)
     return (y.reshape(shape).astype(data.dtype),
             jax.lax.stop_gradient(stats))
+
+
+OP_TABLE["MoEFFN"].dynamic_input_names = _moe_ffn_input_names

@@ -401,6 +401,102 @@ def _gated_ffn(data, gate_weight, up_weight, down_weight, num_hidden):
     return gated_ffn(data, gate_weight, up_weight, down_weight)
 
 
+# ---------------------------------------------------------------------------
+# ShortConv: the gated short convolution of hybrid convolution-attention
+# decoders (a causal depthwise convolution of a few taps between two gates)
+# ---------------------------------------------------------------------------
+
+
+def _shift(x, steps):
+    """``x`` (B, S, D) moved ``steps`` positions later along S (earlier for
+    a negative count), zeros entering at the row's end it leaves: a row
+    never reads its neighbour."""
+    if steps == 0:
+        return x
+    s = x.shape[1]
+    if steps > 0:
+        return jnp.pad(x, ((0, 0), (steps, 0), (0, 0)))[:, :s]
+    return jnp.pad(x, ((0, 0), (0, -steps), (0, 0)))[:, -steps:]
+
+
+def _taps(g, w, sign):
+    """sum_k w[:, k] * g moved ``sign * (L - 1 - k)`` positions: the causal
+    cross-correlation for +1, its transpose for -1."""
+    taps = w.shape[-1]
+    return sum(_shift(g, sign * (taps - 1 - k)) * w[:, k]
+               for k in range(taps))
+
+
+@jax.custom_vjp
+def gated_short_conv(b, c, h, w):
+    """``c * conv(b * h)`` over (B, S, D) streams: ``g = b * h``, ``y_t =
+    sum_k w[:, k] g_{t - (L - 1 - k)}`` a channel with ``g_s = 0`` for
+    ``s < 0`` in every row (a causal depthwise cross-correlation, left
+    padding L - 1, no bias), then the second gate. Arithmetic in float32,
+    result in the streams' dtype. The residuals are the four inputs: the
+    backward makes ``g`` and ``y`` again in its one pass over ``b``, ``c``,
+    ``h`` and the cotangent, and keeps no float32 stream between the two."""
+    return _gated_short_conv_fwd(b, c, h, w)[0]
+
+
+def _gated_short_conv_fwd(b, c, h, w):
+    f32 = jnp.float32
+    y = _taps(b.astype(f32) * h.astype(f32), w.astype(f32), 1)
+    return (c.astype(f32) * y).astype(b.dtype), (b, c, h, w)
+
+
+def _gated_short_conv_bwd(res, dz):
+    b, c, h, w = res
+    f32 = jnp.float32
+    bf, cf, hf, wf = (v.astype(f32) for v in (b, c, h, w))
+    dz = dz.astype(f32)
+    g = bf * hf
+    dy = dz * cf
+    dc = dz * _taps(g, wf, 1)
+    dg = _taps(dy, wf, -1)
+    taps = w.shape[-1]
+    dw = jnp.stack([jnp.sum(dy * _shift(g, taps - 1 - k), axis=(0, 1))
+                    for k in range(taps)], axis=-1)
+    return ((dg * hf).astype(b.dtype), dc.astype(c.dtype),
+            (dg * bf).astype(h.dtype), dw.astype(w.dtype))
+
+
+gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
+
+
+def _short_conv_param_shapes(attrs, shapes):
+    d = shapes[0][-1]
+    return [shapes[0], (3 * d, d), (d, int(attrs["kernel"])), (d, d)]
+
+
+@register("ShortConv", num_inputs=4,
+          input_names=["data", "in_weight", "conv_weight", "out_weight"],
+          param_shapes=_short_conv_param_shapes,
+          attrs=AttrSpec(kernel=("int", 3)))
+def _short_conv(data, in_weight, conv_weight, out_weight, kernel=3):
+    """Gated short convolution over (B, S, D), no bias, no activation:
+    ``[b, c, h] = split(data W_in^T, 3)`` with ``in_weight`` (3D, D);
+    ``y = conv(b * h)``, a causal depthwise convolution of ``kernel`` taps
+    with ``conv_weight`` (D, kernel), every row of the batch starting from
+    zeros; ``out = (c * y) W_out^T`` with ``out_weight`` (D, D). The two
+    matmuls run in the activation's dtype; what lies between them is
+    memory-bound (three streams read, one written) and carries the named
+    scope ``conv`` beside ``in_proj`` and ``out_proj``, so a device trace
+    tells the three apart."""
+    d = data.shape[-1]
+    if data.ndim != 3 or conv_weight.shape != (d, kernel):
+        raise MXNetError(
+            f"ShortConv: data {data.shape} is (rows, positions, channels) "
+            f"and conv_weight {conv_weight.shape} (channels, {kernel})")
+    with jax.named_scope("in_proj"):
+        bch = jnp.dot(data, in_weight.astype(data.dtype).T)
+    with jax.named_scope("conv"):
+        z = gated_short_conv(bch[..., :d], bch[..., d:2 * d], bch[..., 2 * d:],
+                             conv_weight)
+    with jax.named_scope("out_proj"):
+        return jnp.dot(z, out_weight.astype(data.dtype).T)
+
+
 @register("LRN", attrs=AttrSpec(alpha=("float", 1e-4), beta=("float", 0.75),
                                 knorm=("float", 2.0), nsize=("int",),
                                 axis=("int", 1)))

@@ -87,8 +87,10 @@ class OpDef:
         self.aux_update = aux_update or {}
         self.grad_fn = grad_fn
         # input indices that are auxiliary states, not gradient-bearing args
-        # (reference: OperatorProperty::ListAuxiliaryStates)
-        self.aux_inputs = tuple(aux_inputs)
+        # (reference: OperatorProperty::ListAuxiliaryStates); a callable
+        # ``attrs -> indices`` where an attr adds one (MoEFFN's expert_bias)
+        self.aux_inputs = aux_inputs if callable(aux_inputs) \
+            else tuple(aux_inputs)
         # param_shapes(attrs, input_shapes) -> full input-shape list with
         # unknown parameter shapes filled in from the data shape + attrs;
         # the simple_bind-side half of the reference's two-way InferShape
@@ -109,6 +111,12 @@ class OpDef:
         if callable(self._num_outputs):
             return self._num_outputs(attrs)
         return self._num_outputs
+
+    def aux_input_indices(self, attrs) -> Tuple[int, ...]:
+        """The auxiliary inputs of THIS instantiation."""
+        if callable(self.aux_inputs):
+            return tuple(self.aux_inputs(attrs))
+        return self.aux_inputs
 
     def uses_rng(self, attrs) -> bool:
         """Does THIS instantiation actually draw randomness?

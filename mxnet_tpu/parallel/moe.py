@@ -192,11 +192,15 @@ def moe_apply(x, router_w, expert_params, expert_fn: Callable, mesh: Mesh,
 
 # -- the share of a routed layer that one chip holds, with no token dropped ---
 
-def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0):
+def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0,
+                        select_bias=None, renorm_eps: float = 0.0):
     """Sigmoid scores over all experts in float32, the ``k`` largest, and
-    their weights ``scale * s_e / sum of the chosen s``. ``router_w`` is
-    (E, d) as ``FullyConnected`` keeps it. Returns (weights (T, k) float32,
-    indices (T, k) int32)."""
+    their weights ``scale * s_e / (sum of the chosen s + renorm_eps)``.
+    With ``select_bias`` (E,) the ``k`` chosen are those with the largest
+    ``s + select_bias``; the weights are still made of the unbiased ``s``,
+    and no gradient reaches the bias. ``router_w`` is (E, d) as
+    ``FullyConnected`` keeps it. Returns (weights (T, k) float32, indices
+    (T, k) int32)."""
     logits = jax.lax.dot_general(
         x, router_w.astype(x.dtype), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -204,8 +208,17 @@ def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0):
     if k > scores.shape[-1]:
         raise MXNetError(f"top_k={k} exceeds the number of experts "
                          f"{scores.shape[-1]}")
-    top, idx = jax.lax.top_k(scores, k)
-    return scale * top / jnp.sum(top, -1, keepdims=True), idx
+    if select_bias is None:
+        top, idx = jax.lax.top_k(scores, k)
+    else:
+        _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(
+            select_bias.astype(scores.dtype)), k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = scale * top
+    total = jnp.sum(top, -1, keepdims=True)
+    if renorm_eps:
+        total = total + renorm_eps
+    return weights / total, idx
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -241,11 +254,14 @@ _permute_rows.defvjp(lambda x, take, put: (x[take], put),
 
 
 def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
-                       top_k, expert_offset=0, routed_scale=1.0):
+                       top_k, expert_offset=0, routed_scale=1.0,
+                       select_bias=None, renorm_eps=0.0):
     """What the experts held here add to a routed layer's output.
 
     ``x`` (T, d) tokens; ``router_w`` (E, d) scores ALL ``num_experts``
-    experts and every token keeps its ``top_k``; the stacked weights
+    experts and every token keeps its ``top_k`` (chosen under
+    ``select_bias``, weighted without it, ``renorm_eps`` in the weights'
+    denominator: :func:`sigmoid_topk_router`); the stacked weights
     ``w_gate``/``w_up`` (Eh, d, f) and ``w_down`` (Eh, f, d) are those of
     experts ``expert_offset .. expert_offset + Eh - 1``, each a SwiGLU.
     The token-choices are sorted by expert, the absent experts' last, and
@@ -264,8 +280,8 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
     t, d = x.shape
     held = w_gate.shape[0]
     with jax.named_scope("route"):
-        weights, chosen = sigmoid_topk_router(x, router_w, top_k,
-                                              routed_scale)
+        weights, chosen = sigmoid_topk_router(
+            x, router_w, top_k, routed_scale, select_bias, renorm_eps)
     with jax.named_scope("dispatch"):
         local = chosen.reshape(-1) - expert_offset          # slot -> expert
         local = jnp.where((local >= 0) & (local < held), local, held)

@@ -165,7 +165,7 @@ class Symbol:
         aux = set()
         for node in self._topo_nodes():
             if node.op is not None and node.op.aux_inputs:
-                for i in node.op.aux_inputs:
+                for i in node.op.aux_input_indices(node.attrs):
                     if i < len(node.inputs):
                         parent, _ = node.inputs[i]
                         if parent.is_variable:
@@ -791,6 +791,10 @@ def symbol_invoke(opdef: OpDef, inputs: Sequence[Symbol], attrs: Dict,
 
 
 def _expected_inputs(opdef: OpDef, attrs: Dict) -> int:
+    # an op that names its inputs by its attrs has as many as it names
+    names_by_attrs = getattr(opdef, "dynamic_input_names", None)
+    if names_by_attrs is not None:
+        return len(names_by_attrs(attrs))
     if opdef.name in ("Convolution", "Deconvolution", "FullyConnected"):
         return 2 if attrs.get("no_bias") else 3
     if opdef.name == "LeakyReLU":
@@ -801,11 +805,6 @@ def _expected_inputs(opdef: OpDef, attrs: Dict) -> int:
         return int(attrs.get("num_args", 1) or 1)
     if opdef.name == "GroupedQueryAttention":
         return 4 if attrs.get("gated") else 3
-    if opdef.name == "MoEFFN":
-        return 9 if attrs.get("shared_hidden_size") else 6
-    if opdef.name == "_contrib_CTCLoss":
-        return (2 + bool(attrs.get("use_data_lengths"))
-                + bool(attrs.get("use_label_lengths")))
     return len(opdef.input_names or ["data"])
 
 

@@ -119,10 +119,11 @@ def _masked(b, h, s, d, with_lengths):
     return build
 
 
-def _gqa(heads, s, window, backward):
+def _gqa(heads, s, window, backward, d=128, rows=1):
     """The decoder's attention at Laguna-XS.2's widths: ``heads`` query
-    heads over 8 key/value heads of 128, one document of ``s`` positions."""
-    scale = 1.0 / 128 ** 0.5
+    heads over 8 key/value heads of 128, one document of ``s`` positions;
+    or at LFM2-8B-A1B's: heads of ``d`` = 64, ``rows`` = 2 documents."""
+    scale = 1.0 / d ** 0.5
 
     def fwd(q, k, v):
         return attention._gqa_pallas(q, k, v, True, window, scale, 512, 512,
@@ -136,8 +137,8 @@ def _gqa(heads, s, window, backward):
                                                  window, scale, 512)
 
     def build(struct):
-        q = struct((1, heads, s, 128), jnp.bfloat16)
-        kv = struct((1, 8, s, 128), jnp.bfloat16)
+        q = struct((rows, heads, s, d), jnp.bfloat16)
+        kv = struct((rows, 8, s, d), jnp.bfloat16)
         return (fwd_bwd, (q, kv, kv, q)) if backward else (fwd, (q, kv, kv))
     return build
 
@@ -166,6 +167,9 @@ _CASES = {
     "gqa-h64kv8s8192d128-window512-fwd": _gqa(64, 8192, 512, False),
     "gqa-h64kv8s8192d128-window512-fwd-bwd": _gqa(64, 8192, 512, True),
     "gqa-h48kv8s8192d128-full-fwd-bwd": _gqa(48, 8192, 0, True),
+    # half a lane tile a head (perfbench lfm2-8b-a1b.train-fed-2x8k)
+    "gqa-b2h32kv8s8192d64-full-fwd-bwd": _gqa(32, 8192, 0, True, d=64,
+                                              rows=2),
     "masked-b4h16s2048d64-lengths": _masked(4, 16, 2048, 64, True),
     "masked-b4h16s2048d64-segment-ids": _masked(4, 16, 2048, 64, False),
 }
