@@ -19,6 +19,7 @@ from jax import shard_map
 from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import profiler
 from ..base import MXNetError
 
 __all__ = ["moe_apply", "moe_dense_apply", "top1_router", "topk_router",
@@ -223,20 +224,20 @@ def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _rows_by_slot(x, take, put, k):
-    """``x[take // k]``: row r of the result is the token of slot
-    ``take[r]`` (k slots a token). ``put`` is the inverse permutation, so
-    the cotangent is a gather too: slot by slot, then summed over a token's
-    k slots; a scatter-add over 8 T rows is what this spares."""
-    return x[take // k]
+    """``x[take % T]``: row r of the result is the token of slot ``take[r]``
+    (slot ``j * T + t`` is token t's choice j, k choices a token). ``put``
+    is the inverse permutation, so the cotangent is a gather too: slot by
+    slot, then the sum of a token's k slots, which are k whole (T, d)
+    slabs; a scatter-add over k T rows is what this spares."""
+    return x[take % x.shape[0]]
 
 
 def _rows_by_slot_fwd(x, take, put, k):
-    return x[take // k], put
+    return x[take % x.shape[0]], put
 
 
 def _rows_by_slot_bwd(k, put, g):
-    per_slot = g[put]
-    return per_slot.reshape(-1, k, g.shape[-1]).sum(1), None, None
+    return g[put].reshape(k, -1, g.shape[-1]).sum(0), None, None
 
 
 _rows_by_slot.defvjp(_rows_by_slot_fwd, _rows_by_slot_bwd)
@@ -253,6 +254,44 @@ _permute_rows.defvjp(lambda x, take, put: (x[take], put),
                      lambda put, g: (g[put], None, None))
 
 
+@jax.custom_vjp
+def _weighted_return(per_slot, weights, held):
+    """The sum over a token's k slots of ``weights * per_slot`` where
+    ``held``, and exactly nothing where not: ``per_slot`` (k, T, d) the rows
+    back in slot order, ``weights`` (k, T) float32, ``held`` (k, T) bool.
+    Widened, weighted and summed in float32, rounded once to the rows'
+    dtype. One pass over the rows each way: the residuals are the three
+    arguments as they came (no float32 copy of the rows), the rows'
+    cotangent is written once in their dtype, selected and not multiplied,
+    so a row that is not held may hold anything finite or not."""
+    return _weighted_return_fwd(per_slot, weights, held)[0]
+
+
+def _held_rows(per_slot, held):
+    """The rows in float32, an absent slot's selected to zero."""
+    return jnp.where(held[..., None], per_slot, 0).astype(jnp.float32)
+
+
+def _weighted_return_fwd(per_slot, weights, held):
+    y = jnp.sum(_held_rows(per_slot, held) * weights[..., None], axis=0)
+    return y.astype(per_slot.dtype), (per_slot, weights, held)
+
+
+def _weighted_return_bwd(res, g):
+    per_slot, weights, held = res
+    profiler.count("moe.fused_return_layers")
+    g = g.astype(jnp.float32)
+    d_rows = jnp.where(held[..., None], g[None] * weights[..., None], 0)
+    # the caller flattens the slots to rows: hoisted above this product, the
+    # flattening leaves g's broadcast over k a float32 (k, T, d) in memory
+    d_rows = jax.lax.optimization_barrier(d_rows.astype(per_slot.dtype))
+    d_weights = jnp.sum(_held_rows(per_slot, held) * g[None], axis=-1)
+    return d_rows, d_weights, None
+
+
+_weighted_return.defvjp(_weighted_return_fwd, _weighted_return_bwd)
+
+
 def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
                        top_k, expert_offset=0, routed_scale=1.0,
                        select_bias=None, renorm_eps=0.0):
@@ -264,26 +303,32 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
     denominator: :func:`sigmoid_topk_router`); the stacked weights
     ``w_gate``/``w_up`` (Eh, d, f) and ``w_down`` (Eh, f, d) are those of
     experts ``expert_offset .. expert_offset + Eh - 1``, each a SwiGLU.
-    The token-choices are sorted by expert, the absent experts' last, and
+    The token-choices (slots, numbered choice-major: slot ``j * T + t`` is
+    token t's choice j) are sorted by expert, the absent experts' last, and
     ALL of them run through a grouped matmul (``jax.lax.ragged_dot``): the
     absent experts' choices ride at the end of the last held expert's
-    group and their results are put to zero, so a step costs the same
-    wherever the routing goes: the grouped matmul's time goes with the
-    rows in its groups, and with the held rows alone in them the step's
-    time moves by 4% between a routing that passes this chip by and one
-    that lands on it (it is a seed's coin which; PERF.md, PR 27). The
-    price is the matmul of 8 T rows a layer, always. The results return
-    to their tokens weighted: no (T, E, C) tensor, no capacity, nothing
-    dropped. What the absent experts would add is left out. Returns
-    (y (T, d) in x's dtype, counts (Eh,) int32: the choices that fell on
-    each held expert)."""
+    group and their results are selected away on the way back, so a step
+    costs the same wherever the routing goes: the grouped matmul's time
+    goes with the rows in its groups, and with the held rows alone in them
+    the step's time moves by 4% between a routing that passes this chip by
+    and one that lands on it (it is a seed's coin which; PERF.md, PR 27).
+    The price is the matmul of k T rows a layer, always. The results return
+    to their tokens weighted (:func:`_weighted_return`): no (T, E, C)
+    tensor, no capacity, nothing dropped, and no tensor with a token's k
+    slots as a second-minor axis, which at k = 4 is half a sublane tile
+    and made every meeting of the sorted rows with their tokens a copy
+    (PERF.md, PR 32). What the absent experts would add is left out.
+    Returns (y (T, d) in x's dtype, counts (Eh,) int32: the choices that
+    fell on each held expert)."""
     t, d = x.shape
     held = w_gate.shape[0]
     with jax.named_scope("route"):
         weights, chosen = sigmoid_topk_router(
             x, router_w, top_k, routed_scale, select_bias, renorm_eps)
     with jax.named_scope("dispatch"):
-        local = chosen.reshape(-1) - expert_offset          # slot -> expert
+        # slot j * T + t is token t's choice j: a token's k slots are k
+        # whole (T, d) slabs, never the second-minor axis of a tile
+        local = chosen.T.reshape(-1) - expert_offset        # slot -> expert
         local = jnp.where((local >= 0) & (local < held), local, held)
         # slots in the order of their expert, the absent ones last
         take = jnp.argsort(local, stable=True).astype(jnp.int32)
@@ -291,10 +336,9 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
         counts = jnp.sum(
             local[:, None] == jnp.arange(held, dtype=local.dtype)[None, :],
             axis=0, dtype=jnp.int32)
-        n_held = jnp.sum(counts)
         # every row lies in a group (the grouped matmul writes no other)
-        groups = counts.at[-1].add((t * top_k - n_held).astype(counts.dtype))
-        is_held = (jnp.arange(t * top_k) < n_held)[:, None]
+        groups = counts.at[-1].add(
+            (t * top_k - jnp.sum(counts)).astype(counts.dtype))
         rows = _rows_by_slot(x, take, put, top_k)
     with jax.named_scope("experts"):
         dt = x.dtype
@@ -302,11 +346,11 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
         up = jax.lax.ragged_dot(rows, w_up.astype(dt), groups)
         out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dt),
                                  groups)
-        # selected, not multiplied: the cotangent of an absent row is zero
-        # too, so it reaches neither the tokens nor the last expert
-        out = jnp.where(is_held, out, 0)
     with jax.named_scope("combine"):
-        per_slot = _permute_rows(out, put, take).reshape(t, top_k, d)
-        y = jnp.sum(per_slot.astype(jnp.float32) * weights[..., None],
-                    axis=1)
-    return y.astype(x.dtype), counts
+        # an absent slot's row is selected away inside the return, not
+        # multiplied: its cotangent is zero too, so it reaches neither the
+        # tokens nor the last expert
+        per_slot = _permute_rows(out, put, take).reshape(top_k, t, d)
+        y = _weighted_return(per_slot, weights.T,
+                             (local < held).reshape(top_k, t))
+    return y, counts

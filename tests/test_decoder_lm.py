@@ -396,6 +396,118 @@ def test_the_grouped_matmul_does_the_same_work_wherever_the_routing_goes(
         _close(y, jnp.zeros_like(y))
 
 
+def _plain_return(per_slot, weights, held):
+    """What ``_weighted_return`` computes, as autodiff sees plain ``jnp``."""
+    terms = jnp.where(held[..., None], per_slot, 0).astype(jnp.float32)
+    return jnp.sum(terms * weights[..., None], axis=0).astype(per_slot.dtype)
+
+
+@pytest.mark.parametrize("top_k", [1, 4, 8])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_weighted_return_against_plain_jnp(dtype, top_k):
+    """Value and the cotangents of rows and weights (none for the mask)
+    against the select, the float32 product and the sum written out; the
+    rows of absent slots hold large finite garbage, and add exactly nothing
+    to the output, to the weights' cotangent or to their own."""
+    from mxnet_tpu.parallel.moe import _weighted_return
+    t, d = 24, 16
+    held = jax.random.bernoulli(jax.random.PRNGKey(5), 0.6, (top_k, t))
+    rows = jnp.where(held[..., None], _rand(top_k, t, d, seed=1),
+                     3e38).astype(dtype)
+    weights = jnp.abs(_rand(top_k, t, seed=2)) + 0.5
+    ct = _rand(t, d, seed=3).astype(dtype)
+    # bf16 rounds once, to 8 bits; float32 differs by the order of a sum
+    tol = 2e-5 if dtype == "float32" else 2.0 ** -8
+
+    def both(fn):
+        y, vjp = jax.vjp(fn, rows, weights, held)
+        return (y,) + vjp(ct)
+
+    got, want = both(jax.jit(_weighted_return)), both(_plain_return)
+    assert got[0].dtype == got[1].dtype == rows.dtype
+    assert got[2].dtype == jnp.float32
+    assert got[3].dtype == jax.dtypes.float0      # the mask has no cotangent
+    for g, w in zip(got[:3], want[:3]):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        _close(np.asarray(g, np.float32), np.asarray(w, np.float32), tol)
+    absent = ~np.asarray(held)
+    assert not np.asarray(got[1], np.float32)[absent].any()
+    assert not np.asarray(got[2])[absent].any()
+    # tokens none of whose slots is held get exactly zero
+    nobody = ~np.asarray(held).any(0)
+    assert not np.asarray(got[0], np.float32)[nobody].any()
+
+
+def _held_experts_token_major(x, router_w, w_gate, w_up, w_down, *,
+                              num_experts, top_k, expert_offset=0,
+                              routed_scale=1.0):
+    """``held_experts_apply`` as it was before its slots went choice-major:
+    slot ``t * k + j``, the absent rows selected to zero after the third
+    matmul, the return as autodiff differentiates it."""
+    from mxnet_tpu.parallel.moe import sigmoid_topk_router
+    t, d = x.shape
+    held = w_gate.shape[0]
+    weights, chosen = sigmoid_topk_router(x, router_w, top_k, routed_scale)
+    local = chosen.reshape(-1) - expert_offset
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    take = jnp.argsort(local, stable=True)
+    put = jnp.argsort(take)
+    counts = jnp.sum(local[:, None] == jnp.arange(held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    groups = counts.at[-1].add(t * top_k - jnp.sum(counts))
+    rows = x[take // top_k]
+    gate = jax.lax.ragged_dot(rows, w_gate, groups)
+    up = jax.lax.ragged_dot(rows, w_up, groups)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, groups)
+    out = jnp.where((jnp.arange(t * top_k) < jnp.sum(counts))[:, None],
+                    out, 0)
+    per_slot = out[put].reshape(t, top_k, d)
+    y = jnp.sum(per_slot.astype(jnp.float32) * weights[..., None], axis=1)
+    return y.astype(x.dtype), counts
+
+
+@pytest.mark.parametrize("top_k, offset, held", [
+    (1, 0, 16), (4, 0, 4), (4, 8, 4), (8, 4, 8)])
+def test_choice_major_slots_give_the_layer_it_was(top_k, offset, held):
+    """The same inputs through the layer as it was (token-major slots,
+    plain autodiff) and as it is: the output, the count of choices on each
+    held expert, and the gradients of the tokens, the router and the three
+    expert stacks."""
+    from mxnet_tpu.parallel.moe import held_experts_apply
+    m = _moe_inputs()
+    kw = dict(num_experts=16, top_k=top_k, expert_offset=offset,
+              routed_scale=2.5)
+    stacks = [m[n][offset:offset + held] for n in ("gate", "up", "down")]
+    args = (m["x"], m["router"], *stacks)
+    assert np.array_equal(held_experts_apply(*args, **kw)[1],
+                          _held_experts_token_major(*args, **kw)[1])
+    _same_with_gradients(lambda *a: held_experts_apply(*a, **kw)[0],
+                         lambda *a: _held_experts_token_major(*a, **kw)[0],
+                         *args, tol=5e-5)
+
+
+def _fused_returns_traced(cfg):
+    """What ``moe.fused_return_layers`` grows by while ``cfg``'s training
+    step, its blocks checkpoints, is traced."""
+    before = mx.profiler.counters().get("moe.fused_return_layers", 0)
+    _trace_tiny_graph(cfg, remat_blocks=True, is_train=True)
+    return mx.profiler.counters().get("moe.fused_return_layers", 0) - before
+
+
+def test_the_counter_says_how_many_routed_layers_return_through_the_op():
+    """+ 1 for each routed layer while a training step is traced (the
+    blocks checkpoints, as the decoder cells bind them), nothing for a
+    dense model."""
+    five = dict(num_hidden_layers=5,
+                layer_types=["full_attention"] + ["sliding_attention"] * 3
+                + ["full_attention"],
+                num_attention_heads_per_layer=[12, 16, 16, 16, 12])
+    assert _fused_returns_traced(_tiny(
+        mlp_layer_types=["dense"] + ["sparse"] * 4, **five)) == 4
+    assert _fused_returns_traced(_tiny(
+        mlp_layer_types=["dense"] * 5, **five)) == 0
+
+
 # -- the model ------------------------------------------------------------------
 
 def _tiny(**over):
@@ -463,6 +575,22 @@ def _tiny_graph(cfg):
     return sym, args, aux
 
 
+def _trace_tiny_graph(cfg, remat_blocks, is_train):
+    """Trace the tiny graph's loss, in training its gradient, once (x64
+    off, as the chip runs): what the trace-time counters count."""
+    from mxnet_tpu.executor import build_graph_eval
+    sym, args, aux = _tiny_graph(cfg)
+    fn = build_graph_eval(sym, remat_blocks=remat_blocks)
+
+    def f(p):
+        return fn(dict(args, **p), aux, None, is_train)[0][0][0]
+
+    params = {n: v for n, v in args.items()
+              if n not in ("data", "softmax_label")}
+    with jax.enable_x64(False):
+        jax.make_jaxpr(jax.grad(f) if is_train else f)(params)
+
+
 @pytest.mark.parametrize("case,kept", [
     ("kernel, checkpoints, training", 4), ("kernel, checkpoints, inference", 0),
     ("kernel, no checkpoints, training", 0),
@@ -472,23 +600,12 @@ def test_counters_say_what_the_block_checkpoints_keep(monkeypatch, case,
     """``remat.kept_values`` / ``remat.kept_bytes`` grow while a training
     step whose blocks are checkpoints is traced, by ``out`` and ``lse`` of
     every attention that ran the kernel, and at no other time."""
-    from mxnet_tpu.executor import build_graph_eval
     path, blocks, mode = case.split(", ")
     if path == "kernel":
         _through_the_kernel(monkeypatch)
     cfg = _tiny(num_hidden_layers=2)
-    sym, args, aux = _tiny_graph(cfg)
-    fn = build_graph_eval(sym, remat_blocks=blocks == "checkpoints")
-    is_train = mode == "training"
-
-    def f(p):
-        return fn(dict(args, **p), aux, None, is_train)[0][0][0]
-
-    params = {n: v for n, v in args.items()
-              if n not in ("data", "softmax_label")}
     before = mx.profiler.counters()
-    with jax.enable_x64(False):
-        jax.make_jaxpr(jax.grad(f) if is_train else f)(params)
+    _trace_tiny_graph(cfg, blocks == "checkpoints", mode == "training")
     # a layer keeps out (1, H, 32, 16) and lse (1, H, 32) in float32
     heads = sum(cfg["num_attention_heads_per_layer"][:2])
     assert _kept_since(before) == {
@@ -531,8 +648,9 @@ def test_blocks_are_checkpoints_where_the_model_asks(monkeypatch, path):
         _close(ga[n], gb[n], 1e-5)
     _close(ups_a["layer1_moe_stats"], ups_b["layer1_moe_stats"])
     # a checkpoint keeps its inside from being shared with the backward
-    assert "optimization_barrier" in text_a
-    assert "optimization_barrier" not in text_b
+    # (the routed layer's return holds one of its own either way)
+    assert text_a.count("optimization_barrier") > \
+        text_b.count("optimization_barrier")
 
 
 def test_tiny_model_trains_through_fit_like_the_reference():

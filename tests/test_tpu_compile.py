@@ -14,7 +14,9 @@ on-chip-measurement §2): one process at a time may load the TPU
 library, every xdist worker imports this file, and only the worker that
 runs it may make the call. Keep every such test in THIS file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.ops.pallas import attention, lstm
+from mxnet_tpu.parallel import moe
 
 
 @pytest.fixture(scope="module")
@@ -191,3 +194,103 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     if walk_kernels is not None:
         assert ("lstm_cell_scan" in text) == walk_kernels
         assert ("lstm_bwd_step" in text) == walk_kernels
+
+
+# -- what the routed layer moves around its grouped matmuls -------------------
+
+_ITEM_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+               "u16": 2, "f32": 4, "s32": 4, "u32": 4, "s64": 8, "u64": 8}
+_ARRAY = re.compile(r"\b(%s)\[([0-9,]*)\]" % "|".join(_ITEM_BYTES))
+_INSTRUCTION = re.compile(
+    r"(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\((.*)$")
+# no traffic of their own: names for what is there already
+_FREE = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+
+
+def _arrays(text):
+    """(dtype, elements) of every array type written in ``text``."""
+    return [(m.group(1),
+             math.prod(int(n) for n in m.group(2).split(",") if n))
+            for m in _ARRAY.finditer(text)]
+
+
+def _entry_traffic(hlo):
+    """(name, opcode, result arrays, operand + result bytes) of every
+    instruction of the compiled module's entry computation outside the
+    grouped-matmul kernels: what the compiler decided to write down and
+    read back. An asynchronous copy (``*-start`` / ``*-done``: the
+    compiler's prefetch between memory spaces) is left out, as ISSUE 32's
+    table left it."""
+    lines = hlo.splitlines()
+    at = next(i for i, line in enumerate(lines) if line.startswith("ENTRY"))
+    results, found = {}, []
+    for line in lines[at + 1:]:
+        if line.startswith("}"):
+            break
+        m = _INSTRUCTION.match(line.strip())
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        results[name] = _arrays(result)
+        if opcode in _FREE or opcode.endswith(("-start", "-done")) \
+                or name.startswith("ragged-dot-none"):
+            continue
+        # the operands are names: the list ends at the first parenthesis
+        operands = [a for n in re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+                    for a in results.get(n, [])]
+        found.append((name, opcode, results[name], sum(
+            _ITEM_BYTES[t] * n for t, n in results[name] + operands)))
+    return found
+
+
+_ROUTED_LAYERS = {
+    # tokens, d, f, held of experts, top_k; GB outside the grouped matmuls
+    # (ISSUE 32: 12.38 and 7.80 before; 7.93 and 7.03 as compiled, PR 32)
+    "lfm2-8b-a1b-t16384-k4-8of32": ((16384, 2048, 1792, 8, 32, 4), 9.0),
+    "laguna-xs2-t8192-k8-32of256": ((8192, 2048, 512, 32, 256, 8), 7.9),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROUTED_LAYERS))
+def test_routed_layer_moves_no_slot_tensor_it_need_not(case, one_chip,
+                                                       no_compile_cache):
+    """One routed layer, output and gradient (bf16 tokens, float32 master
+    weights), compiled for the described chip at a decoder cell's shape.
+    From the compiled text: outside the grouped matmuls nothing is written
+    in float32 at the size of the slot rows (T k d), no ``reshape`` or
+    ``copy`` relays a tensor of that size (a token's k slots as the
+    second-minor axis of a tile did both), and all that is read and written
+    there stays under the case's bound."""
+    (t, d, f, held, experts, k), bound = _ROUTED_LAYERS[case]
+
+    def loss(x, router, w_gate, w_up, w_down, ct):
+        y, _ = moe.held_experts_apply(x, router, w_gate, w_up, w_down,
+                                      num_experts=experts, top_k=k)
+        return jnp.sum(y.astype(jnp.float32) * ct.astype(jnp.float32)), y
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tokens = struct((t, d), jnp.bfloat16)
+    args = (tokens, struct((experts, d), jnp.float32),
+            struct((held, d, f), jnp.float32),
+            struct((held, d, f), jnp.float32),
+            struct((held, f, d), jnp.float32), tokens)
+    # as the chip runs it: conftest's "highest" is for the CPU's numerics,
+    # and the grouped-matmul kernel takes no float32-precision bf16 matmul
+    with jax.enable_x64(False), jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+                       ).lower(*args).compile().as_text()
+    # forward 3, backward 6: every choice's row still rides in each
+    assert len(set(re.findall(r"%(ragged-dot-none[.\d]*) = ", text))) == 9
+    traffic = _entry_traffic(text)
+    slot_rows = t * k * d
+    wide = [(name, arrays) for name, _, arrays, _ in traffic
+            if any(dt == "f32" and n >= slot_rows for dt, n in arrays)]
+    assert not wide, f"float32 at the slot rows' size: {wide}"
+    relaid = [(name, arrays) for name, opcode, arrays, _ in traffic
+              if opcode in ("reshape", "copy")
+              and any(n == slot_rows for _, n in arrays)]
+    assert not relaid, f"a relayout of the slot rows: {relaid}"
+    moved = sum(b for *_, b in traffic) / 1e9
+    assert moved < bound, f"{moved:.2f} GB outside the grouped matmuls"
