@@ -17,6 +17,15 @@ selection, ``use_expert_bias``, where the configuration has them). With
 ``tie_word_embeddings`` the head reads the embedding's matrix. A new
 architecture of the family is a configuration, not another model file.
 
+The training objective is a key too. Absent, it is next-token prediction
+under a causal mask. ``objective: "block_diffusion"`` (BD3-LM,
+arXiv:2503.09573; with ``block_length``) trains a block-diffusion model:
+every document runs twice in one sequence, a noisy copy (tokens replaced by
+the mask token, the feed's work) and then the clean one, each half of the
+positions ``data`` has and both at positions 0 .. L - 1, under the mask of
+``GroupedQueryAttention(block_length=...)``; the head and a weighted loss
+read the noisy half only (:func:`get_symbol`).
+
 The graph is made of registered ops only (``RMSNorm``, ``FullyConnected``,
 ``RotaryEmbedding``, ``GroupedQueryAttention``, ``ShortConv``, ``GatedFFN``,
 ``MoEFFN``, ``TokenCrossEntropy``), so ``SPMDTrainer`` / ``Module`` train it
@@ -76,11 +85,13 @@ def layer_plan(cfg):
     return plan
 
 
-def _rotary(x, name, head_dim, rope):
+def _rotary(x, name, head_dim, rope, copies=1):
     rotary_dim = int(round(head_dim * rope.get("partial_rotary_factor", 1)))
     attrs = dict(head_dim=head_dim, rotary_dim=rotary_dim,
                  theta=float(rope.get("rope_theta", 10000.0)),
                  rope_type=rope.get("rope_type", "default"))
+    if copies > 1:
+        attrs["copies"] = copies
     if attrs["rope_type"] == "yarn":
         attrs.update(
             factor=float(rope["factor"]),
@@ -106,24 +117,29 @@ def _head_norm(x, name, head_dim, eps):
                        shape=(0, 0, -1))
 
 
-def _attention(u, layer, p, cfg, hd, kv, eps):
+def _attention(u, layer, p, cfg, hd, kv, eps, block_length=0):
     """The attention mixer of one layer over the normed stream ``u``: q, k
     (each under ``qk_norm`` where asked, then rotated), v, the per-head gate
-    where asked, grouped-query attention and the output projection."""
+    where asked, grouped-query attention and the output projection. With
+    ``block_length`` (the block-diffusion objective's) the sequence is two
+    copies of a document, each at positions 0 .. S / 2 - 1, under the
+    block-diffusion mask."""
     heads, d = layer["heads"], int(cfg["hidden_size"])
     gated = bool(cfg.get("gating", False))
     q, key = _linear(u, heads * hd, p + "q"), _linear(u, kv * hd, p + "k")
     if cfg.get("qk_norm"):
         q = _head_norm(q, p + "q_norm", hd, eps)
         key = _head_norm(key, p + "k_norm", hd, eps)
-    ins = [_rotary(q, p + "q_rope", hd, layer["rope"]),
-           _rotary(key, p + "k_rope", hd, layer["rope"]),
+    copies, mask = (2, {"block_length": block_length}) if block_length \
+        else (1, {})
+    ins = [_rotary(q, p + "q_rope", hd, layer["rope"], copies),
+           _rotary(key, p + "k_rope", hd, layer["rope"], copies),
            _linear(u, kv * hd, p + "v")]
     if gated:
         ins.append(_linear(u, heads, p + "gate"))
     attn = sym.GroupedQueryAttention(
         *ins, num_heads=heads, num_kv_heads=kv, window=layer["window"],
-        causal=True, gated=gated, name=p + "attn")
+        causal=True, gated=gated, name=p + "attn", **mask)
     return _linear(attn, d, p + "o")
 
 
@@ -132,6 +148,8 @@ def _routed(z, p, cfg):
     if cfg.get("norm_topk_prob") is False:
         raise MXNetError("decoder_lm: norm_topk_prob false (weights not "
                          "renormalised over the chosen experts) is not built")
+    # absent, the attribute's default: the sigmoid router's graph as it was
+    scoring = {"score_func": cfg["score_func"]} if "score_func" in cfg else {}
     return sym.MoEFFN(
         z, num_experts=experts,
         hidden_size=int(cfg["moe_intermediate_size"]),
@@ -145,14 +163,46 @@ def _routed(z, p, cfg):
             cfg.get("shared_expert_intermediate_size", 0)),
         use_expert_bias=bool(cfg.get("use_expert_bias", False)),
         renorm_eps=float(cfg.get("norm_topk_eps", 0.0)),
-        name=p + "moe")
+        name=p + "moe", **scoring)
+
+
+def _block_length(cfg, plan):
+    """The block-diffusion objective's block length, or 0 for next-token
+    training. The document's length is half of what ``data`` holds: the
+    ops read it from the shape."""
+    objective = cfg.get("objective", "next_token")
+    if objective == "next_token":
+        return 0
+    if objective != "block_diffusion":
+        raise MXNetError(f"decoder_lm: unknown objective {objective!r}")
+    if "block_length" not in cfg:
+        raise MXNetError("decoder_lm: objective 'block_diffusion' needs "
+                         "'block_length'")
+    block = int(cfg["block_length"])
+    if block < 1:
+        raise MXNetError(f"decoder_lm: block_length {block} is no length of "
+                         f"a block")
+    other = sorted({layer["attention"] for layer in plan} - {"full_attention"})
+    if other:
+        raise MXNetError(f"decoder_lm: the block-diffusion mask is built "
+                         f"for full attention layers, not {other}")
+    return block
 
 
 def get_symbol(cfg=None, **kwargs):
     """The training symbol of the configuration ``cfg`` (a dict with the
     keys of a published ``config.json``; ``kwargs`` override). Inputs:
     ``data`` (rows, seq_len) token ids and ``softmax_label`` (rows, seq_len)
-    next-token ids. Output: the mean next-token cross-entropy, shape (1,)."""
+    next-token ids. Output: the mean next-token cross-entropy, shape (1,).
+
+    Under ``objective: "block_diffusion"`` (documents of L tokens in blocks
+    of ``block_length``): ``data`` (rows, 2 L) holds ``[x_t ; x_0]``, the
+    noisy copy and then the clean one; ``softmax_label`` (rows, 2, L)
+    float32 holds the targets ``x_0`` in plane 0 and a weight a position in
+    plane 1 (``1 / t`` where the feed put the mask token into ``x_t``, 0
+    elsewhere). Every layer runs over the 2 L rows; the final norm, the head
+    and the loss over the first L: ``sum(w * CE(logits_i, x0_i)) / (rows
+    L)``, position i predicting token i."""
     cfg = dict(cfg or {}, **kwargs)
     d = int(cfg["hidden_size"])
     hd = int(cfg.get("head_dim") or d // int(cfg["num_attention_heads"]))
@@ -168,7 +218,9 @@ def get_symbol(cfg=None, **kwargs):
         tied["weight"] = sym.var("embed_weight")
     x = sym.Embedding(sym.var("data"), input_dim=vocab, output_dim=d,
                       name="embed", **tied)
-    for k, layer in enumerate(layer_plan(cfg)):
+    plan = layer_plan(cfg)
+    block_length = _block_length(cfg, plan)
+    for k, layer in enumerate(plan):
         p = f"layer{k}_"
         with AttrScope(__block__=f"layer{k}", **remat):
             if layer["attention"] == "conv":
@@ -177,7 +229,8 @@ def get_symbol(cfg=None, **kwargs):
                                       name=p + "conv")
             else:
                 u = sym.RMSNorm(x, eps=eps, name=p + "attn_norm")
-                x = x + _attention(u, layer, p, cfg, hd, kv, eps)
+                x = x + _attention(u, layer, p, cfg, hd, kv, eps,
+                                   block_length)
             z = sym.RMSNorm(x, eps=eps, name=p + "mlp_norm")
             if layer["mlp"] == "dense":
                 m = sym.GatedFFN(z, num_hidden=int(cfg["intermediate_size"]),
@@ -186,7 +239,16 @@ def get_symbol(cfg=None, **kwargs):
                 m = _routed(z, p, cfg)
             x = x + m
     with AttrScope(__block__="loss_head", **remat):
+        if block_length:
+            # the noisy half alone is scored; the last layer's clean half
+            # feeds nothing and is computed as every other layer's is
+            x = sym.split(x, num_outputs=2, axis=1, name="noisy_half")[0]
         x = sym.RMSNorm(x, eps=eps, name="final_norm")
         logits = _linear(x, vocab, "lm_head", **tied)
-        return sym.TokenCrossEntropy(logits, sym.var("softmax_label"),
+        label = sym.var("softmax_label")
+        if not block_length:
+            return sym.TokenCrossEntropy(logits, label, name="loss")
+        targets, weights = sym.split(label, num_outputs=2, axis=1,
+                                     squeeze_axis=True, name="label_planes")
+        return sym.TokenCrossEntropy(logits, targets, weights, weighted=True,
                                      name="loss")

@@ -13,6 +13,7 @@ anywhere from one chip to a 4-D mesh.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -116,19 +117,22 @@ def rotary_frequencies(rotary_dim, theta, rope_type="default", factor=1.0,
                          original_max_position=("int", 0),
                          beta_fast=("float", 32.0),
                          beta_slow=("float", 1.0),
-                         attention_factor=("float", 1.0)),
+                         attention_factor=("float", 1.0),
+                         copies=("int", 1)),
           num_inputs=1, input_names=["data"])
 def _rotary_embedding(data, head_dim, rotary_dim=0, theta=10000.0,
                       rope_type="default", factor=1.0,
                       original_max_position=0, beta_fast=32.0,
-                      beta_slow=1.0, attention_factor=1.0):
+                      beta_slow=1.0, attention_factor=1.0, copies=1):
     """Rotary position embedding over (B, S, heads * head_dim): position s
     of every head has its first ``rotary_dim`` dims (all of them when 0)
     rotated by s times the frequencies, dimension i paired with
     i + rotary_dim / 2; the rest pass. cos and sin are multiplied by
     ``attention_factor`` (YaRN's scaling of the logits, applied where the
-    published models apply it). Angles in float32, output in the input's
-    dtype."""
+    published models apply it). With ``copies`` > 1 the sequence is that
+    many copies of one document laid end to end, each at positions 0 ..
+    S / copies - 1: the position of index s is s mod (S / copies). Angles in
+    float32, output in the input's dtype."""
     b, s, e = data.shape
     r = rotary_dim or head_dim
     if e % head_dim or r > head_dim or r % 2:
@@ -138,7 +142,15 @@ def _rotary_embedding(data, head_dim, rotary_dim=0, theta=10000.0,
             f"part an even number of dims inside a head")
     inv = rotary_frequencies(r, theta, rope_type, factor,
                              original_max_position, beta_fast, beta_slow)
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    if copies < 1 or s % copies:
+        raise MXNetError(
+            f"RotaryEmbedding: a sequence of {s} is not {copies} copies of "
+            f"one document")
+    if copies == 1:
+        position = jnp.arange(s, dtype=jnp.float32)
+    else:
+        position = (jnp.arange(s) % (s // copies)).astype(jnp.float32)
+    angle = position[:, None] * inv[None, :]
     cos = (jnp.cos(angle) * attention_factor)[None, :, None, :]
     sin = (jnp.sin(angle) * attention_factor)[None, :, None, :]
     x = data.reshape(b, s, e // head_dim, head_dim).astype(jnp.float32)
@@ -151,19 +163,26 @@ def _rotary_embedding(data, head_dim, rotary_dim=0, theta=10000.0,
 @register("GroupedQueryAttention",
           attrs=AttrSpec(num_heads=("int",), num_kv_heads=("int",),
                          window=("int", 0), causal=("bool", True),
-                         gated=("bool", False)),
+                         gated=("bool", False), block_length=("int", 0)),
           num_inputs=None, input_names=["query", "key", "value", "gate"],
           output_names=["output"])
 def _grouped_query_attention(*args, num_heads, num_kv_heads, window=0,
-                             causal=True, gated=False):
+                             causal=True, gated=False, block_length=0):
     """Attention of ``num_heads`` query heads over ``num_kv_heads`` key/value
     heads: query (B, S, num_heads * d), key and value (B, S, num_kv_heads *
     d); query head h reads key/value head h // (num_heads / num_kv_heads).
     Scores q k^T / sqrt(d); ``causal``; ``window`` > 0 lets key j be seen
     from i only if i - window < j <= i. With ``gated`` a fourth input ``gate``
     (B, S, num_heads) multiplies head h's output by sigmoid(gate_h).
+    ``block_length`` > 0 (causal, no window) is the block-diffusion
+    training mask: the S positions are a noisy copy of a document and then
+    the clean one, S / 2 each, in blocks of ``block_length``; with blk(i) =
+    (i mod S/2) // block_length and noisy(i) = i < S/2, key k is seen from
+    query q where both are noisy and blk(q) == blk(k), or q is noisy, k
+    clean and blk(q) > blk(k), or both are clean and blk(q) >= blk(k).
     On a TPU it runs ``ops/pallas/attention.py``'s flash kernel over the
-    band; elsewhere plain masked softmax."""
+    mask's live tiles (a band, or the block-diffusion walk, under the named
+    scope ``block_diffusion``); elsewhere plain masked softmax."""
     from .pallas.attention import grouped_query_attention
     query, key, value = args[:3]
     b, s, e = query.shape
@@ -177,9 +196,16 @@ def _grouped_query_attention(*args, num_heads, num_kv_heads, window=0,
     def heads(x, n):
         return x.reshape(b, s, n, d).transpose(0, 2, 1, 3)
 
-    out = grouped_query_attention(
-        heads(query, num_heads), heads(key, num_kv_heads),
-        heads(value, num_kv_heads), causal=causal, window=window)
+    scope = jax.named_scope("block_diffusion") if block_length \
+        else contextlib.nullcontext()
+    try:
+        with scope:
+            out = grouped_query_attention(
+                heads(query, num_heads), heads(key, num_kv_heads),
+                heads(value, num_kv_heads), causal=causal, window=window,
+                block_length=block_length)
+    except ValueError as e:
+        raise MXNetError(f"GroupedQueryAttention: {e}") from None
     out = out.transpose(0, 2, 1, 3)                      # (B, S, H, d)
     if gated:
         gate = jax.nn.sigmoid(args[3].astype(jnp.float32))

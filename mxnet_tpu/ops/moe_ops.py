@@ -113,7 +113,8 @@ def _moe_ffn_param_shapes(attrs, shapes):
                          routed_scale=("float", 1.0),
                          shared_hidden_size=("int", 0),
                          use_expert_bias=("bool", False),
-                         renorm_eps=("float", 0.0)),
+                         renorm_eps=("float", 0.0),
+                         score_func=("str", "sigmoid")),
           num_inputs=None,
           input_names=_moe_ffn_input_names(
               {"use_expert_bias": True, "shared_hidden_size": 1}),
@@ -127,11 +128,12 @@ def _moe_ffn(data, router_weight, expert_gate_weight, expert_up_weight,
              expert_down_weight, stats, *rest, num_experts, hidden_size,
              top_k=1, experts_held=0, expert_offset=0, routed_scale=1.0,
              shared_hidden_size=0, use_expert_bias=False, renorm_eps=0.0,
-             _is_train=False):
+             score_func="sigmoid", _is_train=False):
     """A routed feed-forward layer as one chip of an expert-parallel
     deployment holds it, over (..., d) inputs.
 
-    The router scores all ``num_experts`` experts (sigmoid, float32), every
+    The router scores all ``num_experts`` experts in float32 (``score_func``:
+    a ``sigmoid`` an expert, or the ``softmax`` over all of them), every
     token keeps its ``top_k`` with weights ``routed_scale * s_e / (sum of
     the chosen s + renorm_eps)``, and the layer computes the part of the
     result that its own experts give: those numbered ``expert_offset ..
@@ -165,7 +167,7 @@ def _moe_ffn(data, router_weight, expert_gate_weight, expert_up_weight,
         toks, router_weight, expert_gate_weight, expert_up_weight,
         expert_down_weight, num_experts=num_experts, top_k=top_k,
         expert_offset=expert_offset, routed_scale=routed_scale,
-        select_bias=bias, renorm_eps=renorm_eps)
+        select_bias=bias, renorm_eps=renorm_eps, score_func=score_func)
     if shared_hidden_size:
         with jax.named_scope("shared"):
             y = y + gated_ffn(toks, *shared)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import AttrSpec, MXNetError
-from .registry import register
+from .registry import OP_TABLE, register
 
 # ---------------------------------------------------------------------------
 # FullyConnected (fully_connected.cc:76)
@@ -734,19 +734,49 @@ def _softmax_cross_entropy(data, label):
     return -jnp.sum(picked).reshape(1)
 
 
-@register("TokenCrossEntropy", num_inputs=2, input_names=["data", "label"],
-          param_shapes=lambda attrs, shapes: [shapes[0], shapes[0][:-1]])
-def _token_cross_entropy(data, label):
+def _token_cross_entropy_input_names(attrs):
+    return ["data", "label"] + (["weight", "stats"]
+                                if attrs.get("weighted") else [])
+
+
+@register("TokenCrossEntropy", attrs=AttrSpec(weighted=("bool", False)),
+          num_inputs=None, input_names=["data", "label", "weight", "stats"],
+          param_shapes=lambda attrs, shapes: [shapes[0], shapes[0][:-1]] + (
+              [shapes[0][:-1], (1,)] if attrs.get("weighted") else []),
+          needs_is_train=True,
+          aux_inputs=lambda attrs: (3,) if attrs.get("weighted") else (),
+          aux_update={1: 3}, aux_counters={3: ("loss.weighted_tokens",)})
+def _token_cross_entropy(data, label, weight=None, stats=None,
+                         weighted=False, _is_train=False):
     """Mean cross-entropy of ``data`` (..., V) logits against ``label``
     (...) class ids, shape (1,): a loss head whose output IS the loss, so
     the step hands no (tokens, V) softmax back and a plain ``jax.vjp`` with
     a ones cotangent gives the gradient of the mean. The log-softmax runs
-    in float32 whatever the logits' dtype."""
+    in float32 whatever the logits' dtype.
+
+    With ``weighted`` a third input ``weight`` (...) float32 gives every
+    position its weight: the loss is ``sum(w * (lse - picked))`` over the
+    COUNT of positions (not the sum of the weights), so a position of
+    weight 0 adds nothing and its logits get a zero gradient. The
+    auxiliary state ``stats`` (1,) then counts, on the device and per
+    training step, the positions with ``w > 0``
+    (``loss.weighted_tokens``): read it at a boundary
+    (``SPMDTrainer.aux_counters``), never every step."""
     logits = data.reshape(-1, data.shape[-1]).astype(jnp.float32)
     idx = label.reshape(-1).astype(jnp.int32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - picked).reshape(1)
+    if not weighted:
+        return jnp.mean(lse - picked).reshape(1)
+    w = weight.reshape(-1).astype(jnp.float32)
+    loss = (jnp.sum(w * (lse - picked)) / w.size).reshape(1)
+    if _is_train:
+        stats = stats + jnp.sum(w > 0).astype(stats.dtype)
+    return loss, lax.stop_gradient(stats)
+
+
+OP_TABLE["TokenCrossEntropy"].dynamic_input_names = \
+    _token_cross_entropy_input_names
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))

@@ -16,6 +16,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import keep_residual, register
+from ... import profiler
 from ...base import AttrSpec
 
 _NEG = -1e30
@@ -468,22 +469,90 @@ def _band(i, bq, bk, n_k, causal, window):
     return jnp.maximum(i * bq - (window - 1), 0) // bk, last
 
 
-def _band_steps(n_q, bq, bk, n_k, causal, window):
+def _band_steps(n_q, bq, bk, n_k, causal, window, block_length=0):
     """The most key blocks any query block sees: the static length of the
     kernel's innermost grid axis."""
+    return max(_tile_counts(n_q, bq, bk, n_k, causal, window, block_length))
+
+
+def _walk_steps(i, half):
+    """Steps of query tile ``i``'s block-diffusion walk over halves of
+    ``half`` tiles (a Python int or traced): a noisy tile i reads i + 2 key
+    tiles, the clean tile half + i reads i + 1."""
+    return i % half + 1 + (i < half)
+
+
+def _tile_counts(n_q, bq, bk, n_k, causal, window, block_length=0):
+    """Key blocks each query block visits, a Python list: the schedule's
+    length a query block (:func:`_schedule`)."""
+    if block_length:
+        return [_walk_steps(i, n_q // 2) for i in range(n_q)]
     if not causal:
-        return n_k
-    most = 0
+        return [n_k] * n_q
+    counts = []
     for i in range(n_q):
         last = (i * bq + bq - 1) // bk
         first = max(i * bq - (window - 1), 0) // bk if window else 0
-        most = max(most, last - first + 1)
-    return most
+        counts.append(last - first + 1)
+    return counts
 
 
-def _band_mask(qpos, kpos, causal, window):
+def _schedule(i, j, bq, bk, n_k, causal, window, block_length=0):
+    """``(kb, last)``: the key block that step ``j`` of query block ``i``
+    reads, and the last one of its walk. The step is live while ``kb <=
+    last``; past that the index map hands ``last`` again, so the step moves
+    no data. ``i`` and ``j`` may be traced; the rest is static.
+
+    A band (causal, window, or every key) is one run, ``first(i) + j``.
+    The block-diffusion mask over ``[noisy ; clean]`` halves of n tiles
+    each (``block_length`` > 0, ``bq == bk``) names a block out of line:
+    noisy query tile i reads its own tile i (the block diagonal) and then
+    the clean tiles n .. n + i (blocks before its own: i + 2 steps); clean
+    query tile n + i reads n .. n + i. No tile without a live pair is
+    visited."""
+    if not block_length:
+        first, last = _band(i, bq, bk, n_k, causal, window)
+        return first + j, last
+    n = n_k // 2
+    noisy = i < n
+    last = jnp.where(noisy, i + n, i)
+    kb = jnp.where(noisy, jnp.where(j == 0, i, n + j - 1), n + j)
+    return kb, last
+
+
+def _cuts(i, kb, last, block_length):
+    """Does the mask cut the tile (query block ``i``, key block ``kb``)?
+    None where every visited tile of the schedule is masked (the band
+    paths mask every tile, as they always have); under the block-diffusion
+    mask the two diagonal tiles of a walk, the inner clean tiles not."""
+    if not block_length:
+        return None
+    return (kb == i) | (kb == last)
+
+
+def _band_mask(qpos, kpos, causal, window, block_length=0, half=0):
     """True where key ``kpos`` is seen from query ``qpos`` (broadcastable
-    int32 arrays), or None where every key is."""
+    int32 arrays), or None where every key is. With ``block_length`` the
+    sequence is two halves of ``half`` positions, a noisy copy and then a
+    clean one, in blocks of ``block_length``:
+
+        blk(i) = (i mod half) // block_length;  noisy(i) = i < half
+        see(q, k) =  noisy(q) and  noisy(k) and blk(q) == blk(k)
+                  or noisy(q) and !noisy(k) and blk(q) >  blk(k)
+                  or !noisy(q) and !noisy(k) and blk(q) >= blk(k)
+    """
+    if block_length:
+        q_noisy, k_noisy = qpos < half, kpos < half
+
+        def blk(pos, noisy):
+            # positions are whole and not negative: the truncating division
+            pos = jnp.where(noisy, pos, pos - half)
+            return jax.lax.div(pos, jnp.asarray(block_length, pos.dtype))
+
+        q_blk, k_blk = blk(qpos, q_noisy), blk(kpos, k_noisy)
+        return (q_noisy & k_noisy & (q_blk == k_blk)) \
+            | (q_noisy & ~k_noisy & (q_blk > k_blk)) \
+            | (~q_noisy & ~k_noisy & (q_blk >= k_blk))
     if not causal:
         return None
     mask = kpos <= qpos
@@ -492,12 +561,14 @@ def _band_mask(qpos, kpos, causal, window):
     return mask
 
 
-def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None):
+def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None,
+                            block_length=0):
     """Plain softmax attention with grouped query heads: q (B, H, S, D),
     k/v (B, Hkv, S, D), query head h reads key/value head h // (H/Hkv);
-    ``window`` > 0 lets key j be seen from i only if i - window < j <= i.
-    Scores and softmax in float32. The CPU path, and what the kernel and
-    its backward are tested against."""
+    ``window`` > 0 lets key j be seen from i only if i - window < j <= i;
+    ``block_length`` > 0 is the block-diffusion mask over two halves of
+    S / 2 (:func:`_band_mask`). Scores and softmax in float32. The CPU
+    path, and what the kernel and its backward are tested against."""
     b, h, s, d = q.shape
     hkv = k.shape[1]
     scale = 1.0 / (d ** 0.5) if scale is None else scale
@@ -505,7 +576,8 @@ def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None):
     sc = jnp.einsum("bkgqd,bkcd->bkgqc", q5, k,
                     preferred_element_type=jnp.float32) * scale
     pos = jnp.arange(s)
-    mask = _band_mask(pos[:, None], pos[None, :], causal, window)
+    mask = _band_mask(pos[:, None], pos[None, :], causal, window,
+                      block_length, s // 2)
     if mask is not None:
         sc = jnp.where(mask, sc, _NEG)
     p = jax.nn.softmax(sc, axis=-1)
@@ -515,20 +587,23 @@ def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None):
 
 
 def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, causal, window, scale, n_k):
-    """The flash recurrence over the band only. Grid (B*H, n_q, steps):
-    step ``j`` of query block ``i`` reads key block ``first(i) + j`` (the
-    index map clamps it to ``last(i)``, so a step past the band moves no
-    data and computes nothing). Operands go to the MXU in their own dtype,
-    accumulation is float32. Beside the output it writes each row's
-    logsumexp of the scaled scores over its band, ``m + log(l)`` in
-    float32: what the backward needs to rebuild the probabilities without
-    a pass of its own. ``lse_ref`` is one head's (n_q, 1, BQ), resident
-    while the head's query blocks run; block ``i`` fills row ``i`` of it."""
+                *, causal, window, scale, n_k, block_length=0):
+    """The flash recurrence over the mask's live tiles only. Grid (B*H,
+    n_q, steps): step ``j`` of query block ``i`` reads the key block its
+    schedule names (:func:`_schedule`; the index map clamps it to the
+    walk's last, so a step past the walk moves no data and computes
+    nothing). A band masks every tile it visits; the block-diffusion
+    schedule masks a tile only where the mask cuts it (:func:`_cuts`).
+    Operands go to the MXU in their own dtype, accumulation is float32.
+    Beside the output it writes each row's logsumexp of the scaled scores
+    over the keys it sees, ``m + log(l)`` in float32: what the backward
+    needs to rebuild the probabilities without a pass of its own.
+    ``lse_ref`` is one head's (n_q, 1, BQ), resident while the head's query
+    blocks run; block ``i`` fills row ``i`` of it."""
     i, j = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
-    first, last = _band(i, bq, bk, n_k, causal, window)
-    kb = first + j
+    kb, last = _schedule(i, j, bq, bk, n_k, causal, window, block_length)
+    cuts = _cuts(i, kb, last, block_length)
 
     @pl.when(j == 0)
     def _init():
@@ -541,14 +616,16 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     one_pass = jax.lax.Precision.DEFAULT \
         if q_ref.dtype == jnp.bfloat16 else None
 
-    @pl.when(kb <= last)
-    def _compute():
+    def tile(masked):
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), precision=one_pass,
             preferred_element_type=jnp.float32) * scale     # (BQ, BK)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = _band_mask(i * bq + rows, kb * bk + cols, causal, window)
+        mask = None
+        if masked:
+            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            mask = _band_mask(i * bq + rows, kb * bk + cols, causal, window,
+                              block_length, n_k * bk // 2)
         if mask is not None:
             s = jnp.where(mask, s, _NEG)
         m_prev = m_ref[:, 0]
@@ -565,6 +642,12 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             precision=one_pass, preferred_element_type=jnp.float32)
         m_ref[:] = m_new[:, None] + jnp.zeros_like(m_ref)
         l_ref[:] = l_new[:, None] + jnp.zeros_like(l_ref)
+
+    if cuts is None:
+        pl.when(kb <= last)(lambda: tile(True))
+    else:
+        pl.when((kb <= last) & cuts)(lambda: tile(True))
+        pl.when((kb <= last) & ~cuts)(lambda: tile(False))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -584,20 +667,23 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
 
 def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
-                interpret):
+                interpret, block_length=0):
     b, h, s, d = q.shape
     hkv = k.shape[1]
     group = h // hkv
-    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    # under the block-diffusion mask the tiles are those of a half
+    tiled = s // 2 if block_length else s
+    bq, bk = _pick_block(tiled, block_q), _pick_block(tiled, block_k)
     n_q, n_k = s // bq, s // bk
-    steps = _band_steps(n_q, bq, bk, n_k, causal, window)
+    steps = _band_steps(n_q, bq, bk, n_k, causal, window, block_length)
 
     def kv_index(bh, i, j):
-        first, last = _band(i, bq, bk, n_k, causal, window)
-        return bh // group, jnp.minimum(first + j, last), 0
+        kb, last = _schedule(i, j, bq, bk, n_k, causal, window, block_length)
+        return bh // group, jnp.minimum(kb, last), 0
 
     kernel = functools.partial(_gqa_kernel, causal=causal, window=window,
-                               scale=scale, n_k=n_k)
+                               scale=scale, n_k=n_k,
+                               block_length=block_length)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, n_q, steps),
@@ -620,25 +706,28 @@ def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
             pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
         ],
         interpret=interpret,
-        name="gqa_flash_attention",
+        name="gqa_block_diffusion_attention" if block_length
+        else "gqa_flash_attention",
     )(q.reshape(b * h, s, d), k.reshape(b * hkv, s, d),
       v.reshape(b * hkv, s, d))
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
 
-def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block):
-    """The backward over the same band, in jnp: an outer scan over query
-    blocks, and for each ONE loop over the key blocks it sees, so the
-    temporaries are one (B, H, BQ, BK) tile and no key block outside the
-    band is touched. ``lse`` (B, H, S) float32 is the forward kernel's row
-    logsumexp: the probabilities are ``exp(scores - lse)``, so the scores
+def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block,
+                       block_length=0):
+    """The backward over the same schedule, in jnp: an outer scan over
+    query blocks, and for each ONE loop over the key blocks it sees, so the
+    temporaries are one (B, H, BQ, BK) tile and no key block without a live
+    pair is touched (a band's run, or the block-diffusion walk of
+    :func:`_schedule`). ``lse`` (B, H, S) float32 is the forward kernel's
+    row logsumexp: the probabilities are ``exp(scores - lse)``, so the scores
     are computed once here (five matmuls and one ``exp`` a tile) and not a
     second time to find their normalizer. Matmul operands stay in the
     inputs' dtype with float32 accumulation."""
     b, h, s, d = q.shape
     hkv = k.shape[1]
     g = h // hkv
-    blk = _pick_block(s, block)
+    blk = _pick_block(s // 2 if block_length else s, block)
     n = s // blk
     q5 = q.reshape(b, hkv, g, s, d)
     do5 = do.reshape(b, hkv, g, s, d)
@@ -651,7 +740,8 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block):
         sc = jnp.einsum("bkgqd,bkcd->bkgqc", qi, kj,
                         preferred_element_type=jnp.float32) * scale
         mask = _band_mask((i * blk + rows)[:, None],
-                          (j * blk + rows)[None, :], causal, window)
+                          (j * blk + rows)[None, :], causal, window,
+                          block_length, s // 2)
         if mask is not None:
             sc = jnp.where(mask, sc, _NEG)
         p = jnp.exp(sc - lse_i[..., None])
@@ -666,9 +756,17 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block):
         doi = jax.lax.dynamic_slice_in_dim(do5, i * blk, blk, axis=3)
         di = jax.lax.dynamic_slice_in_dim(delta, i * blk, blk, axis=3)
         lse_i = jax.lax.dynamic_slice_in_dim(lse4, i * blk, blk, axis=3)
-        first, last = _band(i, blk, blk, n, causal, window)
+        if block_length:
+            # the walk's steps, each naming its key block
+            lo, hi = 0, _walk_steps(i, n // 2)
+            key_of = lambda t: _schedule(  # noqa: E731
+                i, t, blk, blk, n, causal, window, block_length)[0]
+        else:
+            first, last = _band(i, blk, blk, n, causal, window)
+            lo, hi, key_of = first, last + 1, lambda t: t  # noqa: E731
 
-        def grad_step(j, c):
+        def grad_step(t, c):
+            j = key_of(t)
             dqi, dk, dv = c
             kj, vj = key_block(k, j), key_block(v, j)
             p = probs(qi, kj, i, j, lse_i)
@@ -688,7 +786,7 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block):
             return dqi, dk, dv
 
         dqi, dk, dv = jax.lax.fori_loop(
-            first, last + 1, grad_step,
+            lo, hi, grad_step,
             (jnp.zeros((b, hkv, g, blk, d), jnp.float32), dk, dv))
         return (dk, dv), dqi.astype(q.dtype)
 
@@ -698,45 +796,54 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block):
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _gqa_attention(q, k, v, causal, window, scale, block, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _gqa_attention(q, k, v, causal, window, scale, block, interpret,
+                   block_length):
     return _gqa_pallas(q, k, v, causal, window, scale, block, block,
-                       interpret)[0]
+                       interpret, block_length)[0]
 
 
-def _gqa_fwd(q, k, v, causal, window, scale, block, interpret):
+def _gqa_fwd(q, k, v, causal, window, scale, block, interpret,
+             block_length):
     """The residual contract: ``(q, k, v, out, lse)``. ``out`` and ``lse``
     carry the name a block's checkpoint keeps (``keep_residual``), and the
     NAMED values are both the primal output and the residuals: a name put
     on the output outside this function would mark another value, and the
     backward's ``out`` would be the kernel run again."""
     out, lse = _gqa_pallas(q, k, v, causal, window, scale, block, block,
-                           interpret)
+                           interpret, block_length)
+    if block_length:
+        profiler.count("attention.block_diffusion_layers")
     out, lse = keep_residual(out), keep_residual(lse)
     return out, (q, k, v, out, lse)
 
 
-def _gqa_bwd(causal, window, scale, block, interpret, res, ct):
+def _gqa_bwd(causal, window, scale, block, interpret, block_length, res, ct):
     q, k, v, out, lse = res
     return _gqa_blockwise_bwd(q, k, v, out, lse, ct, causal, window, scale,
-                              block)
+                              block, block_length)
 
 
 _gqa_attention.defvjp(_gqa_fwd, _gqa_bwd)
 
 
 def grouped_query_attention(q, k, v, causal=True, window=0, scale=None,
-                            block=512, force_pallas=False):
+                            block=512, force_pallas=False, block_length=0):
     """Attention of H query heads over Hkv <= H key/value heads: q
     (B, H, S, D), k/v (B, Hkv, S, D), H a multiple of Hkv; ``window`` > 0
-    (causal only) keeps keys i - window < j <= i.
+    (causal only) keeps keys i - window < j <= i; ``block_length`` > 0
+    (causal, no window) is the block-diffusion training mask over a
+    sequence of two halves, a noisy copy of a document and then the clean
+    one, in blocks of ``block_length`` (:func:`_band_mask`): it has to
+    divide the kernel's tile, and the tile a half.
 
-    On a TPU the forward is the flash kernel run over the band alone (key
-    blocks above the diagonal or behind the window are neither fetched nor
-    computed) and the backward the blockwise jnp recurrence over the same
-    band. The forward hands the backward ``(q, k, v, out, lse)``, ``lse``
-    the kernel's row logsumexp (B, H, S) in float32, so a training step
-    computes the scores twice: once in the kernel, once in the backward.
+    On a TPU the forward is the flash kernel run over the mask's live tiles
+    alone (key blocks above the diagonal, behind the window or outside the
+    block-diffusion walk are neither fetched nor computed) and the backward
+    the blockwise jnp recurrence over the same schedule. The forward hands
+    the backward ``(q, k, v, out, lse)``, ``lse`` the kernel's row
+    logsumexp (B, H, S) in float32, so a training step computes the scores
+    twice: once in the kernel, once in the backward.
     ``out`` and ``lse`` are named for the executor's block checkpoint
     (``ops.registry.keep_residual``), which keeps them where it recomputes
     the rest of a layer: ``out`` is one activation in size and the dearest
@@ -753,12 +860,21 @@ def grouped_query_attention(q, k, v, causal=True, window=0, scale=None,
             f"of query heads that is a multiple of the key/value heads")
     if window and not causal:
         raise ValueError("a window is causal: window > 0 needs causal=True")
+    if block_length:
+        tile = _pick_block(s // 2, block)
+        if not causal or window or s % 2 or tile % block_length:
+            raise ValueError(
+                f"block_length {block_length} over {s} positions: the "
+                f"block-diffusion mask needs causal=True, window=0, an "
+                f"even length (two halves) and a block length that divides "
+                f"the kernel's tile of {tile}")
     scale = 1.0 / (d ** 0.5) if scale is None else float(scale)
     on_tpu = jax.default_backend() == "tpu"
     if not on_tpu and not force_pallas:
-        return gqa_attention_reference(q, k, v, causal, window, scale)
+        return gqa_attention_reference(q, k, v, causal, window, scale,
+                                       int(block_length))
     return _gqa_attention(q, k, v, bool(causal), int(window), scale,
-                          int(block), not on_tpu)
+                          int(block), not on_tpu, int(block_length))
 
 
 @register("_contrib_flash_attention", aliases=["flash_attention_op"],

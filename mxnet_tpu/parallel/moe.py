@@ -23,7 +23,8 @@ from .. import profiler
 from ..base import MXNetError
 
 __all__ = ["moe_apply", "moe_dense_apply", "top1_router", "topk_router",
-           "load_balance_loss", "sigmoid_topk_router", "held_experts_apply"]
+           "load_balance_loss", "scored_topk_router", "sigmoid_topk_router",
+           "held_experts_apply"]
 
 
 def top1_router(x, router_w):
@@ -193,19 +194,26 @@ def moe_apply(x, router_w, expert_params, expert_fn: Callable, mesh: Mesh,
 
 # -- the share of a routed layer that one chip holds, with no token dropped ---
 
-def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0,
-                        select_bias=None, renorm_eps: float = 0.0):
-    """Sigmoid scores over all experts in float32, the ``k`` largest, and
-    their weights ``scale * s_e / (sum of the chosen s + renorm_eps)``.
-    With ``select_bias`` (E,) the ``k`` chosen are those with the largest
-    ``s + select_bias``; the weights are still made of the unbiased ``s``,
-    and no gradient reaches the bias. ``router_w`` is (E, d) as
+def scored_topk_router(x, router_w, k: int, scale: float = 1.0,
+                       select_bias=None, renorm_eps: float = 0.0,
+                       score_func: str = "sigmoid"):
+    """Scores over all experts in float32, the ``k`` largest, and their
+    weights ``scale * s_e / (sum of the chosen s + renorm_eps)``. The
+    scores are sigmoids of the logits, or with ``score_func="softmax"``
+    their softmax over ALL the experts (the weights are then a softmax over
+    the chosen logits). With ``select_bias`` (E,) the ``k`` chosen are
+    those with the largest ``s + select_bias``; the weights are still made
+    of the unbiased ``s``, and no gradient reaches the bias. ``router_w`` is (E, d) as
     ``FullyConnected`` keeps it. Returns (weights (T, k) float32, indices
     (T, k) int32)."""
     logits = jax.lax.dot_general(
         x, router_w.astype(x.dtype), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits)
+    if score_func not in ("sigmoid", "softmax"):
+        raise MXNetError(f"unknown score_func {score_func!r}: sigmoid or "
+                         f"softmax")
+    scores = jax.nn.sigmoid(logits) if score_func == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     if k > scores.shape[-1]:
         raise MXNetError(f"top_k={k} exceeds the number of experts "
                          f"{scores.shape[-1]}")
@@ -220,6 +228,10 @@ def sigmoid_topk_router(x, router_w, k: int, scale: float = 1.0,
     if renorm_eps:
         total = total + renorm_eps
     return weights / total, idx
+
+
+# the name it had while the sigmoid was its only scoring
+sigmoid_topk_router = scored_topk_router
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -294,13 +306,15 @@ _weighted_return.defvjp(_weighted_return_fwd, _weighted_return_bwd)
 
 def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
                        top_k, expert_offset=0, routed_scale=1.0,
-                       select_bias=None, renorm_eps=0.0):
+                       select_bias=None, renorm_eps=0.0,
+                       score_func="sigmoid"):
     """What the experts held here add to a routed layer's output.
 
     ``x`` (T, d) tokens; ``router_w`` (E, d) scores ALL ``num_experts``
     experts and every token keeps its ``top_k`` (chosen under
     ``select_bias``, weighted without it, ``renorm_eps`` in the weights'
-    denominator: :func:`sigmoid_topk_router`); the stacked weights
+    denominator, scored by ``score_func``: :func:`scored_topk_router`);
+    the stacked weights
     ``w_gate``/``w_up`` (Eh, d, f) and ``w_down`` (Eh, f, d) are those of
     experts ``expert_offset .. expert_offset + Eh - 1``, each a SwiGLU.
     The token-choices (slots, numbered choice-major: slot ``j * T + t`` is
@@ -323,8 +337,9 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
     t, d = x.shape
     held = w_gate.shape[0]
     with jax.named_scope("route"):
-        weights, chosen = sigmoid_topk_router(
-            x, router_w, top_k, routed_scale, select_bias, renorm_eps)
+        weights, chosen = scored_topk_router(
+            x, router_w, top_k, routed_scale, select_bias, renorm_eps,
+            score_func)
     with jax.named_scope("dispatch"):
         # slot j * T + t is token t's choice j: a token's k slots are k
         # whole (T, d) slabs, never the second-minor axis of a tile

@@ -395,10 +395,14 @@ class SPMDTrainer:
         for node in self._symbol._topo_nodes():
             if node.is_variable or not node.op.aux_counters:
                 continue
-            found = out[node.name] = {}
+            found = {}
             for idx, names in node.op.aux_counters.items():
+                if idx >= len(node.inputs):     # an input the attrs left out
+                    continue
                 values = np.asarray(self.aux[node.inputs[idx][0].name])
                 found.update(zip(names, map(float, values)))
+            if found:
+                out[node.name] = found
         return out
 
     def integrity_stats(self):

@@ -122,27 +122,32 @@ def _masked(b, h, s, d, with_lengths):
     return build
 
 
-def _gqa(heads, s, window, backward, d=128, rows=1):
+def _gqa(heads, s, window, backward, d=128, rows=1, kv=8, block_length=0):
     """The decoder's attention at Laguna-XS.2's widths: ``heads`` query
     heads over 8 key/value heads of 128, one document of ``s`` positions;
-    or at LFM2-8B-A1B's: heads of ``d`` = 64, ``rows`` = 2 documents."""
+    at LFM2-8B-A1B's: heads of ``d`` = 64, ``rows`` = 2 documents; or at
+    SDAR-30B-A3B's: 32 heads over ``kv`` = 4 under the block-diffusion
+    mask, ``s`` the noisy and the clean copy of a document together."""
     scale = 1.0 / d ** 0.5
 
     def fwd(q, k, v):
         return attention._gqa_pallas(q, k, v, True, window, scale, 512, 512,
-                                     interpret=False)
+                                     interpret=False,
+                                     block_length=block_length)
 
     def fwd_bwd(q, k, v, do):
         # what jax.grad of grouped_query_attention runs on the chip: the
         # kernel, then the blockwise jnp backward reading its logsumexp
         out, lse = fwd(q, k, v)
         return out, attention._gqa_blockwise_bwd(q, k, v, out, lse, do, True,
-                                                 window, scale, 512)
+                                                 window, scale, 512,
+                                                 block_length)
 
     def build(struct):
         q = struct((rows, heads, s, d), jnp.bfloat16)
-        kv = struct((rows, 8, s, d), jnp.bfloat16)
-        return (fwd_bwd, (q, kv, kv, q)) if backward else (fwd, (q, kv, kv))
+        keys = struct((rows, kv, s, d), jnp.bfloat16)
+        return (fwd_bwd, (q, keys, keys, q)) if backward \
+            else (fwd, (q, keys, keys))
     return build
 
 
@@ -173,6 +178,10 @@ _CASES = {
     # half a lane tile a head (perfbench lfm2-8b-a1b.train-fed-2x8k)
     "gqa-b2h32kv8s8192d64-full-fwd-bwd": _gqa(32, 8192, 0, True, d=64,
                                               rows=2),
+    # the walk of the mask's live tiles, 288 of 1,024 (perfbench
+    # sdar-30b-a3b.train-fed-bd4-8k: 8,192 noisy + 8,192 clean rows)
+    "gqa-h32kv4s16384d128-block-diffusion4-fwd-bwd": _gqa(
+        32, 16384, 0, True, kv=4, block_length=4),
     "masked-b4h16s2048d64-lengths": _masked(4, 16, 2048, 64, True),
     "masked-b4h16s2048d64-segment-ids": _masked(4, 16, 2048, 64, False),
 }
@@ -190,6 +199,8 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
         compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert ("gqa_block_diffusion_attention" in text) \
+        == ("block-diffusion" in case)
     walk_kernels = getattr(_CASES[case], "walk_kernels", None)
     if walk_kernels is not None:
         assert ("lstm_cell_scan" in text) == walk_kernels
