@@ -12,6 +12,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -457,77 +458,116 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
 
 # -- grouped query heads, causal window (the decoder's attention) -------------
 
-def _band(i, bq, bk, n_k, causal, window):
-    """First and last key block that query block ``i`` can see. ``i`` may
-    be traced; the rest is static. Keys [q - window + 1, q] under a window,
-    [0, q] under plain causality, all of them otherwise."""
-    if not causal:
-        return 0 * i, 0 * i + (n_k - 1)
-    last = (i * bq + bq - 1) // bk
-    if not window:
-        return 0 * i, last
-    return jnp.maximum(i * bq - (window - 1), 0) // bk, last
+_OPENS, _CLOSES, _CUT = 1, 2, 4     # a visit's flags
 
 
-def _band_steps(n_q, bq, bk, n_k, causal, window, block_length=0):
-    """The most key blocks any query block sees: the static length of the
-    kernel's innermost grid axis."""
-    return max(_tile_counts(n_q, bq, bk, n_k, causal, window, block_length))
+def _host_ints(values):
+    """A list of Python numbers as a read-only int32 array (the tables are
+    cached, and every caller gets the one copy). Nothing traced comes to the
+    host here, whatever traced function asks."""
+    x = np.asarray(values, np.int32)  # tpu-lint: disable=host-sync-under-trace
+    x.setflags(write=False)
+    return x
 
 
-def _walk_steps(i, half):
-    """Steps of query tile ``i``'s block-diffusion walk over halves of
-    ``half`` tiles (a Python int or traced): a noisy tile i reads i + 2 key
-    tiles, the clean tile half + i reads i + 1."""
-    return i % half + 1 + (i < half)
+@functools.lru_cache(maxsize=None)
+def _visit_table(n_q, n_k, bq, bk, causal, window, block_length=0):
+    """The schedule of the kernel and of its backward, built on the host from
+    the static shapes: ``(q_tile, k_tile, flags)``, three int32 arrays with
+    one entry a LIVE tile of the mask (a tile with a pair that sees), query
+    tile by query tile and within one by rising key tile, so a query tile's
+    visits are one run. ``flags`` says whether the visit opens its query
+    tile's walk (``_OPENS``), closes it (``_CLOSES``), and whether the mask
+    cuts the tile (``_CUT``: it holds a dead pair too; a tile that is not cut
+    needs no mask).
 
-
-def _tile_counts(n_q, bq, bk, n_k, causal, window, block_length=0):
-    """Key blocks each query block visits, a Python list: the schedule's
-    length a query block (:func:`_schedule`)."""
-    if block_length:
-        return [_walk_steps(i, n_q // 2) for i in range(n_q)]
-    if not causal:
-        return [n_k] * n_q
-    counts = []
+    One rule serves the three masks: inside a tile, seeing depends on the
+    difference of two whole numbers only, each running over the tile's rows
+    or keys. Under a band they are the positions, and ``q - k`` has to lie in
+    [0, window - 1], [0, far) under plain causality, anywhere without it.
+    Under the block-diffusion mask over ``[noisy ; clean]`` halves
+    (:func:`_band_mask`; a tile lies in one half, a block in one tile) they
+    are the block numbers within the half, and ``blk(q) - blk(k)`` has to be 0
+    from noisy to noisy, at least 1 from noisy to clean, at least 0 from clean
+    to clean; clean sees nothing noisy. A tile's pairs take every difference
+    from ``least`` to ``most``: the tile is live where that run meets the
+    allowed one, and cut where it also leaves it."""
+    far = n_q * bq + n_k * bk           # past every difference there is
+    q_tile, k_tile, flags = [], [], []
     for i in range(n_q):
-        last = (i * bq + bq - 1) // bk
-        first = max(i * bq - (window - 1), 0) // bk if window else 0
-        counts.append(last - first + 1)
-    return counts
+        opened = len(flags)
+        for j in range(n_k):
+            q0, k0, unit, lo, hi = i * bq, j * bk, 1, -far, far
+            if block_length:
+                q_noisy, k_noisy = i < n_q // 2, j < n_k // 2
+                if k_noisy and not q_noisy:
+                    continue
+                q0, k0 = i % (n_q // 2) * bq, j % (n_k // 2) * bk
+                unit = block_length
+                lo, hi = (0, 0) if k_noisy else (1 if q_noisy else 0, far)
+            elif causal:
+                lo, hi = 0, window - 1 if window else far
+            least = q0 // unit - (k0 + bk - 1) // unit
+            most = (q0 + bq - 1) // unit - k0 // unit
+            if most < lo or least > hi:
+                continue
+            q_tile.append(i)
+            k_tile.append(j)
+            flags.append(_CUT if least < lo or most > hi else 0)
+        if len(flags) == opened:
+            raise ValueError(f"query tile {i} of {n_q} sees no key")
+        flags[opened] |= _OPENS
+        flags[-1] |= _CLOSES
+    return _host_ints(q_tile), _host_ints(k_tile), _host_ints(flags)
 
 
-def _schedule(i, j, bq, bk, n_k, causal, window, block_length=0):
-    """``(kb, last)``: the key block that step ``j`` of query block ``i``
-    reads, and the last one of its walk. The step is live while ``kb <=
-    last``; past that the index map hands ``last`` again, so the step moves
-    no data. ``i`` and ``j`` may be traced; the rest is static.
+def _run(i, blk, n, causal, window, block_length=0, xp=jnp):
+    """``(lo, hi, key_of)``: query tile ``i``'s run of :func:`_visit_table`
+    in closed form, for square tiles of ``blk`` over ``n`` of them: its
+    visits are the key tiles ``key_of(t)`` for t in [lo, hi). ``i`` may be
+    traced (``xp`` = jnp) or a number (``xp`` = np: how
+    :func:`_checked_run` holds every tile's run against the table). The
+    backward's loops take their bounds and key tiles from here and not from
+    the table's arrays, because XLA compiles arithmetic on the loop counters
+    into a loop that adds each key tile's dk and dv in place, where bounds
+    read from an array cost the whole step 20% of the backward's time on
+    LFM2 and Laguna (PERF.md, PR 34).
 
-    A band (causal, window, or every key) is one run, ``first(i) + j``.
-    The block-diffusion mask over ``[noisy ; clean]`` halves of n tiles
-    each (``block_length`` > 0, ``bq == bk``) names a block out of line:
-    noisy query tile i reads its own tile i (the block diagonal) and then
-    the clean tiles n .. n + i (blocks before its own: i + 2 steps); clean
-    query tile n + i reads n .. n + i. No tile without a live pair is
-    visited."""
-    if not block_length:
-        first, last = _band(i, bq, bk, n_k, causal, window)
-        return first + j, last
-    n = n_k // 2
-    noisy = i < n
-    last = jnp.where(noisy, i + n, i)
-    kb = jnp.where(noisy, jnp.where(j == 0, i, n + j - 1), n + j)
-    return kb, last
+    A band is one run of rising key tiles, the loop variable the key tile
+    itself. Under the block-diffusion mask over two halves of n / 2 tiles a
+    noisy tile i reads its own tile and then the clean tiles from n / 2 up,
+    as far as its own clean tile, or the one before where a block fills the
+    tile; clean tile n / 2 + i reads n / 2 .. n / 2 + i."""
+    if block_length:
+        half = n // 2
+        noisy = i < half
+        steps = xp.where(noisy, 1 + i + (block_length < blk), i - half + 1)
+
+        def key_of(t):
+            return xp.where(noisy, xp.where(t == 0, i, half + t - 1),
+                            half + t)
+        return 0 * i, steps, key_of
+    if not causal:
+        return 0 * i, 0 * i + n, lambda t: t
+    first = xp.maximum(i * blk - (window - 1), 0) // blk if window else 0 * i
+    return first, i + 1, lambda t: t
 
 
-def _cuts(i, kb, last, block_length):
-    """Does the mask cut the tile (query block ``i``, key block ``kb``)?
-    None where every visited tile of the schedule is masked (the band
-    paths mask every tile, as they always have); under the block-diffusion
-    mask the two diagonal tiles of a walk, the inner clean tiles not."""
-    if not block_length:
-        return None
-    return (kb == i) | (kb == last)
+def _checked_run(blk, n, causal, window, block_length=0):
+    """Hold :func:`_run` to the table, tile by tile, on the host (a few
+    hundred numbers a trace): the mask has one description, and the closed
+    form is a view of it or an error."""
+    q_tile, k_tile, _ = _visit_table(n, n, blk, blk, causal, window,
+                                     block_length)
+    for i in range(n):
+        lo, hi, key_of = _run(i, blk, n, causal, window, block_length, np)
+        walked = [int(key_of(t)) for t in range(int(lo), int(hi))]
+        listed = k_tile[q_tile == i].tolist()
+        if walked != listed:
+            raise NotImplementedError(
+                f"query tile {i} of {n} (tiles of {blk}, window {window}, "
+                f"blocks of {block_length}): the backward's closed form "
+                f"walks key tiles {walked}, the table {listed}")
 
 
 def _band_mask(qpos, kpos, causal, window, block_length=0, half=0):
@@ -550,9 +590,18 @@ def _band_mask(qpos, kpos, causal, window, block_length=0, half=0):
             return jax.lax.div(pos, jnp.asarray(block_length, pos.dtype))
 
         q_blk, k_blk = blk(qpos, q_noisy), blk(kpos, k_noisy)
-        return (q_noisy & k_noisy & (q_blk == k_blk)) \
-            | (q_noisy & ~k_noisy & (q_blk > k_blk)) \
-            | (~q_noisy & ~k_noisy & (q_blk >= k_blk))
+        # the three lines as two comparisons of a query's number with a
+        # key's, so that a column of queries against a row of keys does its
+        # arithmetic on the column and the row and three operations on the
+        # tile. Noisy to noisy: equal tags, a clean query's and a clean
+        # key's tags being two numbers no block has. To a clean key: the
+        # query's block, less one where it is noisy (> for >=), against the
+        # key's, a noisy key's being past every block
+        far = jnp.iinfo(k_blk.dtype).max
+        same = jnp.where(q_noisy, q_blk, -1) == jnp.where(k_noisy, k_blk, -2)
+        before = q_blk - q_noisy.astype(q_blk.dtype) \
+            >= jnp.where(k_noisy, far, k_blk)
+        return same | before
     if not causal:
         return None
     mask = kpos <= qpos
@@ -586,30 +635,52 @@ def gqa_attention_reference(q, k, v, causal=True, window=0, scale=None,
     return out.reshape(b, h, s, d).astype(q.dtype)
 
 
-def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, causal, window, scale, n_k, block_length=0):
-    """The flash recurrence over the mask's live tiles only. Grid (B*H,
-    n_q, steps): step ``j`` of query block ``i`` reads the key block its
-    schedule names (:func:`_schedule`; the index map clamps it to the
-    walk's last, so a step past the walk moves no data and computes
-    nothing). A band masks every tile it visits; the block-diffusion
-    schedule masks a tile only where the mask cuts it (:func:`_cuts`).
-    Operands go to the MXU in their own dtype, accumulation is float32.
+def _lanes(x, n):
+    """``x`` (rows, 128) with every lane of a row equal, over ``n`` lanes."""
+    copies = -(-n // x.shape[1])
+    x = jnp.tile(x, (1, copies)) if copies > 1 else x
+    return x if x.shape[1] == n else x[:, :n]
+
+
+def _gqa_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref, *, causal, window, scale, half,
+                block_length, kinds):
+    """The flash recurrence, one grid step a live tile. Grid (B*H, visits):
+    step ``t`` of a head reads, from the scalar-prefetched
+    :func:`_visit_table`, its query tile ``qt_ref[t]``, its key tile
+    ``kt_ref[t]`` (the index maps fetch by the same tables, so no step is
+    without work and no tile without a live pair is fetched) and its flags:
+    the step that opens a query tile's walk resets the running state, the
+    one that closes it writes the output and the logsumexp, and only a step
+    whose tile the mask cuts builds the mask (``kinds``: which of the two
+    bodies, masked and not, the table asks for at all).
+
+    A step holds the (BQ, d) query tile and a (BK, d) key/value tile and
+    computes the tile whole (on the chip a key tile walked in slices of 256
+    or 128 keys lost 20-50%: PERF.md, PR 34): scores (BQ, BK) in float32 from
+    operands in their own dtype, the running max ``m`` and sum ``l`` as
+    (BQ, 128) values with a row's lanes all equal from load to store
+    (reductions keep their dimension and are broadcast over lanes: no value
+    with the rows along lanes anywhere in the body, each of which cost a
+    relayout a tile), the unnormalised output (BQ, d) in float32.
+    A dead pair's score is ``_NEG``; a row that has met dead pairs only holds
+    ``m == _NEG`` and a finite sum of ones, which the first live pair's
+    ``alpha = exp(_NEG - m_new) == 0`` wipes, and every row of these masks
+    sees a key (itself, or its own block).
     Beside the output it writes each row's logsumexp of the scaled scores
     over the keys it sees, ``m + log(l)`` in float32: what the backward
     needs to rebuild the probabilities without a pass of its own.
     ``lse_ref`` is one head's (n_q, 1, BQ), resident while the head's query
-    blocks run; block ``i`` fills row ``i`` of it."""
-    i, j = pl.program_id(1), pl.program_id(2)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
-    kb, last = _schedule(i, j, bq, bk, n_k, causal, window, block_length)
-    cuts = _cuts(i, kb, last, block_length)
+    tiles run; tile ``i`` fills row ``i`` of it."""
+    t = pl.program_id(1)
+    i, kb, flags = qt_ref[t], kt_ref[t], fl_ref[t]
+    bq, bk, d = q_ref.shape[1], k_ref.shape[1], q_ref.shape[2]
 
-    @pl.when(j == 0)
+    @pl.when(flags & _OPENS != 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # bfloat16 operands are exact in one MXU pass; an ambient "highest"
     # would ask Mosaic for a float32 matmul of them, which it refuses
@@ -620,43 +691,37 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), precision=one_pass,
             preferred_element_type=jnp.float32) * scale     # (BQ, BK)
-        mask = None
         if masked:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = _band_mask(i * bq + rows, kb * bk + cols, causal, window,
-                              block_length, n_k * bk // 2)
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        if mask is not None:
-            # a row whose keys in this block are all outside its window
-            # has s == m_new == _NEG, where exp gives 1
-            p = jnp.where(mask, p, 0.0)
+            # a column of rows against a row of keys: the mask's arithmetic
+            # on positions runs over BQ + BK numbers, its last comparisons
+            # alone over the tile
+            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            s = jnp.where(
+                _band_mask(i * bq + rows, kb * bk + cols, causal, window,
+                           block_length, half), s, _NEG)
+        m_prev = m_ref[...]                                 # (BQ, 128)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, bk))
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             precision=one_pass, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new[:, None] + jnp.zeros_like(m_ref)
-        l_ref[:] = l_new[:, None] + jnp.zeros_like(l_ref)
 
-    if cuts is None:
-        pl.when(kb <= last)(lambda: tile(True))
-    else:
-        pl.when((kb <= last) & cuts)(lambda: tile(True))
-        pl.when((kb <= last) & ~cuts)(lambda: tile(False))
+    for masked in kinds:
+        pl.when((flags & _CUT != 0) == masked)(
+            functools.partial(tile, masked))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(flags & _CLOSES != 0)
     def _finish():
-        l = jnp.maximum(l_ref[:], 1e-30)        # (BQ, 128), lanes equal
-        o_ref[0] = (acc_ref[:] / l[:, :1]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)      # (BQ, 128), lanes equal
+        o_ref[0] = (acc_ref[...] / _lanes(l, d)).astype(o_ref.dtype)
         # the rows lie along sublanes here (every lane of a row equal) and
         # along lanes in the output: 128 rows at a time, keep the diagonal
         # and add the sublanes up (exact: the other terms are zeros)
-        lse = m_ref[:] + jnp.log(l)
+        lse = m_ref[...] + jnp.log(l)
         n = min(bq, 128)
         diagonal = jax.lax.broadcasted_iota(jnp.int32, (n, 128), 0) \
             == jax.lax.broadcasted_iota(jnp.int32, (n, 128), 1)
@@ -666,60 +731,75 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                 keepdims=True)[:, :n]
 
 
+def _gqa_tiles(s, block_q, block_k, block_length):
+    """``(bq, bk)``: under the block-diffusion mask the tiles are those of
+    a half."""
+    tiled = s // 2 if block_length else s
+    return _pick_block(tiled, block_q), _pick_block(tiled, block_k)
+
+
 def _gqa_pallas(q, k, v, causal, window, scale, block_q, block_k,
                 interpret, block_length=0):
     b, h, s, d = q.shape
     hkv = k.shape[1]
     group = h // hkv
-    # under the block-diffusion mask the tiles are those of a half
-    tiled = s // 2 if block_length else s
-    bq, bk = _pick_block(tiled, block_q), _pick_block(tiled, block_k)
+    bq, bk = _gqa_tiles(s, block_q, block_k, block_length)
     n_q, n_k = s // bq, s // bk
-    steps = _band_steps(n_q, bq, bk, n_k, causal, window, block_length)
+    table = _visit_table(n_q, n_k, bq, bk, causal, window, block_length)
+    cut = table[2] & _CUT != 0
+    kernel = functools.partial(
+        _gqa_kernel, causal=causal, window=window, scale=scale, half=s // 2,
+        block_length=block_length,
+        kinds=tuple(m for m in (False, True) if (cut == m).any()))
 
-    def kv_index(bh, i, j):
-        kb, last = _schedule(i, j, bq, bk, n_k, causal, window, block_length)
-        return bh // group, jnp.minimum(kb, last), 0
+    def q_index(bh, t, qt, kt, fl):
+        return bh, qt[t], 0
 
-    kernel = functools.partial(_gqa_kernel, causal=causal, window=window,
-                               scale=scale, n_k=n_k,
-                               block_length=block_length)
+    def kv_index(bh, t, qt, kt, fl):
+        return bh // group, kt[t], 0
+
+    grid = (b * h, len(cut))
+    profiler.count("attention.kernel_grid_steps", grid[0] * grid[1])
+    profiler.count("attention.kernel_live_tiles", b * h * len(cut))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, n_q, steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), kv_index),
-            pl.BlockSpec((1, bk, d), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, n_q, 1, bq), lambda bh, i, j: (bh, 0, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_index),
+                pl.BlockSpec((1, bk, d), kv_index),
+                pl.BlockSpec((1, bk, d), kv_index),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), q_index),
+                pl.BlockSpec((1, n_q, 1, bq),
+                             lambda bh, t, qt, kt, fl: (bh, 0, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),  # running max m
+                pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
+                pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, n_q, 1, bq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),  # running max m
-            pltpu.VMEM((bq, 128), jnp.float32),  # running normalizer l
-            pltpu.VMEM((bq, d), jnp.float32),    # unnormalized output
-        ],
         interpret=interpret,
         name="gqa_block_diffusion_attention" if block_length
         else "gqa_flash_attention",
-    )(q.reshape(b * h, s, d), k.reshape(b * hkv, s, d),
-      v.reshape(b * hkv, s, d))
+    )(*(jnp.asarray(x) for x in table), q.reshape(b * h, s, d),
+      k.reshape(b * hkv, s, d), v.reshape(b * hkv, s, d))
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
 
 def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block,
                        block_length=0):
     """The backward over the same schedule, in jnp: an outer scan over
-    query blocks, and for each ONE loop over the key blocks it sees, so the
-    temporaries are one (B, H, BQ, BK) tile and no key block without a live
-    pair is touched (a band's run, or the block-diffusion walk of
-    :func:`_schedule`). ``lse`` (B, H, S) float32 is the forward kernel's
+    query blocks, and for each ONE loop over its run of :func:`_visit_table`
+    (in the closed form of :func:`_run`, held to the table at trace time), so
+    the temporaries are one (B, H, BQ, BK) tile and no key block without a
+    live pair is touched. ``lse`` (B, H, S) float32 is the forward kernel's
     row logsumexp: the probabilities are ``exp(scores - lse)``, so the scores
     are computed once here (five matmuls and one ``exp`` a tile) and not a
     second time to find their normalizer. Matmul operands stay in the
@@ -727,7 +807,7 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block,
     b, h, s, d = q.shape
     hkv = k.shape[1]
     g = h // hkv
-    blk = _pick_block(s // 2 if block_length else s, block)
+    blk = _gqa_tiles(s, block, block, block_length)[0]
     n = s // blk
     q5 = q.reshape(b, hkv, g, s, d)
     do5 = do.reshape(b, hkv, g, s, d)
@@ -735,6 +815,7 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block,
     delta = jnp.sum(do5.astype(jnp.float32)
                     * out.reshape(b, hkv, g, s, d).astype(jnp.float32), -1)
     rows = jnp.arange(blk)
+    _checked_run(blk, n, causal, window, block_length)
 
     def probs(qi, kj, i, j, lse_i):
         sc = jnp.einsum("bkgqd,bkcd->bkgqc", qi, kj,
@@ -756,14 +837,8 @@ def _gqa_blockwise_bwd(q, k, v, out, lse, do, causal, window, scale, block,
         doi = jax.lax.dynamic_slice_in_dim(do5, i * blk, blk, axis=3)
         di = jax.lax.dynamic_slice_in_dim(delta, i * blk, blk, axis=3)
         lse_i = jax.lax.dynamic_slice_in_dim(lse4, i * blk, blk, axis=3)
-        if block_length:
-            # the walk's steps, each naming its key block
-            lo, hi = 0, _walk_steps(i, n // 2)
-            key_of = lambda t: _schedule(  # noqa: E731
-                i, t, blk, blk, n, causal, window, block_length)[0]
-        else:
-            first, last = _band(i, blk, blk, n, causal, window)
-            lo, hi, key_of = first, last + 1, lambda t: t  # noqa: E731
+
+        lo, hi, key_of = _run(i, blk, n, causal, window, block_length)
 
         def grad_step(t, c):
             j = key_of(t)
@@ -838,10 +913,15 @@ def grouped_query_attention(q, k, v, causal=True, window=0, scale=None,
     divide the kernel's tile, and the tile a half.
 
     On a TPU the forward is the flash kernel run over the mask's live tiles
-    alone (key blocks above the diagonal, behind the window or outside the
-    block-diffusion walk are neither fetched nor computed) and the backward
-    the blockwise jnp recurrence over the same schedule. The forward hands
-    the backward ``(q, k, v, out, lse)``, ``lse`` the kernel's row
+    alone: its grid is (B*H, visits), one step a tile that holds a pair that
+    sees, by a table built on the host from the shapes and prefetched as
+    scalars (:func:`_visit_table`: 136 visits a head for 16 causal tiles
+    where the square has 256, 31 under a window of one tile, 288 for the
+    block-diffusion walk over 2 x 16), so key blocks above the diagonal,
+    behind the window or outside the walk are neither fetched nor computed
+    nor stepped over, and the mask is built only in the tiles it cuts. The
+    backward is the blockwise jnp recurrence over the same table. The forward
+    hands the backward ``(q, k, v, out, lse)``, ``lse`` the kernel's row
     logsumexp (B, H, S) in float32, so a training step computes the scores
     twice: once in the kernel, once in the backward.
     ``out`` and ``lse`` are named for the executor's block checkpoint

@@ -107,44 +107,111 @@ def test_mask_against_its_three_line_definition(length, block, model):
 @pytest.mark.parametrize("tiles,visits", [(1, 3), (2, 8), (4, 24),
                                           (16, 288)])
 def test_the_walk_visits_the_masks_live_tiles_and_no_other(tiles, visits):
-    """``tiles`` tiles of 512 a half, blocks of 4: every (query tile, key
-    tile) with a live pair is visited once, none without one; 288 key tiles
-    for 32 query tiles at L = 8,192 where a causal walk of 2 L makes 528 and
-    a dense one 1,024. The static grid is the longest walk, n + 1; a step
-    past a tile's walk is not live and is handed the walk's last block."""
+    """``tiles`` tiles of 512 a half, blocks of 4: the schedule's table holds
+    every (query tile, key tile) with a live pair once and none without one;
+    288 visits for 32 query tiles at L = 8,192 where a causal walk of 2 L
+    makes 528 and a dense one 1,024. Noisy tile i reads its own tile and
+    then the clean tiles n .. n + i, clean tile n + i reads n .. n + i; the
+    mask cuts a noisy tile's two diagonal tiles and a clean tile's one."""
     tile, block, n = 512, 4, tiles
-    counts = attention._tile_counts(2 * n, tile, tile, 2 * n, True, 0, block)
-    assert sum(counts) == visits and len(counts) == 2 * n
-    assert attention._band_steps(2 * n, tile, tile, 2 * n, True, 0,
-                                 block) == n + 1
-    live = _mask_by_a_double_loop(n * 8, 4).reshape(2 * n, 8, 2 * n, 8) \
-        .any(axis=(1, 3)) if n <= 4 else None     # blocks of 4 in tiles of 8
-    walked = set()
-    for i in range(2 * n):
-        for j in range(n + 1):
-            kb, last = attention._schedule(jnp.int32(i), jnp.int32(j), tile,
-                                           tile, 2 * n, True, 0, block)
-            if j < counts[i]:
-                assert int(kb) <= int(last)
-                walked.add((i, int(kb)))
-            else:
-                assert int(kb) > int(last)
-    assert len(walked) == visits
-    if live is not None:
-        assert walked == {(i, k) for i in range(2 * n)
-                          for k in range(2 * n) if live[i, k]}
-    causal = attention._tile_counts(2 * n, tile, tile, 2 * n, True, 0)
-    assert sum(causal) == n * (2 * n + 1)           # 528 at n = 16
+    q_tile, k_tile, flags = attention._visit_table(
+        2 * n, 2 * n, tile, tile, True, 0, block)
+    assert len(q_tile) == visits
+    walks = {i: k_tile[q_tile == i].tolist() for i in range(2 * n)}
+    assert walks == {**{i: [i] + list(range(n, n + i + 1)) for i in range(n)},
+                     **{n + i: list(range(n, n + i + 1)) for i in range(n)}}
+    cut = flags & attention._CUT != 0
+    assert (cut == ((k_tile == q_tile) | (k_tile == q_tile + n))).all()
+    assert int(cut.sum()) == 3 * n
+    if n <= 4:
+        # the same walk in tiles of 8, against the mask written out
+        assert _table_against_the_mask(n, 8, 4) == visits
+    causal = attention._visit_table(2 * n, 2 * n, tile, tile, True, 0)
+    assert len(causal[0]) == n * (2 * n + 1)           # 528 at n = 16
 
 
-def test_the_band_schedule_is_the_band():
-    for window in (0, 512):
-        first, last = attention._band(jnp.int32(5), 512, 512, 16, True,
-                                      window)
-        kb, end = attention._schedule(jnp.int32(5), jnp.int32(1), 512, 512,
-                                      16, True, window)
-        assert int(kb) == int(first) + 1 and int(end) == int(last)
-    assert attention._cuts(3, 3, 5, 0) is None
+def _table_against_the_mask(n, tile, block):
+    """The table of two halves of ``n`` tiles against the mask written out
+    pair by pair: the visits are its live tiles, the cut flag is true exactly
+    on tiles with both a live and a dead pair. Returns the visits."""
+    q_tile, k_tile, flags = attention._visit_table(
+        2 * n, 2 * n, tile, tile, True, 0, block)
+    tiles = _mask_by_a_double_loop(n * tile, block).reshape(
+        2 * n, tile, 2 * n, tile)
+    assert sorted(zip(q_tile.tolist(), k_tile.tolist())) == [
+        tuple(x) for x in np.argwhere(tiles.any(axis=(1, 3))).tolist()]
+    assert ((flags & attention._CUT != 0)
+            == ~tiles.all(axis=(1, 3))[q_tile, k_tile]).all()
+    first = np.r_[True, q_tile[1:] != q_tile[:-1]]
+    assert ((flags & attention._OPENS != 0) == first).all()
+    assert ((flags & attention._CLOSES != 0) == np.r_[first[1:], True]).all()
+    return len(q_tile)
+
+
+@pytest.mark.parametrize("tile,block,visits", [(16, 16, 6), (16, 8, 8),
+                                               (8, 1, 24), (12, 4, 8)])
+def test_a_block_that_fills_its_tile_is_not_cut(tile, block, visits):
+    """Blocks as large as the tile: the noisy diagonal tile is whole and a
+    noisy tile's own clean tile is dead, so it is neither cut nor visited;
+    smaller blocks cut both."""
+    n = 2 if tile != 8 else 4
+    assert _table_against_the_mask(n, tile, block) == visits
+    flags = attention._visit_table(2 * n, 2 * n, tile, tile, True, 0,
+                                   block)[2]
+    assert bool((flags & attention._CUT).any()) == (block < tile)
+
+
+@pytest.mark.parametrize("tile,n,window,block", [
+    (16, 8, 0, 0), (16, 8, 16, 0), (16, 8, 40, 0), (16, 8, 5, 0),
+    (16, 8, 0, 4), (16, 8, 0, 16), (16, 2, 0, 4), (512, 32, 0, 4)])
+def test_the_backwards_closed_form_is_a_view_of_the_table(tile, n, window,
+                                                          block):
+    """The backward's loops take bounds and key tiles from arithmetic on the
+    loop counters (``_run``: what XLA compiles into updates in place), and
+    that arithmetic is held to the table tile by tile, with numbers and
+    traced alike."""
+    q_tile, k_tile, _ = attention._visit_table(n, n, tile, tile, True, window,
+                                               block)
+    attention._checked_run(tile, n, True, window, block)
+    for i in {0, 1, n // 2 - 1, n // 2, n - 1}:
+        lo, hi, key_of = jax.jit(
+            lambda i: (lambda lo, hi, key_of: (lo, hi, key_of(
+                jnp.arange(n + 1))))(*attention._run(
+                    i, tile, n, True, window, block)))(jnp.int32(i))
+        assert np.asarray(key_of)[int(lo):int(hi)].tolist() \
+            == k_tile[q_tile == i].tolist()
+
+
+def test_forward_and_backward_walk_the_one_table(monkeypatch):
+    """The kernel's grid reads ``_visit_table`` and the backward's closed
+    form is checked against it: with query tile 1's last visit taken out of
+    the table the kernel's output changes in exactly the rows that lost
+    keys, and the backward refuses to walk what the table no longer says."""
+    q, k, v, ct = _qkv(32, 4, 1)       # two halves of two tiles of 16
+    real = attention._visit_table
+
+    def shortened(*a):
+        q_tile, k_tile, flags = (x.copy() for x in real(*a))
+        drop = np.flatnonzero(q_tile == 1)[-1]
+        flags[drop - 1] |= attention._CLOSES
+        return tuple(np.delete(x, drop) for x in (q_tile, k_tile, flags))
+
+    def run():
+        return jax.vjp(lambda *a: attention.grouped_query_attention(
+            *a, block=16, force_pallas=True, block_length=4), q, k, v)
+
+    want, vjp = run()
+    vjp(ct)
+    monkeypatch.setattr(attention, "_visit_table", shortened)
+    got, vjp = run()
+    # query tile 1 (rows 16..31, noisy) lost its clean tile 3 (keys 48..63),
+    # of which its first block of 4 saw nothing
+    rows = np.zeros(64, bool)
+    rows[20:32] = True
+    moved = np.abs(np.asarray(got - want)).max(axis=(0, 1, 3)) > 1e-6
+    assert (moved == rows).all()
+    with pytest.raises(NotImplementedError, match="query tile 1 of 4"):
+        vjp(ct)
 
 
 def _qkv(length, heads, kv, d=16, seed=0):

@@ -195,6 +195,23 @@ def test_band_kernel_at_head_size_64_in_the_interpreter(heads, kv):
             lambda *a: gqa_attention_reference(*a, True, 0), q, k, v)
 
 
+def test_the_cells_attention_layer_steps_over_its_live_tiles_alone():
+    """Two documents of 8,192 tokens, 32 heads of 64 over 8: the kernel's
+    grid is 64 heads x the 136 live tiles of a causal walk of 16 (256 steps
+    a head before PR 34), 16 of them cut by the mask."""
+    from mxnet_tpu.ops.pallas.attention import (_CUT, _gqa_pallas,
+                                                _visit_table)
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16)
+    with jax.enable_x64(False):
+        traced = jax.make_jaxpr(lambda *a: _gqa_pallas(
+            *a, True, 0, 0.125, 512, 512, False))(q, k, k)
+    call, = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert tuple(call.params["grid_mapping"].grid) == (64, 136)
+    flags = _visit_table(16, 16, 512, 512, True, 0)[2]
+    assert int((flags & _CUT != 0).sum()) == 16
+
+
 # -- the routed layer: a bias in the selection ------------------------------------
 
 def _moe_inputs(t=48, d=16, e=32, f=8, fs=8):

@@ -262,16 +262,136 @@ def test_a_checkpoint_with_the_executors_policy_runs_the_kernel_once(
                     {"remat.kept_values": 0, "remat.kept_bytes": 0})
 
 
-def test_band_skips_the_blocks_a_query_block_cannot_see():
-    from mxnet_tpu.ops.pallas.attention import _band, _band_steps
-    # 8192 positions in blocks of 512: a window of 512 sees two blocks
-    assert _band_steps(16, 512, 512, 16, True, 512) == 2
-    assert _band_steps(16, 512, 512, 16, True, 0) == 16
-    assert _band_steps(16, 512, 512, 16, False, 0) == 16
-    assert [int(x) for x in _band(jnp.int32(5), 512, 512, 16, True, 512)] \
-        == [4, 5]
-    assert [int(x) for x in _band(jnp.int32(5), 512, 512, 16, True, 0)] \
-        == [0, 5]
+def _tiles_by_the_mask(n, tile, causal, window, block_length=0):
+    """``(live, whole)`` (n, n) bool from the mask written out pair by pair
+    (``_band_mask`` over every position): a tile with a pair that sees, and
+    a tile whose every pair sees."""
+    from mxnet_tpu.ops.pallas.attention import _band_mask
+    pos = jnp.arange(n * tile)
+    mask = _band_mask(pos[:, None], pos[None, :], causal, window,
+                      block_length, n * tile // 2)
+    mask = np.ones((n * tile,) * 2, bool) if mask is None \
+        else np.asarray(mask)
+    tiles = mask.reshape(n, tile, n, tile)
+    return tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+
+
+def _table_against_the_mask(n, tile, causal, window, block_length=0):
+    """The host-built schedule against the mask itself: one visit a live
+    tile and no other, a query tile's visits one run by rising key tile with
+    its ends flagged, the cut flag exactly on the tiles that hold a live and
+    a dead pair. Returns the number of visits."""
+    from mxnet_tpu.ops.pallas.attention import (_CLOSES, _CUT, _OPENS,
+                                                _visit_table)
+    q_tile, k_tile, flags = _visit_table(n, n, tile, tile, causal, window,
+                                         block_length)
+    live, whole = _tiles_by_the_mask(n, tile, causal, window, block_length)
+    assert sorted(zip(q_tile.tolist(), k_tile.tolist())) \
+        == [tuple(x) for x in np.argwhere(live).tolist()]
+    assert len(set(zip(q_tile.tolist(), k_tile.tolist()))) == len(q_tile)
+    order = np.lexsort((k_tile, q_tile))
+    assert (order == np.arange(len(order))).all()
+    first = np.r_[True, q_tile[1:] != q_tile[:-1]]
+    last = np.r_[q_tile[1:] != q_tile[:-1], True]
+    assert ((flags & _OPENS != 0) == first).all()
+    assert ((flags & _CLOSES != 0) == last).all()
+    assert ((flags & _CUT != 0) == ~whole[q_tile, k_tile]).all()
+    return len(q_tile)
+
+
+# (causal, window, tile, visits of 16 tiles): the decoder cells' 8,192
+# positions in tiles of 512, and small tiles the window lies inside, on and
+# across
+@pytest.mark.parametrize("causal,window,tile,visits", [
+    (True, 0, 512, 136), (True, 512, 512, 31), (False, 0, 512, 256),
+    (True, 0, 8, 136), (True, 8, 8, 31), (True, 3, 8, 31), (True, 20, 8, 58),
+    (True, 10, 8, 45)])
+def test_the_table_visits_a_bands_live_tiles_and_no_other(causal, window,
+                                                          tile, visits):
+    from mxnet_tpu.ops.pallas.attention import _CUT, _visit_table
+    if tile <= 8:       # the mask written out: 128 x 128 pairs
+        assert _table_against_the_mask(16, tile, causal, window) == visits
+    q_tile, k_tile, flags = _visit_table(16, 16, tile, tile, causal, window)
+    assert len(q_tile) == visits
+    cut = int((flags & _CUT != 0).sum())
+    if not causal:
+        assert cut == 0
+    elif not window:
+        # only the diagonal tile of a causal walk needs its mask
+        assert cut == 16 and (q_tile == k_tile)[flags & _CUT != 0].all()
+    elif window == tile:
+        assert cut == visits        # both of a window's tiles hold an edge
+
+
+def test_the_table_takes_tiles_that_are_not_square():
+    """A query tile of 4 over key tiles of 8, window 6: against the mask
+    written out."""
+    from mxnet_tpu.ops.pallas.attention import _CUT, _band_mask, _visit_table
+    q_tile, k_tile, flags = _visit_table(8, 4, 4, 8, True, 6)
+    pos = jnp.arange(32)
+    tiles = np.asarray(_band_mask(pos[:, None], pos[None, :], True, 6)) \
+        .reshape(8, 4, 4, 8)
+    assert sorted(zip(q_tile.tolist(), k_tile.tolist())) \
+        == [tuple(x) for x in np.argwhere(tiles.any(axis=(1, 3))).tolist()]
+    assert ((flags & _CUT != 0)
+            == ~tiles.all(axis=(1, 3))[q_tile, k_tile]).all()
+
+
+def _the_kernels_grid(q, k, v, **mask):
+    """Grid of the ``pallas_call`` the forward builds."""
+    from mxnet_tpu.ops.pallas.attention import _gqa_pallas
+    with jax.enable_x64(False):
+        traced = jax.make_jaxpr(lambda *a: _gqa_pallas(
+            *a, mask.get("causal", True), mask.get("window", 0), 1.0,
+            mask["block"], mask["block"], True,
+            mask.get("block_length", 0)))(q, k, v)
+    call, = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return tuple(call.params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("mask,visits", [
+    ({"window": 0}, 136), ({"window": 512}, 31), ({"causal": False}, 256),
+    ({"block_length": 4}, 80)])
+def test_the_kernels_grid_has_no_dead_step(mask, visits):
+    """16 tiles of 512 under each mask, 2 x 12 heads: the grid is (heads,
+    the table's visits), one step a live tile, and the trace counts both."""
+    struct = jax.ShapeDtypeStruct((2, 12, 8192, 64), jnp.bfloat16)
+    keys = jax.ShapeDtypeStruct((2, 2, 8192, 64), jnp.bfloat16)
+    before = mx.profiler.counters()
+    assert _the_kernels_grid(struct, keys, keys, block=512, **mask) \
+        == (24, visits)
+    now = mx.profiler.counters()
+    assert [now[n] - before.get(n, 0) for n in (
+        "attention.kernel_grid_steps", "attention.kernel_live_tiles")] \
+        == [24 * visits] * 2
+
+
+# (head size, group, mask): the three cells' heads through the interpreter,
+# 128 positions (two halves of 64 under the block-diffusion mask)
+@pytest.mark.parametrize("d,group,mask", [
+    (128, 8, {"block_length": 4}), (128, 6, {"window": 0}),
+    (128, 8, {"window": 32}), (128, 8, {"window": 40}),
+    (64, 4, {"window": 0}), (64, 4, {"window": 32}),
+    (64, 4, {"block_length": 4}), (128, 6, {"block_length": 32})])
+@pytest.mark.parametrize("tiles", [(32, 32), (16, 32)])
+def test_kernel_and_logsumexp_at_the_cells_head_sizes(d, group, mask, tiles):
+    """Output and row logsumexp against the plain reference, in square tiles
+    and with a query tile half a key tile."""
+    from mxnet_tpu.ops.pallas import attention
+    q, k, v = _band_inputs(group, kv=1, d=d, s=128)
+    window, block_length = mask.get("window", 0), mask.get("block_length", 0)
+    scale = 1.0 / math.sqrt(d)
+    with jax.enable_x64(False):
+        out, lse = attention._gqa_pallas(q, k, v, True, window, scale,
+                                         *tiles, True, block_length)
+    _close(out, attention.gqa_attention_reference(
+        q, k, v, True, window, scale, block_length))
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, axis=1)) \
+        * scale
+    pos = jnp.arange(128)
+    seen = attention._band_mask(pos[:, None], pos[None, :], True, window,
+                                block_length, 64)
+    _close(lse, jax.nn.logsumexp(jnp.where(seen, sc, -jnp.inf), axis=-1))
 
 
 # -- the routed layer ---------------------------------------------------------

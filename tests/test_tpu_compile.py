@@ -207,6 +207,51 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
         assert ("lstm_bwd_step" in text) == walk_kernels
 
 
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside its equations'
+    parameters (the branches of a ``pl.when``, a ``jit`` inside)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("case", [
+    "gqa-h64kv8s8192d128-window512-fwd", "gqa-h48kv8s8192d128-full-fwd-bwd",
+    "gqa-b2h32kv8s8192d64-full-fwd-bwd",
+    "gqa-h32kv4s16384d128-block-diffusion4-fwd-bwd"])
+def test_gqa_kernel_keeps_its_rows_along_sublanes(case):
+    """The relayouts PR 34 took out cannot come back unnoticed: in the
+    forward kernel's jaxpr the running state is two-dimensional from load to
+    store. JAX writes a reduction that keeps its dimension as a reduce to
+    one dimension and a ``broadcast_in_dim`` back, so that pair is what a
+    one-dimensional value may be: made by a reduction, read by nothing but
+    the broadcast that gives the dimension back. Needs no chip and no
+    described one."""
+    fn, args = _CASES[case](jax.ShapeDtypeStruct)
+    with jax.enable_x64(False):
+        traced = jax.make_jaxpr(lambda q, k, v, *_: attention._gqa_pallas(
+            q, k, v, True, 512 * ("window" in case), 0.125, 512, 512, False,
+            4 * ("block-diffusion" in case)))(*args)
+    call, = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    eqns = list(_eqns(call.params["jaxpr"]))
+    flat = {}
+    for eqn in eqns:
+        for var in eqn.outvars:
+            if len(var.aval.shape) < 2 and var.aval.shape != ():
+                assert eqn.primitive.name.startswith("reduce_"), eqn
+                flat[var] = eqn
+    assert flat       # the row max and sum of every slice, the lse's sums
+    for eqn in eqns:
+        for var in eqn.invars:
+            if not hasattr(var, "val") and var in flat:    # no literal
+                assert eqn.primitive.name == "broadcast_in_dim" \
+                    and len(eqn.outvars[0].aval.shape) == 2, eqn
+
+
 # -- what the routed layer moves around its grouped matmuls -------------------
 
 _ITEM_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
