@@ -8,6 +8,10 @@ Typical use mirrors the reference:
     x = mx.nd.zeros((2, 3), ctx=mx.tpu(0))
     net = mx.sym.FullyConnected(mx.sym.Variable('data'), num_hidden=10)
 """
+import time as _time
+
+_import_start_ns = _time.perf_counter_ns()
+
 from . import base  # noqa: F401
 from . import ops  # noqa: F401  (populates the op table)
 from . import ndarray  # noqa: F401
@@ -77,3 +81,8 @@ from .context import Context, cpu, current_context, gpu, num_gpus, num_tpus, tpu
 from .ndarray import NDArray  # noqa: F401
 
 __version__ = libinfo.__version__
+
+# the import as one span of the program's own profiler: known only now
+profiler.record("import.mxnet_tpu", _import_start_ns,
+                _time.perf_counter_ns())
+del _time, _import_start_ns

@@ -32,6 +32,17 @@ map**: ``{HLO instruction name: op_name path}`` parsed once from
 device trace gives its operations (``fusion.2089``) read back as the graph
 op that emitted them (``jvp(Convolution/stage1_unit1_conv1)``). A warm load
 pays nothing for it; :func:`op_map` reads it back on demand.
+
+Every materialization is one ``compile.materialize`` span of the program's
+profiler (``mxnet_tpu/profiler.py``) whose ``args`` say which program
+(``kind``, ``key``, ``sig``), what served it (``source``) and why it was
+needed (``cause``), over one child span a phase: ``compile.store_get``,
+``compile.load``, or ``compile.lower``, ``compile.backend``,
+``compile.op_map``, ``compile.store_put``. What JAX itself reports of a
+trace, a lowering or a backend compile (``jax.monitoring``) is recorded as
+``jax.trace``, ``jax.lower``, ``jax.backend_compile`` under whichever span
+is open, so the compiles that pass through no ``PersistentJit`` are seen
+too.
 """
 from __future__ import annotations
 
@@ -41,12 +52,14 @@ import logging
 import pickle
 import re
 import threading
+import time
 from collections import OrderedDict
 from typing import Callable, Dict, Optional, Sequence
 
 import jax
 from jax.experimental import serialize_executable as _se
 
+from .. import profiler as _profiler
 from ..base import getenv
 from . import cache as _cache
 from .fingerprint import aval_signature, program_key
@@ -85,15 +98,53 @@ def reset_program_stats():
 # entry then dies at run time with "Function <fusion> not found". JAX
 # records the hit on the compiling thread, so a thread-local count taken
 # around one compile tells whose executable came back.
-_jax_cache_hits = threading.local()
+_jax_thread = threading.local()     # .cache_hits, .phases_open, .hits_before
 
 
 def _on_jax_event(event, **_):
     if event == "/jax/compilation_cache/cache_hits":
-        _jax_cache_hits.n = getattr(_jax_cache_hits, "n", 0) + 1
+        _jax_thread.cache_hits = getattr(_jax_thread, "cache_hits", 0) + 1
+
+
+# What JAX times of its own work, by the name its span takes. JAX reports a
+# phase's start (a scalar, the wall clock) and at its end the duration; a
+# traced body that calls jitted functions reports a trace for each of them
+# inside its own, thousands in a model's step, so only the outermost phase
+# open on a thread is recorded: what it covers is its own to tell.
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile"}
+
+
+def _on_jax_phase_start(event, _value, **_):
+    if event in _JAX_PHASES:
+        opened = getattr(_jax_thread, "phases_open", 0)
+        if opened == 0:
+            _jax_thread.hits_before = getattr(_jax_thread, "cache_hits", 0)
+        _jax_thread.phases_open = opened + 1
+
+
+def _on_jax_phase_end(event, seconds, fun_name=None, **_):
+    name = _JAX_PHASES.get(event)
+    if name is None:
+        return
+    still_open = getattr(_jax_thread, "phases_open", 1) - 1
+    _jax_thread.phases_open = max(still_open, 0)
+    if still_open > 0:
+        return
+    args = {"fun": str(fun_name)} if fun_name else {}
+    if getattr(_jax_thread, "cache_hits", 0) != \
+            getattr(_jax_thread, "hits_before", 0):
+        args["jax_cache_hit"] = True    # JAX's own cache answered
+    _jax_thread.hits_before = getattr(_jax_thread, "cache_hits", 0)
+    end = time.perf_counter_ns()
+    _profiler.record(name, end - int(seconds * 1e9), end, args=args or None)
 
 
 jax.monitoring.register_event_listener(_on_jax_event)
+jax.monitoring.register_scalar_listener(_on_jax_phase_start)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_phase_end)
 
 
 # -- the op map --------------------------------------------------------------
@@ -130,17 +181,19 @@ def _op_map_key(key: str) -> str:
 
 def _keep_op_map(store, kind: str, key: str, compiled):
     """Parse and store the op map of a program just compiled."""
-    try:
-        ops, total = parse_op_map(compiled.as_text())
-    except Exception as err:    # noqa: BLE001 — names only; never the run
-        logging.info("PersistentJit[%s]: no op map (%s: %s)", kind,
-                     type(err).__name__, err)
-        return
-    logging.info("PersistentJit[%s]: op map names %d of %d instructions",
-                 kind, len(ops), total)
-    store.put(_op_map_key(key), json.dumps(ops).encode(),
-              meta={"kind": kind, "op_map_of": key}, counter="op_maps")
-    _materialized[kind] = (key, ops)
+    with _profiler.span("compile.op_map", args={}) as phase:
+        try:
+            ops, total = parse_op_map(compiled.as_text())
+        except Exception as err:    # noqa: BLE001 — names only; never the run
+            logging.info("PersistentJit[%s]: no op map (%s: %s)", kind,
+                         type(err).__name__, err)
+            return
+        logging.info("PersistentJit[%s]: op map names %d of %d "
+                     "instructions", kind, len(ops), total)
+        phase.args.update(instructions=total, named=len(ops))
+        store.put(_op_map_key(key), json.dumps(ops).encode(),
+                  meta={"kind": kind, "op_map_of": key}, counter="op_maps")
+        _materialized[kind] = (key, ops)
 
 
 _METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
@@ -288,18 +341,39 @@ class PersistentJit:
             self._on_materialize(kind)
 
     def _materialize(self, canon: str, args) -> Callable:
+        """One new executable, under one ``compile.materialize`` span that
+        says which program, what served it and why it was needed."""
+        note = {"kind": self.kind,
+                # a program this object already has means a drifted shape,
+                # dtype or static: the case CompileGuard warns of
+                "cause": "new_signature" if self._programs else "first",
+                "sig": canon[:120]}
+        with _profiler.span("compile.materialize", args=note):
+            prog = self._materialize_noted(canon, args, note)
+        if note["source"] != "bypassed":
+            _profiler.count("compile.materialized")
+            _profiler.count("compile." + note["source"])
+        return prog
+
+    def _materialize_noted(self, canon: str, args, note: dict) -> Callable:
         key = program_key(self.kind, "+".join(self._key_parts), canon,
                           donation=self._donate)
+        note["key"] = key[:12]
         store = _cache.default_cache()
-        data = store.get(key)
+        with _profiler.span("compile.store_get", args={}) as phase:
+            data = store.get(key)
+            phase.args["bytes"] = len(data) if data is not None else 0
         if data is not None:
             try:
-                payload, in_tree, out_tree, device_ids = pickle.loads(data)
-                compiled = _se.deserialize_and_load(
-                    payload, in_tree, out_tree,
-                    execution_devices=_devices_by_id(device_ids))
+                with _profiler.span("compile.load"):
+                    payload, in_tree, out_tree, device_ids = \
+                        pickle.loads(data)
+                    compiled = _se.deserialize_and_load(
+                        payload, in_tree, out_tree,
+                        execution_devices=_devices_by_id(device_ids))
                 self._notify("loaded")
                 _materialized[self.kind] = (key, None)
+                note["source"] = "loaded"
                 return self._wrap_compiled(compiled)
             except Exception as err:    # noqa: BLE001 — entry unusable here
                 logging.warning("PersistentJit[%s]: cached executable "
@@ -310,10 +384,16 @@ class PersistentJit:
                 # definition lives on the cache
                 store.invalidate(key)
                 _count("invalid_load")
-        jax_hits = getattr(_jax_cache_hits, "n", 0)
+                note["invalid_load"] = True
+        jax_hits = getattr(_jax_thread, "cache_hits", 0)
         try:
             with _metadata_in_jax_key():
-                compiled = self._jit.lower(*args).compile()
+                # the python step body traced and lowered, then XLA and
+                # Mosaic: two spans, so the two can be told apart
+                with _profiler.span("compile.lower"):
+                    lowered = self._jit.lower(*args)
+                with _profiler.span("compile.backend"):
+                    compiled = lowered.compile()
         except Exception as err:        # noqa: BLE001 — AOT-unfriendly call
             # loud, counted, and the same call then goes through the
             # plain jit, which raises the real error if there is one
@@ -323,28 +403,33 @@ class PersistentJit:
                             self.kind, type(err).__name__, err)
             _count("bypassed")
             self._disabled = True       # don't re-pay the sig walk per call
+            note["source"] = "bypassed"
             return self._jit
         self._notify("compiled")
         _keep_op_map(store, self.kind, key, compiled)
-        if getattr(_jax_cache_hits, "n", 0) != jax_hits:
+        if getattr(_jax_thread, "cache_hits", 0) != jax_hits:
             # JAX's cache served it and keeps serving it; see above
             _count("jax_cache_served")
+            note["source"] = "jax_cache_served"
             return self._wrap_compiled(compiled)
-        try:
-            payload, in_tree, out_tree = _se.serialize(compiled)
-            # the program's own device assignment, in order: a warm
-            # load must hand deserialize_and_load exactly these, or it
-            # spreads a one-device program over every local device
-            device_ids = [d.id for d in
-                          compiled.runtime_executable().local_devices()]
-            store.put(key, pickle.dumps((payload, in_tree, out_tree,
-                                         device_ids)),
-                      meta={"kind": self.kind, "sig": canon[:512]})
-        except Exception as err:        # noqa: BLE001 — unserializable
-            logging.info("PersistentJit[%s]: executable not "
-                         "serializable (%s: %s); in-process only",
-                         self.kind, type(err).__name__, err)
-            _count("unserializable")
+        note["source"] = "compiled"
+        with _profiler.span("compile.store_put", args={}) as phase:
+            try:
+                payload, in_tree, out_tree = _se.serialize(compiled)
+                # the program's own device assignment, in order: a warm
+                # load must hand deserialize_and_load exactly these, or it
+                # spreads a one-device program over every local device
+                device_ids = [d.id for d in
+                              compiled.runtime_executable().local_devices()]
+                data = pickle.dumps((payload, in_tree, out_tree, device_ids))
+                phase.args["bytes"] = len(data)
+                store.put(key, data,
+                          meta={"kind": self.kind, "sig": canon[:512]})
+            except Exception as err:        # noqa: BLE001 — unserializable
+                logging.info("PersistentJit[%s]: executable not "
+                             "serializable (%s: %s); in-process only",
+                             self.kind, type(err).__name__, err)
+                _count("unserializable")
         return self._wrap_compiled(compiled)
 
 

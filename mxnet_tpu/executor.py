@@ -695,7 +695,7 @@ class Executor:
         # global key chain untouched — they draw nothing from it)
         rng = _random.next_key() if self._needs_rng else _null_key()
         from . import profiler as _profiler
-        with _profiler.profile_scope("Forward", "executor", "symbolic"):
+        with _profiler.span("Forward", cat="executor", kind="symbolic"):
             outs, aux_up = self._fwd(arg_vals, aux_vals, rng, bool(is_train),
                                      _ambient_mesh_key())
         if is_train:
@@ -733,8 +733,8 @@ class Executor:
         sparse_w = {s["w"] for s in self._sparse_specs}
         dense_diff = tuple(n for n in self._diff_args if n not in sparse_w)
         from . import profiler as _profiler
-        with _profiler.profile_scope("ForwardBackward", "executor",
-                                     "symbolic"):
+        with _profiler.span("ForwardBackward", cat="executor",
+                            kind="symbolic"):
             outs, aux_up, grads, proxy_grads = self._fwd_bwd(
                 arg_vals, aux_vals, rng, head_grads, dense_diff,
                 _ambient_mesh_key())

@@ -138,6 +138,17 @@ class NDArrayIter(DataIter):
                  last_batch_handle="pad", data_name="data",
                  label_name="softmax_label", seed=None):
         super().__init__(batch_size)
+        # the sources go to the device (_init_data) and come back as the
+        # host cache: one span, so a long start can be put down to it
+        with _profiler.span("input.construct", args={}) as made:
+            self._construct(data, label, batch_size, shuffle,
+                            last_batch_handle, data_name, label_name, seed)
+            made.args["bytes"] = sum(
+                cached.nbytes for cached in self._np_cache.values())
+        _profiler.count("input.construct_bytes", made.args["bytes"])
+
+    def _construct(self, data, label, batch_size, shuffle, last_batch_handle,
+                   data_name, label_name, seed):
         self.data = _init_data(data, allow_empty=False, default_name=data_name)
         self.label = _init_data(label, allow_empty=True, default_name=label_name)
 
@@ -494,11 +505,13 @@ class PrefetchingIter(DataIter):
         self._fetched = [{"n": 0} for _ in self.iters]
         self._taken = 0
         self._slots = [_ExchangeSlot() for _ in self.iters]
-        for src, slot, fetched in zip(self.iters, self._slots,
-                                      self._fetched):
-            threading.Thread(target=self._produce,
-                             args=(src, slot, self._snap_flag, fetched),
-                             daemon=True).start()
+        # the producers start fetching at once; no byte moves here
+        with _profiler.span("input.construct", args={"bytes": 0}):
+            for src, slot, fetched in zip(self.iters, self._slots,
+                                          self._fetched):
+                threading.Thread(target=self._produce,
+                                 args=(src, slot, self._snap_flag, fetched),
+                                 daemon=True).start()
 
     @staticmethod
     def _produce(source, slot, snap_flag, fetched):

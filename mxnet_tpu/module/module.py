@@ -14,6 +14,7 @@ from typing import Dict, List, Optional
 
 from .. import ndarray as nd
 from .. import optimizer as opt
+from .. import profiler as _profiler
 from ..base import MXNetError
 from ..initializer import InitDesc, Uniform
 from ..io import DataDesc
@@ -217,6 +218,13 @@ class Module(BaseModule):
                             "init_params call ignored.")
             return
         assert self.binded, "call bind before initializing the parameters"
+        with _profiler.span("bind", args={"front": "module",
+                                          "stage": "init_params"}):
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing)
+
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing):
         self._sync_fused()      # make the executor arrays live targets
         attrs = self._symbol.attr_dict()
         for pname, layout in self._symbol._arg_layouts().items():
@@ -234,10 +242,17 @@ class Module(BaseModule):
             if initializer is not None:
                 initializer(InitDesc(name, attrs.get(name)), arr)
 
-        for name in self._param_names:
-            fill(name, self._exec.arg_dict[name], arg_params)
-        for name in self._aux_names:
-            fill(name, self._exec.aux_dict[name], aux_params)
+        with _profiler.span("bind.params", args={}) as placed:
+            for name in self._param_names:
+                fill(name, self._exec.arg_dict[name], arg_params)
+            for name in self._aux_names:
+                fill(name, self._exec.aux_dict[name], aux_params)
+            leaves = [self._exec.arg_dict[n] for n in self._param_names] \
+                + [self._exec.aux_dict[n] for n in self._aux_names]
+            placed.args.update(
+                bytes=sum(a.size * a.dtype.itemsize for a in leaves),
+                leaves=len(leaves))
+        _profiler.count("bind.param_bytes", placed.args["bytes"])
 
         self.params_initialized = True
         self._params_dirty = False
@@ -262,6 +277,13 @@ class Module(BaseModule):
             self.logger.warning("Already bound, ignoring bind()")
             return
 
+        with _profiler.span("bind", args={"front": "module",
+                                          "stage": "bind"}):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
 
@@ -289,10 +311,11 @@ class Module(BaseModule):
             else:
                 req[name] = grad_req
         shared_exec = shared_module._exec if shared_module is not None else None
-        self._exec = self._symbol.simple_bind(
-            ctx=self._context[0], grad_req=req,
-            shared_exec=shared_exec, group2ctx=self._group2ctxs,
-            **shape_kwargs)
+        with _profiler.span("bind.plan"):   # shapes inferred, arrays made
+            self._exec = self._symbol.simple_bind(
+                ctx=self._context[0], grad_req=req,
+                shared_exec=shared_exec, group2ctx=self._group2ctxs,
+                **shape_kwargs)
         self.binded = True
 
         if shared_module is not None and shared_module.params_initialized:
@@ -377,6 +400,11 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _profiler.span("bind", args={"front": "module",
+                                          "stage": "init_optimizer"}):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         # flush the stepper's donated device state BEFORE dropping it —
         # dropping first would orphan the trained params in dead buffers
         self._sync_fused()
@@ -413,24 +441,33 @@ class Module(BaseModule):
         self._update_on_kvstore = update_on_kvstore
         self._updater = None
 
-        if kvstore:
-            # copy initialized weights into the store
-            param_arrays = [self._exec.arg_dict[n] for n in self._param_names]
-            _initialize_kvstore(kvstore=kvstore, param_arrays=param_arrays,
-                                arg_params=self._arg_params or
-                                {n: self._exec.arg_dict[n]
-                                 for n in self._param_names},
-                                param_names=self._param_names,
-                                update_on_kvstore=update_on_kvstore)
-        if update_on_kvstore:
-            kvstore.set_optimizer(self._optimizer)
-        else:
-            self._updater = opt.get_updater(optimizer)
+        # what holds the optimizer's state from here on: the kvstore's copy
+        # of the weights, the updater, a state loaded before bind. The fused
+        # step makes its own on the device at its first step (stage
+        # ``fused_step``, perf/step_runtime.py module_stepper).
+        with _profiler.span("bind.state", args={}) as made:
+            if kvstore:
+                # copy initialized weights into the store
+                param_arrays = [self._exec.arg_dict[n]
+                                for n in self._param_names]
+                _initialize_kvstore(kvstore=kvstore,
+                                    param_arrays=param_arrays,
+                                    arg_params=self._arg_params or
+                                    {n: self._exec.arg_dict[n]
+                                     for n in self._param_names},
+                                    param_names=self._param_names,
+                                    update_on_kvstore=update_on_kvstore)
+            if update_on_kvstore:
+                kvstore.set_optimizer(self._optimizer)
+            else:
+                self._updater = opt.get_updater(optimizer)
 
-        self.optimizer_initialized = True
-        if self._preload_opt_states is not None:
-            self.load_optimizer_states(self._preload_opt_states)
-            self._preload_opt_states = None
+            self.optimizer_initialized = True
+            if self._preload_opt_states is not None:
+                self.load_optimizer_states(self._preload_opt_states)
+                self._preload_opt_states = None
+            made.args["leaves"] = (len(self._updater.states)
+                                   if self._updater is not None else 0)
 
     def borrow_optimizer(self, shared_module):
         """Share another Module's optimizer/updater/kvstore (reference

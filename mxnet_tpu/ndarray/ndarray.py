@@ -568,7 +568,7 @@ def imperative_invoke(op_name, inputs, attrs, out=None):
         call_attrs["_op_state"] = {}
     rng = None
     from .. import profiler as _profiler
-    with _profiler.profile_scope(opdef.name, "operator", "imperative"):
+    with _profiler.span(opdef.name, cat="operator", kind="imperative"):
         if opdef.needs_rng:
             rng = _random.next_key()
             outputs = opdef.fn(rng, *vals, **call_attrs)

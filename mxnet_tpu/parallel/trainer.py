@@ -139,7 +139,34 @@ class SPMDTrainer:
     def bind(self, data_shapes, label_shapes=None,
              initializer=None, arg_params=None, aux_params=None):
         """Infer shapes, initialize + shard parameters, compile the step."""
-        initializer = initializer or _init_mod.Xavier(magnitude=2.0)
+        with _profiler.span("bind", args={"front": "spmd"}):
+            with _profiler.span("bind.plan"):
+                plan, fused, known, shapes, aux_shapes = self._bind_plan(
+                    data_shapes, label_shapes)
+            with _profiler.span("bind.params", args={}) as placed:
+                params, aux = self._bind_params(
+                    plan, fused._param_names, shapes, aux_shapes,
+                    initializer or _init_mod.Xavier(magnitude=2.0),
+                    arg_params, aux_params)
+                leaves = list(params.values()) + list(aux.values())
+                placed.args.update(bytes=sum(x.nbytes for x in leaves),
+                                   leaves=len(leaves))
+            _profiler.count("bind.param_bytes", placed.args["bytes"])
+            with _profiler.span("bind.state", args={}) as made:
+                states = self._bind_states(plan, fused, params, shapes)
+                made.args["leaves"] = len(jax.tree_util.tree_leaves(states))
+            self.params, self.states, self.aux = params, states, aux
+            self._fused = fused
+            self._opt_res = fused._opt_res
+            self._ig_cfg = fused._ig_cfg
+            self.retrace_guard = fused.guard    # a fresh program's, count 0
+            self._in_shardings = self._input_shardings(known)
+        return self
+
+    def _bind_plan(self, data_shapes, label_shapes):
+        """The sharding plan, the inferred shapes and the step program
+        (``bind.plan``): everything that can refuse a bind, before any
+        state of a previous one is replaced."""
         known = dict(data_shapes)
         known.update(label_shapes or {})
         # remembered for elastic re-binds: remesh() re-runs bind with the
@@ -220,7 +247,13 @@ class SPMDTrainer:
             input_dtypes={n: str(self._dtype) for n in all_shapes},
             sharding=plan, loss_scale=self._loss_scale_req,
             integrity=self._integrity_req, kind="spmd-step")
+        return plan, fused, known, shapes, dict(zip(aux_names, aux_shapes))
 
+    def _bind_params(self, plan, param_names, shapes, aux_shapes,
+                     initializer, arg_params, aux_params):
+        """Every parameter and auxiliary state taken to the device under
+        its spec (``bind.params``): the given value, else the
+        initializer's."""
         mesh = self._mesh
         layouts = self._symbol._arg_layouts()
         params = {}
@@ -238,7 +271,7 @@ class SPMDTrainer:
             spec = plan.param_spec(name, host.shape)
             params[name] = jax.device_put(host, NamedSharding(mesh, spec))
         aux = {}
-        for name, shp in zip(aux_names, aux_shapes):
+        for name, shp in aux_shapes.items():
             if aux_params and name in aux_params:
                 host = np.asarray(aux_params[name].asnumpy()
                                   if isinstance(aux_params[name], NDArray)
@@ -248,7 +281,11 @@ class SPMDTrainer:
                 initializer(_init_mod.InitDesc(name), arr)
                 host = arr.asnumpy()
             aux[name] = jax.device_put(host, NamedSharding(mesh, P()))
+        return params, aux
 
+    def _bind_states(self, plan, fused, params, shapes):
+        """The optimizer state made on the device, a parameter at a time
+        (``bind.state``)."""
         # optimizer-state sharding from the plan: param spec, plus (in
         # ZeRO mode) the first mesh-divisible unsharded dim split over
         # the data axis (sharding.zero_shard_spec)
@@ -256,26 +293,25 @@ class SPMDTrainer:
             # ZeRO contract check: a param whose every dim is either
             # already sharded or data-indivisible keeps replicated state —
             # report it instead of silently degrading (VERDICT r2 #7)
-            unsharded = plan.zero_unsharded(
-                {n: shapes[n] for n in param_names})
+            unsharded = plan.zero_unsharded({n: shapes[n] for n in params})
             if unsharded:
                 import logging
                 logging.warning(
                     "shard_optimizer_state: %d param(s) have no dim "
                     "divisible by the data axis (%d) and keep REPLICATED "
                     "optimizer state: %s", len(unsharded),
-                    mesh.shape["data"], unsharded[:8])
+                    self._mesh.shape["data"], unsharded[:8])
         states = {}
         for n, w in params.items():
             state_sh = plan.state_sharding(n, shapes[n])
             states[n] = jax.tree_util.tree_map(
                 lambda x, _sh=state_sh: jax.device_put(x, _sh),
                 fused._init_state(w))
-        self.params, self.states, self.aux = params, states, aux
-        self._fused = fused
-        self._opt_res = fused._opt_res
-        self._ig_cfg = fused._ig_cfg
-        self.retrace_guard = fused.guard    # a fresh program's, count 0
+        return states
+
+    def _input_shardings(self, known):
+        """name -> sharding of every bound input, by the batch rule."""
+        mesh = self._mesh
         # sequence parallelism: shard the sequence dim (dim 1) of token
         # inputs over the axis the graph's attention ops actually name —
         # not a hardcoded literal — so inputs arrive pre-sharded for the
@@ -288,7 +324,7 @@ class SPMDTrainer:
             if ax and ax in mesh.axis_names and mesh.shape[ax] > 1:
                 seq_axis = ax
                 break
-        self._in_shardings = {}
+        in_shardings = {}
         for n in list(self._data_names) + list(self._label_names):
             if n not in known:
                 continue
@@ -298,8 +334,8 @@ class SPMDTrainer:
             if (seq_axis is not None and len(shp) >= 2 and spec[1] is None
                     and shp[1] % mesh.shape[seq_axis] == 0):
                 spec[1] = seq_axis
-            self._in_shardings[n] = NamedSharding(mesh, P(*spec))
-        return self
+            in_shardings[n] = NamedSharding(mesh, P(*spec))
+        return in_shardings
 
     def rebind_step(self):
         """Rebuild the donated step program on the SAME mesh and live

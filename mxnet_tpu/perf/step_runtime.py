@@ -1237,16 +1237,23 @@ def module_stepper(module, compute_dtype=None, donate=True, mesh=None,
         return None
     all_arrs = list(exec_.arg_dict.items()) + list(exec_.aux_dict.items())
     try:
-        fused = FusedStep(module._symbol, module._optimizer, trainable,
-                          compute_dtype=compute_dtype, donate=donate,
-                          name=f"module-step:{type(module).__name__}",
-                          input_shapes={n: tuple(v.shape)
-                                        for n, v in all_arrs},
-                          input_dtypes={n: str(v.dtype)
-                                        for n, v in all_arrs},
-                          mesh=mesh, sharding=sharding,
-                          loss_scale=loss_scale, integrity=integrity)
-        stepper = ModuleStepper(module, fused, frozen)
+        # the module's last stage of bind: the step program, then the
+        # training state as that program holds it
+        with _profiler.span("bind", args={"front": "module",
+                                          "stage": "fused_step"}):
+            with _profiler.span("bind.plan"):
+                fused = FusedStep(
+                    module._symbol, module._optimizer, trainable,
+                    compute_dtype=compute_dtype, donate=donate,
+                    name=f"module-step:{type(module).__name__}",
+                    input_shapes={n: tuple(v.shape) for n, v in all_arrs},
+                    input_dtypes={n: str(v.dtype) for n, v in all_arrs},
+                    mesh=mesh, sharding=sharding,
+                    loss_scale=loss_scale, integrity=integrity)
+            with _profiler.span("bind.state", args={}) as made:
+                stepper = ModuleStepper(module, fused, frozen)
+                made.args["leaves"] = len(
+                    jax.tree_util.tree_leaves(stepper._states))
     except MemoryBudgetError:
         raise       # the budget gate must surface, never silently
         # degrade into the (equally over-budget) imperative fallback

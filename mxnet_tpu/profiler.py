@@ -12,7 +12,10 @@ TPU-native rebuild. The host's work is recorded where it happens, as
 (its cause) and a **batch ordinal**: the running number of the batch since
 the iterator's last ``reset()``, which the input pipeline's producer thread
 and the fit loop each count for themselves, so every span of one batch
-carries the same number without a field on ``DataBatch``.
+carries the same number without a field on ``DataBatch``. A span may carry
+a few named values of its own (``args``: what served a program, how many
+bytes went to the device), and work whose start is only known at its end
+is put in by its end points (:func:`record`).
 
 * Always (state 'stop', the default): a span goes into a bounded in-memory
   ring and adds its duration to a per-name total. No lock beyond the GIL,
@@ -20,7 +23,7 @@ carries the same number without a field on ``DataBatch``.
   read them; ``perfbench/metrics/`` does.
 * ``profiler_set_state('run')`` / ``MXTPU_PROFILER_AUTOSTART``, or between
   :func:`start_xla_trace` and :func:`stop_xla_trace`: each span is also a
-  ``jax.profiler.TraceAnnotation(name, batch=k)``, so in an XLA trace the
+  ``jax.profiler.TraceAnnotation(name, batch=k, **args)``, so in an XLA trace the
   spans lie on the profiler's own clock in ``/host:CPU`` beside the
   device's operations. :func:`dump_profile` writes the ring as Chrome JSON.
 
@@ -43,7 +46,7 @@ from .base import MXNetError, getenv
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "start_xla_trace", "stop_xla_trace", "is_running",
-           "span", "profile_scope", "count", "spans", "totals", "self_totals",
+           "span", "record", "count", "spans", "totals", "self_totals",
            "counters", "set_batch", "op_scopes", "Span"]
 
 _MODES = ("symbolic", "imperative", "all")
@@ -51,10 +54,17 @@ _MODES = ("symbolic", "imperative", "all")
 # One constant size. The fit thread records at most 10 spans a step and a
 # producer 5 a batch (``input.fetch``, and ``input.slice`` + ``input.h2d``
 # for the data and again for the label), so the ring holds the last ~4,000
-# steps.
+# steps. The spans of set-up (``import.*``, ``bind*``, ``input.construct``,
+# ``compile.*``, ``jax.*``) come once a process or once a program, some
+# tens in all.
 RING_SIZE = 1 << 16
 
-Span = namedtuple("Span", "seq name start_ns end_ns thread parent batch cat")
+Span = namedtuple("Span",
+                  "seq name start_ns end_ns thread parent batch cat args",
+                  defaults=(None,))
+# a record built straight from its tuple, without the namedtuple's own
+# python-level constructor: the one allocation a span costs
+_new_span = tuple.__new__
 
 
 class _ThreadState(threading.local):
@@ -64,6 +74,7 @@ class _ThreadState(threading.local):
 
     def __init__(self):
         self.stack: List["span"] = []
+        self.ident = threading.get_ident()
         self.batch: Optional[int] = None
         self.totals: Dict[str, list] = {}      # name -> [count, ns]
         self.counts: Dict[str, int] = {}
@@ -156,15 +167,22 @@ class span:
     (:func:`set_batch`). ``kind`` ('symbolic' / 'imperative') marks a
     per-call span that is recorded only while the profiler runs in that
     mode; the named spans of the fit loops and the input pipeline pass
-    none and are always recorded."""
+    none and are always recorded. ``args``: a small dict of named values
+    that goes into the record as it is; the body may add to it until the
+    span closes (``with span("x", args={}) as s: s.args["source"] = ...``).
+    Values it holds when the span opens are also the ``TraceAnnotation``'s
+    keywords while a trace runs. A span given none carries ``None``."""
 
-    __slots__ = ("name", "batch", "cat", "seq", "parent", "start", "note")
+    __slots__ = ("name", "batch", "cat", "seq", "parent", "start", "note",
+                 "args")
 
     def __init__(self, name: str, batch: Optional[int] = None,
-                 cat: str = "span", kind: Optional[str] = None):
+                 cat: str = "span", kind: Optional[str] = None,
+                 args: Optional[dict] = None):
         self.name = name
         self.batch = batch
         self.cat = cat
+        self.args = args
         self.seq = None if kind is not None and not is_running(kind) else -1
 
     def __enter__(self):
@@ -185,8 +203,10 @@ class span:
         self.seq = next(_PROF.seq)
         self.note = None
         if _PROF.running or _PROF.xla_tracing:
-            self.note = (_PROF.annotation(self.name) if self.batch is None
-                         else _PROF.annotation(self.name, batch=self.batch))
+            words = dict(self.args) if self.args else {}
+            if self.batch is not None:
+                words["batch"] = self.batch
+            self.note = _PROF.annotation(self.name, **words)
             self.note.__enter__()
         self.start = time.perf_counter_ns()
         return self
@@ -199,9 +219,9 @@ class span:
             self.note.__exit__(*exc)
         tls = _TLS
         tls.stack.pop()
-        _PROF.ring[self.seq % RING_SIZE] = Span(
-            self.seq, self.name, self.start, end, threading.get_ident(),
-            self.parent, self.batch, self.cat)
+        _PROF.ring[self.seq % RING_SIZE] = _new_span(Span, (
+            self.seq, self.name, self.start, end, tls.ident,
+            self.parent, self.batch, self.cat, self.args))
         cell = tls.totals.get(self.name)
         if cell is None:
             cell = tls.totals[self.name] = [0, 0]
@@ -210,9 +230,26 @@ class span:
         return False
 
 
-def profile_scope(name: str, cat: str = "operator", kind: str = "symbolic"):
-    """The old name of :class:`span`, with its old argument order."""
-    return span(name, cat=cat, kind=kind)
+def record(name: str, start_ns: int, end_ns: int,
+           args: Optional[dict] = None):
+    """Put a finished span into the ring and the totals by its end points
+    (``time.perf_counter_ns``): for work whose start is only known once it
+    has ended, such as a duration a library reports. Its cause is the span
+    open on this thread now, its batch ordinal that span's or the loop's."""
+    tls = _TLS
+    if tls.stack:
+        top = tls.stack[-1]
+        parent, batch = top.seq, top.batch
+    else:
+        parent, batch = -1, tls.batch
+    seq = next(_PROF.seq)
+    _PROF.ring[seq % RING_SIZE] = Span(
+        seq, name, start_ns, end_ns, tls.ident, parent, batch, "span", args)
+    cell = tls.totals.get(name)
+    if cell is None:
+        cell = tls.totals[name] = [0, 0]
+    cell[0] += 1
+    cell[1] += end_ns - start_ns
 
 
 def count(name: str, n: int = 1):
@@ -283,7 +320,7 @@ def dump_profile():
     tids: Dict[int, int] = {}
     events = []
     for s in found:
-        args = {}
+        args = dict(s.args) if s.args else {}
         if s.batch is not None:
             args["batch"] = s.batch
         if s.parent in names:

@@ -97,15 +97,15 @@ def test_counters_and_totals_merge_threads():
 
 def test_a_span_of_a_kind_is_recorded_only_while_that_mode_runs():
     t0 = since()
-    with profiler.profile_scope("quiet-op", "operator", "imperative"):
+    with profiler.span("quiet-op", cat="operator", kind="imperative"):
         pass
     assert "quiet-op" not in names(profiler.spans(t0))
     profiler.profiler_set_config(mode="imperative", filename="unused.json")
     profiler.profiler_set_state("run")
     try:
-        with profiler.profile_scope("loud-op", "operator", "imperative"):
+        with profiler.span("loud-op", cat="operator", kind="imperative"):
             pass
-        with profiler.profile_scope("Forward", "executor", "symbolic"):
+        with profiler.span("Forward", cat="executor", kind="symbolic"):
             pass
     finally:
         profiler.profiler_set_state("stop")
@@ -123,6 +123,52 @@ def prefetched(rows=12, batch=4):
 
 def by_name(found, name):
     return [s for s in found if s.name == name]
+
+
+def test_args_go_through_spans_and_the_dump_and_a_span_without_stays_bare(
+        tmp_path):
+    path = str(tmp_path / "args.json")
+    profiler.profiler_set_config(mode="symbolic", filename=path)
+    profiler.profiler_set_state("run")
+    t0 = since()
+    with profiler.span("t.with", batch=3, args={"source": "loaded"}) as s:
+        s.args["bytes"] = 12            # filled before the span closes
+        with profiler.span("t.bare"):
+            pass
+    profiler.dump_profile()
+    found = {s.name: s for s in profiler.spans(t0)}
+    assert found["t.with"].args == {"source": "loaded", "bytes": 12}
+    assert found["t.bare"].args is None
+    assert found["t.bare"]._fields[-1] == "args"
+    # a record made the old way, eight fields, still reads as one without
+    assert profiler.Span(0, "x", 1, 2, 3, -1, None, "span").args is None
+    events = {e["name"]: e for e in json.load(open(path))["traceEvents"]}
+    assert events["t.with"]["args"] == {"source": "loaded", "bytes": 12,
+                                        "batch": 3}
+    assert events["t.bare"]["args"] == {"batch": 3, "parent": "t.with"}
+
+
+def test_record_lands_under_the_open_span_and_in_the_totals():
+    t0 = since()
+    before = profiler.totals().get("t.recorded", (0, 0))
+    with profiler.span("t.outer", batch=7) as outer:
+        lo = since()
+        time.sleep(0.002)
+        profiler.record("t.recorded", lo, since(), args={"fun": "f"})
+    profiler.record("t.recorded", lo, lo + 5)           # no span open
+    inner, alone = by_name(profiler.spans(t0), "t.recorded")
+    assert (inner.parent, inner.batch, inner.args) == (
+        outer.seq, 7, {"fun": "f"})
+    assert (alone.parent, alone.args) == (-1, None)
+    assert inner.thread == threading.get_ident()
+    count, ns = profiler.totals()["t.recorded"]
+    assert count - before[0] == 2
+    assert ns - before[1] == inner.end_ns - inner.start_ns + 5
+    # the recorded span is its cause's child: self time leaves it out
+    own = profiler.self_totals(t0)
+    whole = [s for s in profiler.spans(t0) if s.name == "t.outer"][0]
+    assert own["t.outer"] == (whole.end_ns - whole.start_ns
+                              - (inner.end_ns - inner.start_ns))
 
 
 def test_producer_and_fit_thread_give_a_batch_one_ordinal_across_a_reset():
