@@ -14,12 +14,14 @@ anywhere from one chip to a 4-D mesh.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
 from ..base import AttrSpec, MXNetError
+from .pallas import rotary
 from .registry import register
 
 
@@ -109,6 +111,32 @@ def rotary_frequencies(rotary_dim, theta, rope_type="default", factor=1.0,
     return inv / factor * ramp + inv * (1.0 - ramp)
 
 
+def _angles(positions, r, frequencies, attention_factor):
+    """cos and sin (positions, r / 2) of position times frequency, times
+    ``attention_factor``, float32."""
+    angle = jnp.arange(positions, dtype=jnp.float32)[:, None] \
+        * rotary_frequencies(r, *frequencies)[None, :]
+    return jnp.cos(angle) * attention_factor, jnp.sin(angle) * attention_factor
+
+
+def _kernel_rotated(data, attrs, negative):
+    head_dim, angles, plan = attrs
+    return rotary.rotate_pallas(data, *_angles(*angles), head_dim, negative,
+                                plan)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _kernel_rotation(data, attrs):
+    return _kernel_rotated(data, attrs, False)
+
+
+# a rotation's transpose is the rotation by the negative angle (the
+# attention factor multiplies both ways): the same kernel, nothing kept
+_kernel_rotation.defvjp(
+    lambda data, attrs: (_kernel_rotated(data, attrs, False), None),
+    lambda attrs, _, g: (_kernel_rotated(g, attrs, True),))
+
+
 @register("RotaryEmbedding",
           attrs=AttrSpec(head_dim=("int",), rotary_dim=("int", 0),
                          theta=("float", 10000.0),
@@ -131,8 +159,22 @@ def _rotary_embedding(data, head_dim, rotary_dim=0, theta=10000.0,
     ``attention_factor`` (YaRN's scaling of the logits, applied where the
     published models apply it). With ``copies`` > 1 the sequence is that
     many copies of one document laid end to end, each at positions 0 ..
-    S / copies - 1: the position of index s is s mod (S / copies). Angles in
-    float32, output in the input's dtype."""
+    S / copies - 1: the position of index s is s mod (S / copies). Angles,
+    products and the sum in float32, one rounding to the input's dtype.
+
+    On a TPU it runs ``ops/pallas/rotary.py``'s kernel over the tensor laid
+    out by head, (B heads, S, head_dim), as the attention kernel reads it and
+    as a projection writes it for nothing (the two transpositions are
+    logical and cancel against the neighbours'): a row of lanes is one head
+    at one position and the exchange of its halves a lane rotation, one read
+    and one write of the tensor, where the four-dimensional view over
+    (B, S, heads, head_dim) costs a float32 relayout each way. A head size
+    that is no multiple of 128 lanes, or a document length that no row tile
+    of 8 (16 for a two-byte dtype) divides, keeps the ``jnp`` formulation, as
+    does every other backend; the choice reads the input's shape and nothing
+    else. Through the kernel the op is one ``jax.custom_vjp`` with no
+    residual: its gradient is the rotation by the negative angle, the same
+    kernel; the ``jnp`` formulation is differentiated by JAX."""
     b, s, e = data.shape
     r = rotary_dim or head_dim
     if e % head_dim or r > head_dim or r % 2:
@@ -140,24 +182,18 @@ def _rotary_embedding(data, head_dim, rotary_dim=0, theta=10000.0,
             f"RotaryEmbedding: width {e}, head_dim {head_dim}, rotary_dim "
             f"{r}: the width is a whole number of heads and the rotated "
             f"part an even number of dims inside a head")
-    inv = rotary_frequencies(r, theta, rope_type, factor,
-                             original_max_position, beta_fast, beta_slow)
     if copies < 1 or s % copies:
         raise MXNetError(
             f"RotaryEmbedding: a sequence of {s} is not {copies} copies of "
             f"one document")
-    if copies == 1:
-        position = jnp.arange(s, dtype=jnp.float32)
-    else:
-        position = (jnp.arange(s) % (s // copies)).astype(jnp.float32)
-    angle = position[:, None] * inv[None, :]
-    cos = (jnp.cos(angle) * attention_factor)[None, :, None, :]
-    sin = (jnp.sin(angle) * attention_factor)[None, :, None, :]
-    x = data.reshape(b, s, e // head_dim, head_dim).astype(jnp.float32)
-    x1, x2, rest = x[..., :r // 2], x[..., r // 2:r], x[..., r:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-    return out.reshape(b, s, e).astype(data.dtype)
+    angles = (s // copies, r, (theta, rope_type, factor,
+                               original_max_position, beta_fast, beta_slow),
+              attention_factor)
+    plan = rotary.kernel_plan(data, s // copies, head_dim)
+    if plan is None:
+        return rotary.rotate_reference(data, *_angles(*angles), head_dim,
+                                       copies)
+    return _kernel_rotation(data, (head_dim, angles, plan))
 
 
 @register("GroupedQueryAttention",

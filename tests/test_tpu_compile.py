@@ -14,6 +14,7 @@ on-chip-measurement §2): one process at a time may load the TPU
 library, every xdist worker imports this file, and only the worker that
 runs it may make the call. Keep every such test in THIS file.
 """
+import functools
 import math
 import os
 import re
@@ -23,7 +24,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu.ops.pallas import attention, lstm
+from mxnet_tpu.ops.pallas import attention, lstm, rotary
+from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.parallel import moe
 
 
@@ -350,3 +352,122 @@ def test_routed_layer_moves_no_slot_tensor_it_need_not(case, one_chip,
     assert not relaid, f"a relayout of the slot rows: {relaid}"
     moved = sum(b for *_, b in traffic) / 1e9
     assert moved < bound, f"{moved:.2f} GB outside the grouped matmuls"
+
+
+# -- what RotaryEmbedding moves: one read and one write -----------------------
+
+_YARN = dict(rope_type="yarn", factor=32.0, original_max_position=4096,
+             attention_factor=1.2)
+_ROTATIONS = {
+    # (B, S, heads * head_dim), the op's attributes (ISSUE 36's table: the
+    # jnp formulation moved 1.9 / 1.3 / 1.9 / 0.19 GB forward and 12-17%
+    # more in jax's transpose)
+    "laguna-sliding-q-8192x64x128": ((1, 8192, 64 * 128), dict(head_dim=128)),
+    "laguna-full-q-8192x48x128-half-yarn": (
+        (1, 8192, 48 * 128),
+        dict(head_dim=128, rotary_dim=64, theta=5e5, **_YARN)),
+    "sdar-q-16384x32x128-two-copies": (
+        (1, 16384, 32 * 128), dict(head_dim=128, theta=1e6, copies=2)),
+    "sdar-k-16384x4x128-two-copies": (
+        (1, 16384, 4 * 128), dict(head_dim=128, theta=1e6, copies=2)),
+}
+
+
+@pytest.fixture
+def rotary_kernel(monkeypatch):
+    """``RotaryEmbedding`` down the chip's path: the op takes the kernel
+    only where ``jax.default_backend()`` is ``tpu``."""
+    monkeypatch.setattr(rotary, "kernel_plan", functools.partial(
+        rotary.kernel_plan, impl="pallas"))
+
+
+@pytest.mark.parametrize("stage", ["forward", "vjp"])
+@pytest.mark.parametrize("case", list(_ROTATIONS))
+def test_rotary_embedding_moves_one_read_and_one_write(
+        case, stage, one_chip, no_compile_cache, rotary_kernel):
+    """The op and its ``vjp`` between neighbours that keep the heads apart
+    (a projection's output, the attention's operands: the transpositions
+    cancel against the kernel's own), each compiled for the described chip at
+    a decoder cell's shape, bf16 in and out: the kernel is in the program,
+    everything the entry computation reads and writes stays under 1.3 x
+    (input + output) plus the angle table built, written and read (three
+    tables' bytes), and no float32 array of the tensor's size is written
+    anywhere: the four-dimensional view's relayout cannot come back
+    unnoticed. (LFM2's head of 64 keeps the jnp formulation.)"""
+    (b, s, e), attrs = _ROTATIONS[case]
+    d = attrs["head_dim"]
+    op = get_op("RotaryEmbedding").fn
+
+    def by_head(x):                   # (B, heads, S, d) in and out
+        out = op(x.transpose(0, 2, 1, 3).reshape(b, s, e), **attrs)
+        return out.reshape(b, s, e // d, d).transpose(0, 2, 1, 3)
+
+    x = jax.ShapeDtypeStruct((b, e // d, s, d), jnp.bfloat16,
+                             sharding=one_chip)
+    if stage == "forward":
+        fn, args = by_head, (x,)
+    else:
+        fn, args = (lambda x, g: jax.vjp(by_head, x)[1](g)[0]), (x, x)
+    with jax.enable_x64(False):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    elements = b * s * e
+    wide = [(dt, n) for dt, n in _arrays(text) if dt == "f32" and n >= elements]
+    assert not wide, f"float32 at the tensor's size: {wide}"
+    moved = sum(b for *_, b in _entry_traffic(text))
+    table = s // attrs.get("copies", 1) * d * 4
+    assert moved < 1.3 * 2 * 2 * elements + 3 * table, \
+        f"{moved / 1e9:.3f} GB for {2 * 2 * elements / 1e9:.3f} in and out"
+
+
+_Q_PATHS = {
+    # positions, model width, heads, head size, q norm, the op's attributes;
+    # GB as compiled (PR 36; through the jnp formulation 5.57 / 3.17 / 2.81,
+    # through a kernel over the (B S, heads x head size) view 6.44 / 2.34 /
+    # 1.78)
+    "sdar-q-norm-rotary-heads": (
+        (16384, 2048, 32, 128, True, dict(theta=1e6, copies=2)), 2.6),
+    "laguna-sliding-q-rotary-heads": (
+        (8192, 2048, 64, 128, False, dict(theta=1e4)), 1.87),
+    "laguna-full-q-rotary-heads": (
+        (8192, 2048, 48, 128, False,
+         dict(rotary_dim=64, theta=5e5, **_YARN)), 1.43),
+}
+
+
+@pytest.mark.parametrize("case", list(_Q_PATHS))
+def test_query_path_keeps_its_heads_apart(case, one_chip, no_compile_cache,
+                                          rotary_kernel):
+    """Projection, q norm where the model has one, rotation and the
+    attention's head layout, output and gradient, compiled for the described
+    chip at a decoder cell's shape: the projection writes its heads apart,
+    the kernel reads and writes them so, and all that the path moves stays
+    under the case's bound. A kernel whose operand's layout XLA has to meet
+    with copies of its own (position-minor float32 between the norm and the
+    kernel: SDAR's step lost 1.5% to them) fails here at no chip time."""
+    (s, width, heads, d, norm, attrs), bound = _Q_PATHS[case]
+    rms, rope = get_op("RMSNorm").fn, get_op("RotaryEmbedding").fn
+
+    def path(u, w, gain):
+        q = jnp.dot(u, w.astype(jnp.bfloat16).T)
+        if norm:
+            q = rms(q.reshape(1, s, heads, d), gain, eps=1e-6) \
+                .reshape(1, s, heads * d)
+        q = rope(q, head_dim=d, **attrs)
+        return q.reshape(1, s, heads, d).transpose(0, 2, 1, 3)
+
+    def both(u, w, gain, ct):
+        out, vjp = jax.vjp(path, u, w, gain)
+        return out, vjp(ct)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (struct((1, s, width), jnp.bfloat16),
+            struct((heads * d, width), jnp.float32), struct((d,), jnp.float32),
+            struct((1, heads, s, d), jnp.bfloat16))
+    with jax.enable_x64(False), jax.default_matmul_precision("default"):
+        text = jax.jit(both).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    moved = sum(b for *_, b in _entry_traffic(text)) / 1e9
+    assert moved < bound, f"{moved:.2f} GB along the query path"
