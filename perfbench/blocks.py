@@ -31,8 +31,7 @@ if ROOT not in sys.path:
 from perfbench import scopes  # noqa: E402
 
 # the readers under perfbench/metrics/ that read these records; main() prints
-# them beside the table (BENCHMARK.json does not list them yet: PERF.md, Open
-# question 13)
+# them beside the table (BENCHMARK.json entries since PR 37)
 DECODER_METRICS = ("attention_share", "moe_share",
                    "window_attention_roofline", "expert_matmul_roofline",
                    "moe_load_max_over_mean")

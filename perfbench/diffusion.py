@@ -2,8 +2,8 @@
 """``blocks.py`` for a decoder trained under the block-diffusion objective:
 the same traced run and ``blocks {...}`` line, its ``metrics`` holding the
 mask's attention roofline and the share of tokens that carried loss beside
-the five decoder readers (none of the seven is a ``BENCHMARK.json`` entry
-yet: PERF.md, Open question 13), a ``counters {...}`` line with the
+the five decoder readers (all but ``loss_weighted_share`` are
+``BENCHMARK.json`` entries since PR 37), a ``counters {...}`` line with the
 program's two counters of the objective, and a ``mask_probe {...}`` line.
 
     python3 perfbench/diffusion.py --workload <cell> --seed <n> --seconds <s>

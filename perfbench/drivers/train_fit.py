@@ -156,6 +156,9 @@ def run(cell, args, devices, t_process):
                "peaks": cell_peaks(device["kind"], args.rehearse),
                "chips": chips, "train_rate": train_rate,
                "window_s": window_s,
+               # set-up's start for the setup_* readers: under run.py this
+               # module's ``harness`` is a second import, stamped later
+               "t_process": t_process,
                "counters": {k: counters_after[k] - counters_before[k]
                             for k in counters_after}}
         line["metrics"] = per_layer_metrics(cell, ctx)

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """``blocks.py`` for a decoder with short-convolution layers: the same
 traced run and ``blocks {...}`` line, its ``metrics`` holding the two
-``ShortConv`` readers beside the five decoder readers (none of the seven is
-a ``BENCHMARK.json`` entry yet: PERF.md, Open question 13).
+``ShortConv`` readers beside the five decoder readers (all seven are
+``BENCHMARK.json`` entries since PR 37).
 
     python3 perfbench/hybrid.py --workload <cell> --seed <n> --seconds <s>
 """

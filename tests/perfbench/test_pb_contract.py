@@ -1,107 +1,124 @@
 """BENCHMARK.json against the contract's limits, and every file a cell, a
 configuration, a traffic mix, a driver or a per-layer metric needs is found
 by its name: adding one is adding files and an entry."""
-import json
+import copy
 import os
 import re
 
 import pytest
 
-from _pb import BENCH, CELLS, METRICS, PB, ROOT
-
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-LINE = re.compile(r"^[^\t\n]{1,200}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+import _structure as st
+from _pb import BENCH, METRICS, PB
 
 
 def test_top_level_keys_and_command():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert 1 <= len(BENCH["command"]) <= 32
-    assert isinstance(BENCH["run_seconds"], int)
-    assert 1 <= BENCH["run_seconds"] <= 51
-    for path in BENCH["paths"]:
-        assert re.match(r"^[A-Za-z0-9_.\-/]{1,200}$", path)
-        assert os.path.isdir(os.path.join(ROOT, path))
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 65536
-    # a full check with the full 24 cells fits the driver's budget
-    runs = 2 + 14 * 24
-    assert runs * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+    st.check_top_level(BENCH, st.DISK)
 
 
 @pytest.mark.parametrize("config", BENCH["configs"],
                          ids=lambda c: c["name"])
 def test_config_entry_and_files(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(config["name"]) and LINE.match(config["source"])
-    assert LINE.match(config["why"])
-    assert any(config["file"].startswith(p.rstrip("/") + "/")
-               for p in BENCH["paths"])
-    with open(os.path.join(ROOT, config["file"])) as f:
-        body = json.load(f)
-    assert body["source"] == config["source"]
-    assert body["reduced"] == config["reduced"] and "assumed" in body
-    assert len(config["reduced"]) <= 16
-    assert os.path.isfile(os.path.join(PB, "models", config["name"] + ".py"))
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    st.check_config(BENCH, st.DISK, config)
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_cell_entry_and_files(cell):
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    for key in ("name", "config", "traffic"):
-        assert NAME.match(cell[key])
-    assert cell["chips"] in (1, 4) and LINE.match(cell["why"])
-    assert cell["config"] in {c["name"] for c in BENCH["configs"]}
-    with open(os.path.join(PB, "traffic", cell["traffic"] + ".json")) as f:
-        traffic = json.load(f)
-    assert traffic["chips"] == cell["chips"]
-    assert os.path.isfile(os.path.join(PB, "drivers",
-                                       traffic["driver"] + ".py"))
-    with open(os.path.join(PB, "limits", cell["name"] + ".json")) as f:
-        limits = json.load(f)
-    assert limits and all(isinstance(v, float) for k, v in limits.items()
-                          if k != "rehearse")
+    st.check_cell(BENCH, st.DISK, cell)
+
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_cell_reports_setup_another_end_to_end_and_a_layer(cell):
+    st.check_cell_reports(BENCH, st.DISK, cell)
 
 
 def test_cells_are_distinct_and_few_take_four_chips():
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
-    assert len(set(pairs)) == len(pairs) and len(set(CELLS)) == len(CELLS)
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(CELLS) // 4)
-    assert all(w["name"] == "resnet50.train-zero1-x4" for w in four)
+    st.check_cells(BENCH, st.DISK)
+    st.check_four_chip_cells(BENCH, st.DISK)
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
 def test_metric_entry(metric):
-    end_to_end = metric in BENCH["end_to_end"]
-    allowed = {"name", "unit", "better", "source", "workloads"} | (
-        {"bound"} if end_to_end else {"layer", "moves"})
-    assert set(metric) <= allowed
-    assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
-    assert metric["better"] in ("lower", "higher")
-    assert metric["source"] in SOURCES
-    for cell in metric.get("workloads", []):
-        assert cell in CELLS
-    if end_to_end:
-        assert metric["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= metric["bound"] <= 0.1
-    else:
-        assert LINE.match(metric["layer"])
-        assert metric["moves"] in {m["name"] for m in BENCH["end_to_end"]}
-        reader = os.path.join(PB, "metrics",
-                              metric["name"].split(".")[0] + ".py")
-        assert os.path.isfile(reader)
-        with open(reader) as f:
-            assert "def read(ctx)" in f.read()
+    st.check_metric(BENCH, st.DISK, metric)
 
 
 def test_metric_names_unique_and_setup_present():
-    names = [m["name"] for m in METRICS]
-    assert len(set(names)) == len(names)
-    assert "setup_s" in names and "train_rate" in names
-    assert any("mfu" in m["name"] for m in BENCH["per_layer"])
+    st.check_metrics(BENCH, st.DISK)
+
+
+@pytest.mark.parametrize("check", st.STRUCTURAL, ids=lambda f: f.__name__)
+def test_every_check_holds_on_the_file_as_it_stands(check):
+    check(BENCH, st.DISK)
+
+
+@pytest.mark.parametrize("check", st.STRUCTURAL, ids=lambda f: f.__name__)
+def test_every_check_holds_with_a_config_two_cells_and_a_metric_appended(
+        check):
+    """What a ``model_config`` PR adds (a configuration, a one-chip cell, a
+    four-chip cell whose mesh is ``{model: 4}``, a per-layer metric that
+    lists both) passes every check without an edit to one."""
+    check(*st.appended())
+
+
+def test_the_appended_copy_holds_what_it_says_and_leaves_the_file_alone():
+    before = copy.deepcopy(BENCH)
+    bench, files = st.appended()
+    assert BENCH == before
+    assert len(bench["configs"]) == len(BENCH["configs"]) + 1
+    assert [w["chips"] for w in bench["workloads"][-2:]] == [1, 4]
+    four = files.json(st.pb("traffic", bench["workloads"][-1]["traffic"]
+                            + ".json"))
+    assert four["mesh"] == {"model": 4}
+    assert bench["per_layer"][-1]["workloads"] == [st.NEW_ONE_CHIP,
+                                                   st.NEW_FOUR_CHIP]
+    assert bench["per_layer"][:-1] == BENCH["per_layer"]
+    assert not os.path.exists(os.path.join(PB, "metrics",
+                                           st.NEW_METRIC + ".py"))
+
+
+@pytest.mark.parametrize("mesh", [{"model": 2}, {"data": 2, "model": 1},
+                                  None], ids=["model2", "data2", "none"])
+def test_a_four_chip_cell_that_leaves_chips_idle_is_refused(mesh):
+    bench, files = st.appended()
+    traffic = files.overlay[st.pb("traffic", st.NEW_FOUR_CHIP_TRAFFIC
+                                  + ".json")]
+    if mesh is None:
+        del traffic["mesh"]
+    else:
+        traffic["mesh"] = mesh
+    with pytest.raises(AssertionError):
+        st.check_four_chip_cells(bench, files)
+
+
+def test_more_four_chip_cells_than_a_quarter_is_refused():
+    bench, files = st.appended()
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert len(four) == max(1, len(bench["workloads"]) // 4)
+    # one more: a one-chip cell of the copy asks for four
+    one = next(w for w in bench["workloads"] if w["chips"] == 1)
+    one["chips"] = 4
+    with pytest.raises(AssertionError):
+        st.check_four_chip_cells(bench, files)
+
+
+def test_a_copy_whose_four_chip_cells_are_full_makes_room_first():
+    full, first = st.appended()
+    bench, files = st.appended(full, tag="-2")
+    files.overlay.update(first.overlay)
+    assert len([w for w in bench["workloads"] if w["chips"] == 4]) == 3
+    for check in st.STRUCTURAL:
+        check(bench, files)
+
+
+def test_a_metric_put_into_the_middle_or_a_cell_left_unreported_is_refused():
+    bench, files = st.appended()
+    bench["per_layer"].insert(st.SPAN_RUN_AT + 3, bench["per_layer"].pop())
+    with pytest.raises(AssertionError):
+        st.check_span_run(bench, files)
+    bench, files = st.appended()
+    rate = next(m for m in bench["end_to_end"] if m["name"] == "train_rate")
+    rate["workloads"].remove(st.NEW_FOUR_CHIP)
+    with pytest.raises(AssertionError):
+        st.check_metrics(bench, files)
 
 
 def test_benchmark_imports_nothing_of_the_repos_other_benchmarks():

@@ -11,6 +11,7 @@ import types
 import numpy as np
 import pytest
 
+import _structure as st
 from _pb import BENCH, PB
 from perfbench import blocks, compare
 from perfbench import run as harness
@@ -239,18 +240,15 @@ def test_control_and_planted_faults_are_not_correct(model):
 
 
 def test_the_cell_reports_a_rate_and_the_metrics_with_no_list():
-    """The five decoder readers are files and not entries yet: an accepted
-    test (``test_pb_span_metrics.py``: the tail of ``per_layer`` is PR 25's
-    eleven) stands in the way of appending them, and no accepted benchmark
-    file may change here. The cell still reports an end-to-end rate and the
-    per-layer metrics that list no cells."""
+    """The cell reports an end-to-end rate, the per-layer metrics with no
+    list that move what it reports, and those that list it: the five
+    decoder readers among them (entries since PR 37)."""
     cell = harness.load_cell(CELL)
     assert [m["name"] for m in cell["end_to_end"]] == ["train_rate",
                                                        "setup_s"]
-    assert {m["name"] for m in cell["per_layer"]} == {
-        "compiles_in_window", "device_idle_share", "mfu_step"}
-    listed = {m["name"] for m in BENCH["per_layer"]}
-    assert not listed & set(blocks.DECODER_METRICS)
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert reads == st.cell_metrics(BENCH, CELL)[1]
+    assert st.DECODER_BASE | set(blocks.DECODER_METRICS) <= reads
     for name in blocks.DECODER_METRICS:
         assert hasattr(harness.load_reader(name), "read")
 

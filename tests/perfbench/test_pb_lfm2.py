@@ -9,6 +9,7 @@ import types
 
 import pytest
 
+import _structure as st
 from _pb import BENCH, PB
 from perfbench import blocks, compare, hybrid
 from perfbench import run as harness
@@ -114,10 +115,10 @@ def test_readers_find_nothing_in_a_program_without_the_records(cfg, metric):
 
 def test_the_hybrid_table_adds_its_two_readers_to_the_decoders():
     assert not set(hybrid.HYBRID_METRICS) & set(blocks.DECODER_METRICS)
-    listed = {m["name"] for m in BENCH["per_layer"]}
-    # files, not entries yet (PERF.md, Open question 13)
-    assert not listed & set(hybrid.HYBRID_METRICS)
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    # entries since PR 37, each read in this cell alone
     for name in hybrid.HYBRID_METRICS:
+        assert listed[name]["workloads"] == [CELL]
         assert hasattr(harness.load_reader(name), "read")
 
 
@@ -125,8 +126,10 @@ def test_the_cell_reports_a_rate_and_the_metrics_with_no_list():
     cell = harness.load_cell(CELL)
     assert [m["name"] for m in cell["end_to_end"]] == ["train_rate",
                                                        "setup_s"]
-    assert {m["name"] for m in cell["per_layer"]} == {
-        "compiles_in_window", "device_idle_share", "mfu_step"}
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert reads == st.cell_metrics(BENCH, CELL)[1]
+    assert st.DECODER_BASE | set(blocks.DECODER_METRICS) \
+        - {"window_attention_roofline"} | set(hybrid.HYBRID_METRICS) <= reads
     assert cell["chips"] == 1
     traffic = cell["traffic_params"]
     assert (traffic["per_chip_batch"], traffic["seq_len"],

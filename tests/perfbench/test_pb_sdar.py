@@ -9,6 +9,7 @@ import types
 
 import pytest
 
+import _structure as st
 from _pb import BENCH, PB
 from perfbench import blocks, compare, diffusion
 from perfbench import run as harness
@@ -163,9 +164,12 @@ def test_readers_find_nothing_in_a_program_without_the_records(cfg, model,
 
 def test_the_diffusion_table_adds_its_two_readers_to_the_decoders():
     assert not set(diffusion.DIFFUSION_METRICS) & set(blocks.DECODER_METRICS)
-    listed = {m["name"] for m in BENCH["per_layer"]}
-    # files, not entries yet (PERF.md, Open question 13)
-    assert not listed & set(diffusion.DIFFUSION_METRICS)
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    # the roofline an entry since PR 37, read in this cell alone; the share
+    # of tokens that carried loss a reader only: it reads the traffic's
+    # noise schedule, which no change to the program moves
+    assert listed["block_diffusion_attention_roofline"]["workloads"] == [CELL]
+    assert "loss_weighted_share" not in listed
     for name in diffusion.DIFFUSION_METRICS:
         assert hasattr(harness.load_reader(name), "read")
 
@@ -174,8 +178,12 @@ def test_the_cell_reports_a_rate_and_the_metrics_with_no_list():
     cell = harness.load_cell(CELL)
     assert [m["name"] for m in cell["end_to_end"]] == ["train_rate",
                                                        "setup_s"]
-    assert {m["name"] for m in cell["per_layer"]} == {
-        "compiles_in_window", "device_idle_share", "mfu_step"}
+    reads = {m["name"] for m in cell["per_layer"]}
+    assert reads == st.cell_metrics(BENCH, CELL)[1]
+    assert st.DECODER_BASE | set(blocks.DECODER_METRICS) \
+        - {"window_attention_roofline"} \
+        | {"block_diffusion_attention_roofline"} <= reads
+    assert "loss_weighted_share" not in reads
     assert cell["chips"] == 1
     traffic = cell["traffic_params"]
     assert (traffic["per_chip_batch"], traffic["seq_len"],
@@ -184,10 +192,12 @@ def test_the_cell_reports_a_rate_and_the_metrics_with_no_list():
         == (1, 8192, 4, 4, 3, "host", "train_fit")
     laguna = harness.load_cell("laguna-xs2.train-fed-seq8k")["cfg"]
     assert cell["cfg"]["optimizer"] == laguna["optimizer"]
-    entry = BENCH["configs"][-1]
-    assert entry["name"] == "sdar-30b-a3b" and entry["reduced"] == [
+    # found by name: a configuration or cell appended after them is fine
+    entry, = [c for c in BENCH["configs"] if c["name"] == "sdar-30b-a3b"]
+    assert entry["reduced"] == [
         "num_hidden_layers", "num_experts_held", "vocab_size"]
-    assert BENCH["workloads"][-1]["name"] == CELL
+    found, = [w for w in BENCH["workloads"] if w["name"] == CELL]
+    assert found["config"] == entry["name"] and found["chips"] == 1
 
 
 def test_every_catalog_number_stands_under_its_key(cfg):

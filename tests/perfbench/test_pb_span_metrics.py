@@ -4,6 +4,7 @@ from collections import namedtuple
 
 import pytest
 
+import _structure as st
 from _pb import BENCH
 from perfbench import reduce, scopes
 from perfbench import run as harness
@@ -56,34 +57,12 @@ def read(metric, ctx):
     return harness.load_reader(metric).read(ctx)
 
 
-NEW = ["input_fetch_busy_share", "input_slice_share", "input_h2d_share",
-       "step_dispatch_ms", "step_dispatch_ms.hostfed",
-       "idle_unattributed_share", "idle_unattributed_share.hostfed",
-       "conv_roofline", "norm_act_share", "lstm_backward_share",
-       "unscoped_share"]
+NEW = [name for name, _ in st.SPAN_RUN]
 
 
 def test_the_new_metrics_are_listed_for_the_cells_the_issue_names():
-    listed = {m["name"]: m for m in BENCH["per_layer"]}
-    fed = ["resnet50.train-fed"]
-    rate = ["lstm-ptb-large.train-fed-seq128", "resnet50.train-resident",
-            "resnet50.train-zero1-x4"]
-    want = {"input_fetch_busy_share": fed, "input_slice_share": fed,
-            "input_h2d_share": fed, "step_dispatch_ms": rate,
-            "step_dispatch_ms.hostfed": fed,
-            # only where the chip idles for 1% of the window or more: a
-            # share of 8-11 ms of idle in 10 s is the offset's error
-            "idle_unattributed_share": rate[2:],
-            "idle_unattributed_share.hostfed": fed,
-            "conv_roofline": rate[1:], "norm_act_share": rate[1:],
-            "lstm_backward_share": rate[:1], "unscoped_share": rate}
-    assert sorted(want) == sorted(NEW)
-    for name, cells in want.items():
-        assert listed[name]["workloads"] == cells, name
-        moves = "train_rate_hostfed" if cells == fed else "train_rate"
-        assert listed[name]["moves"] == moves, name
-    # appended: what was there stands first, in its order
-    assert [m["name"] for m in BENCH["per_layer"]][-len(NEW):] == NEW
+    # one run, in order, where PR 25 put it; entries after it are appended
+    st.check_span_run(BENCH, st.DISK)
 
 
 @pytest.mark.parametrize("metric", NEW)
