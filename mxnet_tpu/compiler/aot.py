@@ -93,12 +93,22 @@ def reset_program_stats():
 
 # JAX's own persistent cache can answer the store's compile (same HLO
 # under another store key, or a store entry lost). The executable it
-# hands back was deserialized, and must not be serialized again:
+# hands back was deserialized. On XLA:CPU it must not be serialized again:
 # XLA:CPU writes such an executable out without its kernels, and the
-# entry then dies at run time with "Function <fusion> not found". JAX
-# records the hit on the compiling thread, so a thread-local count taken
-# around one compile tells whose executable came back.
+# entry then dies at run time with "Function <fusion> not found". A TPU
+# executable carries its code and is written to the store like a compiled
+# one: left to JAX's cache, every later process would trace and lower the
+# program again before JAX's cache answered (the step of a decoder cell:
+# 5.5-14.5 s each time). JAX records the hit on the compiling thread, so a
+# thread-local count taken around one compile tells whose executable came
+# back.
 _jax_thread = threading.local()     # .cache_hits, .phases_open, .hits_before
+
+
+def _rewritable(compiled) -> bool:
+    """Whether an executable JAX's cache handed back may be serialized
+    again: everywhere but on XLA:CPU (see above)."""
+    return compiled.runtime_executable().local_devices()[0].platform != "cpu"
 
 
 def _on_jax_event(event, **_):
@@ -408,11 +418,13 @@ class PersistentJit:
         self._notify("compiled")
         _keep_op_map(store, self.kind, key, compiled)
         if getattr(_jax_thread, "cache_hits", 0) != jax_hits:
-            # JAX's cache served it and keeps serving it; see above
+            # JAX's cache served it; on the CPU it keeps serving it (above)
             _count("jax_cache_served")
             note["source"] = "jax_cache_served"
-            return self._wrap_compiled(compiled)
-        note["source"] = "compiled"
+            if not _rewritable(compiled):
+                return self._wrap_compiled(compiled)
+        else:
+            note["source"] = "compiled"
         with _profiler.span("compile.store_put", args={}) as phase:
             try:
                 payload, in_tree, out_tree = _se.serialize(compiled)

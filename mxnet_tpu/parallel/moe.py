@@ -21,6 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import profiler
 from ..base import MXNetError
+from ..ops.pallas import moe_rows
 
 __all__ = ["moe_apply", "moe_dense_apply", "top1_router", "topk_router",
            "load_balance_loss", "scored_topk_router", "sigmoid_topk_router",
@@ -234,22 +235,30 @@ def scored_topk_router(x, router_w, k: int, scale: float = 1.0,
 sigmoid_topk_router = scored_topk_router
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_by_slot(x, take, put, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rows_by_slot(x, take, put, k, plan=None):
     """``x[take % T]``: row r of the result is the token of slot ``take[r]``
     (slot ``j * T + t`` is token t's choice j, k choices a token). ``put``
     is the inverse permutation, so the cotangent is a gather too: slot by
     slot, then the sum of a token's k slots, which are k whole (T, d)
-    slabs; a scatter-add over k T rows is what this spares."""
-    return x[take % x.shape[0]]
+    slabs; a scatter-add over k T rows is what this spares. With a ``plan``
+    (:func:`~mxnet_tpu.ops.pallas.moe_rows.row_plan`) both ways run the
+    row kernels: the gather, and the k rows summed in float32 and rounded
+    once."""
+    return _rows_by_slot_fwd(x, take, put, k, plan)[0]
 
 
-def _rows_by_slot_fwd(x, take, put, k):
-    return x[take % x.shape[0]], put
+def _rows_by_slot_fwd(x, take, put, k, plan):
+    t = x.shape[0]
+    if plan is None:
+        return x[take % t], put
+    return moe_rows.rows_to_slots(x, take % t, plan), take
 
 
-def _rows_by_slot_bwd(k, put, g):
-    return g[put].reshape(k, -1, g.shape[-1]).sum(0), None, None
+def _rows_by_slot_bwd(k, plan, res, g):
+    if plan is None:
+        return g[res].reshape(k, -1, g.shape[-1]).sum(0), None, None
+    return moe_rows.slots_to_rows(g, res, k, plan), None, None
 
 
 _rows_by_slot.defvjp(_rows_by_slot_fwd, _rows_by_slot_bwd)
@@ -304,6 +313,38 @@ def _weighted_return_bwd(res, g):
 _weighted_return.defvjp(_weighted_return_fwd, _weighted_return_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _return_by_kernel(out, weights, held, take, put, plan):
+    """:func:`_weighted_return` of ``out`` (k T, d), the rows in sorted order,
+    put back in slot order, by the row kernels: token t's float32 sum over
+    its k slots of ``select(held, row, 0) * weights``, rounded once, with no
+    (k, T, d) tensor in slot order among the residuals (they are ``out`` as it
+    came, ``weights`` and ``held`` (k, T), ``take`` and ``put``). The
+    cotangent of ``out`` is the tokens' cotangent gathered to sorted order,
+    ``select(held, g x w, 0)`` rounded once, and the weights' is the dot of
+    each slot's row with its token's cotangent, both from one pass."""
+    return _return_by_kernel_fwd(out, weights, held, take, put, plan)[0]
+
+
+def _return_by_kernel_fwd(out, weights, held, take, put, plan):
+    y = moe_rows.slots_to_rows(out, take, weights.shape[0], plan, weights,
+                               held)
+    return y, (out, weights, held, take, put)
+
+
+def _return_by_kernel_bwd(plan, res, g):
+    out, weights, held, take, put = res
+    profiler.count("moe.fused_return_layers")
+    k, t = weights.shape
+    d_out, d_weights = moe_rows.rows_to_slots(
+        g, take % t, plan, weights.reshape(-1)[take], held.reshape(-1)[take],
+        out)
+    return (d_out, d_weights[put].reshape(k, t), None, None, None)
+
+
+_return_by_kernel.defvjp(_return_by_kernel_fwd, _return_by_kernel_bwd)
+
+
 def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
                        top_k, expert_offset=0, routed_scale=1.0,
                        select_bias=None, renorm_eps=0.0,
@@ -354,7 +395,8 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
         # every row lies in a group (the grouped matmul writes no other)
         groups = counts.at[-1].add(
             (t * top_k - jnp.sum(counts)).astype(counts.dtype))
-        rows = _rows_by_slot(x, take, put, top_k)
+        plan = moe_rows.row_plan(t, d, x.dtype)
+        rows = _rows_by_slot(x, take, put, top_k, plan)
     with jax.named_scope("experts"):
         dt = x.dtype
         gate = jax.lax.ragged_dot(rows, w_gate.astype(dt), groups)
@@ -365,7 +407,11 @@ def held_experts_apply(x, router_w, w_gate, w_up, w_down, *, num_experts,
         # an absent slot's row is selected away inside the return, not
         # multiplied: its cotangent is zero too, so it reaches neither the
         # tokens nor the last expert
-        per_slot = _permute_rows(out, put, take).reshape(top_k, t, d)
-        y = _weighted_return(per_slot, weights.T,
-                             (local < held).reshape(top_k, t))
+        held_slots = (local < held).reshape(top_k, t)
+        if plan is None:
+            per_slot = _permute_rows(out, put, take).reshape(top_k, t, d)
+            y = _weighted_return(per_slot, weights.T, held_slots)
+        else:
+            y = _return_by_kernel(out, weights.T, held_slots, take, put,
+                                  plan)
     return y, counts

@@ -672,6 +672,32 @@ def test_store_keeps_out_what_jax_cache_served(tmp_cache,
     assert seen[3]["programs"]["loaded"] == 1
 
 
+def test_store_keeps_what_jax_cache_served_where_it_can_be_rewritten(
+        tmp_cache, jax_cache_takes_everything, monkeypatch):
+    """Where an executable JAX's cache served may be serialized again (a
+    TPU executable carries its code), the store writes it like a compiled
+    one, so the next process loads it instead of tracing and lowering the
+    program before JAX's cache answers; XLA:CPU is the one place it may
+    not (the test above)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.compiler import aot
+    monkeypatch.setattr(aot, "_rewritable", lambda compiled: True)
+
+    def f(x):
+        return jnp.tanh(x @ x) * 2.0
+
+    x = jnp.asarray(np.random.RandomState(1).rand(8, 8), jnp.float32)
+    seen = []
+    for key in ("a", "b"):
+        jax.clear_caches()
+        compiler.PersistentJit(f, kind="rewritten", key_parts=(key,))(x)
+        seen.append(compiler.stats())
+    assert seen[0]["cache"]["writes"] == 1
+    assert seen[1]["programs"]["jax_cache_served"] == 1, seen[1]
+    assert seen[1]["cache"]["writes"] == 2, seen[1]
+
+
 def test_persistent_jit_warm_load_skips_tracing(tmp_cache):
     import jax.numpy as jnp
     traces = [0]

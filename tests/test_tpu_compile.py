@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu.ops.pallas import attention, lstm, rotary
+from mxnet_tpu.ops.pallas import attention, lstm, moe_rows, rotary
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.parallel import moe
 
@@ -303,19 +303,31 @@ def _entry_traffic(hlo):
 
 _ROUTED_LAYERS = {
     # tokens, d, f, held of experts, top_k; GB outside the grouped matmuls
-    # (ISSUE 32: 12.38 and 7.80 before; 7.93 and 7.03 as compiled, PR 32)
-    "lfm2-8b-a1b-t16384-k4-8of32": ((16384, 2048, 1792, 8, 32, 4), 9.0),
-    "laguna-xs2-t8192-k8-32of256": ((8192, 2048, 512, 32, 256, 8), 7.9),
+    # through the row kernels (6.66 / 7.76 / 10.62 as compiled; through jnp
+    # 7.03 / 7.93, and 7.80 / 12.38 before the slots were choice-major)
+    "laguna-xs2-t8192-k8-32of256": ((8192, 2048, 512, 32, 256, 8), 7.3),
+    "lfm2-8b-a1b-t16384-k4-8of32": ((16384, 2048, 1792, 8, 32, 4), 8.5),
+    "sdar-30b-a3b-t16384-k8-16of128": ((16384, 2048, 768, 16, 128, 8), 11.7),
 }
+
+
+@pytest.fixture
+def row_kernels(monkeypatch):
+    """The routed layer down the chip's path: it takes the row kernels only
+    where ``jax.default_backend()`` is ``tpu``."""
+    monkeypatch.setattr(moe_rows, "row_plan", functools.partial(
+        moe_rows.row_plan, impl="pallas"))
 
 
 @pytest.mark.parametrize("case", list(_ROUTED_LAYERS))
 def test_routed_layer_moves_no_slot_tensor_it_need_not(case, one_chip,
-                                                       no_compile_cache):
+                                                       no_compile_cache,
+                                                       row_kernels):
     """One routed layer, output and gradient (bf16 tokens, float32 master
-    weights), compiled for the described chip at a decoder cell's shape.
-    From the compiled text: outside the grouped matmuls nothing is written
-    in float32 at the size of the slot rows (T k d), no ``reshape`` or
+    weights), compiled for the described chip at a decoder cell's shape
+    through the row kernels. From the compiled text: the two kernels are
+    there both ways, outside the grouped matmuls nothing is written in
+    float32 at the size of the slot rows (T k d), no ``reshape`` or
     ``copy`` relays a tensor of that size (a token's k slots as the
     second-minor axis of a tile did both), and all that is read and written
     there stays under the case's bound."""
@@ -341,6 +353,11 @@ def test_routed_layer_moves_no_slot_tensor_it_need_not(case, one_chip,
                        ).lower(*args).compile().as_text()
     # forward 3, backward 6: every choice's row still rides in each
     assert len(set(re.findall(r"%(ragged-dot-none[.\d]*) = ", text))) == 9
+    # the gather and the gather-sum (two kernels) forward, their transposes
+    # backward
+    for kernel in ("moe_rows_to_slots", "moe_rows_to_slot_order",
+                   "moe_slots_to_rows"):
+        assert len(set(re.findall(r"%%(%s[.\d]*) = " % kernel, text))) == 2
     traffic = _entry_traffic(text)
     slot_rows = t * k * d
     wide = [(name, arrays) for name, _, arrays, _ in traffic
@@ -352,6 +369,71 @@ def test_routed_layer_moves_no_slot_tensor_it_need_not(case, one_chip,
     assert not relaid, f"a relayout of the slot rows: {relaid}"
     moved = sum(b for *_, b in traffic) / 1e9
     assert moved < bound, f"{moved:.2f} GB outside the grouped matmuls"
+
+
+def test_row_kernels_do_not_grow_with_the_rows(one_chip):
+    """Each row kernel's Mosaic module, as the step's lowering carries it,
+    is the same size at two token counts and two k (to the digits of the
+    shapes it names): the kernels walk their rows in loops, so neither the
+    lowering nor the stored executable grows with T or k (a row loop
+    unrolled in Python would make both grow with the rows)."""
+    def modules(t, k, d=2048):
+        plan = moe_rows.row_plan(t, d, jnp.bfloat16, impl="pallas")
+
+        def struct(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        x, rows = struct((t, d), jnp.bfloat16), struct((k * t, d),
+                                                       jnp.bfloat16)
+        idx, scale = struct((k * t,), jnp.int32), struct((k * t,),
+                                                         jnp.float32)
+        flags = struct((k * t,), jnp.bool_)
+        weights, held = struct((k, t), jnp.float32), struct((k, t), jnp.bool_)
+        calls = (
+            (lambda x, i: moe_rows.rows_to_slots(x, i, plan), (x, idx)),
+            (lambda x, i, s, h, o: moe_rows.rows_to_slots(
+                x, i, plan, s, h, o), (x, idx, scale, flags, rows)),
+            (lambda r, i: moe_rows.slots_to_rows(r, i, k, plan), (rows, idx)),
+            (lambda r, i, w, h: moe_rows.slots_to_rows(
+                r, i, k, plan, w, h), (rows, idx, weights, held)))
+        with jax.enable_x64(False):
+            return [[len(c) for c in re.findall(
+                r'backend_config = "([^"]*)"',
+                jax.jit(fn).lower(*args).as_text())] for fn, args in calls]
+
+    small, large = modules(4096, 4), modules(8192, 8)
+    assert all(m for m in small), small
+    # the same module but for the digits of the shapes it names
+    for a, b in zip(sum(small, []), sum(large, [])):
+        assert abs(b - a) <= 0.01 * a, (small, large)
+
+
+def test_a_process_traces_each_row_kernel_once_a_shape(row_kernels):
+    """Four routed layers of one shape, traced as shape inference traces a
+    step at bind (in every process, before the stored step is loaded): each
+    row kernel's call is traced once, not once a layer. Traced a layer at a
+    time, the kernels cost Laguna's warm set-up 3.1 s on a TPU v5e
+    (``jax.trace`` outside any program, 1.06 -> 3.29 s); once a shape, 0.15
+    s."""
+    from mxnet_tpu import profiler
+    t, d, f, held, experts, k = 8192, 2048, 512, 32, 256, 8
+    args = tuple(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((t, d), jnp.bfloat16), ((experts, d), jnp.float32),
+        ((held, d, f), jnp.float32), ((held, d, f), jnp.float32),
+        ((held, f, d), jnp.float32)))
+
+    def layers(x, *weights):
+        for _ in range(4):
+            x = moe.held_experts_apply(x, *weights, num_experts=experts,
+                                       top_k=k)[0]
+        return x
+
+    jax.clear_caches()
+    before = profiler.counters().get("moe.row_kernel_calls", 0)
+    with jax.enable_x64(False):
+        jax.eval_shape(layers, *args)
+    # the gather and the weighted gather-sum
+    assert profiler.counters().get("moe.row_kernel_calls", 0) - before == 2
 
 
 # -- what RotaryEmbedding moves: one read and one write -----------------------
